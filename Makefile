@@ -74,8 +74,8 @@ bench-chaos:
 # reactive plane (ISSUE 12): event-driven detection latency — deploy
 # PATCH -> first verdict through the fake kube server's real watch
 # stream (<= 1 s bar), anomaly POST -> completed_unhealth through the
-# real ingest receiver at the 16k fleet (p99 <= 2 s bar, pinned in
-# BENCHMARKS.md), micro-vs-full tick-path status parity asserted in-run
+# real ingest receiver at the 16k fleet (p99 <= 2 s bar),
+# micro-vs-full tick-path status parity asserted in-run
 bench-latency:
 	$(PY) -m benchmarks.latency_bench
 

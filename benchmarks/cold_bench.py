@@ -1,10 +1,10 @@
 """Cold-start benchmark: ring-resident cold fits, short-history
-admission, background refinement (ISSUE 10, BENCHMARKS.md round 12).
+admission, background refinement (ISSUE 10).
 
 Rounds 5/8 left the cold/churn path as the last order-of-magnitude
 bound: a 16k daily-season COLD tick paid a full 7-day history
-fetch+upload per doc (271 s), and a 10%-churn tick re-paid the churned
-fraction's share every tick (13.1 s). The ingest ring already holds
+fetch+upload per doc, and a 10%-churn tick re-paid the churned
+fraction's share every tick. The ingest ring already holds
 that history resident — this benchmark measures the tentpole that lets
 cold fits read it from there:
 
@@ -58,7 +58,7 @@ from foremast_tpu.metrics.source import PrometheusSource
 
 NOW = 1_760_000_000.0
 ALIASES = 4
-# full-shape acceptance bars (ISSUE 10 / BENCHMARKS.md round 12)
+# full-shape acceptance bars (ISSUE 10)
 FULL_SERVICES = 16_384
 FULL_HIST = 10_080
 BAR_COLD_SECONDS = 120.0
@@ -429,6 +429,9 @@ def main(argv=None):
         "--small", action="store_true", help="CPU smoke shapes (CI)"
     )
     args = ap.parse_args(argv)
+    from foremast_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     if args.small:
         args.services = min(args.services, 24)
         args.hist_len = min(args.hist_len, 512)

@@ -797,6 +797,9 @@ def main(argv=None) -> int:
         "--small", action="store_true", help="CPU smoke shapes (CI)"
     )
     args = parser.parse_args(argv)
+    from foremast_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     phases = run(small=args.small)
     from benchmarks.report import write_summary
 

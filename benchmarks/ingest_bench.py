@@ -602,6 +602,9 @@ def main(argv=None):
         "--small", action="store_true", help="CPU smoke shapes (CI)"
     )
     args = ap.parse_args(argv)
+    from foremast_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     if args.small:
         args.services = min(args.services, 24)
         args.aliases = min(args.aliases, 2)

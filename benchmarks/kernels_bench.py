@@ -1,16 +1,14 @@
-"""Pallas-vs-XLA judgment re-bench on the bf16-delta regime (VERDICT
-r5 #5: "settle the Pallas question").
+"""Pallas-vs-XLA judgment bench on the bf16-delta regime.
 
 Measures the four variants of the fused moving_average_all judgment on
 identical data — XLA f32 (`scoring._score_xla`), XLA bf16-delta
 (`scoring.score_bf16_delta`, the shipped steady-state program), Pallas
 f32 (`ops.kernels.ma_judgment`), Pallas bf16-delta
-(`ops.kernels.ma_judgment_bf16_delta`, added this round so the kernel
-finally speaks the default storage layout) — at the headline shape,
-steady-state amortized like bench.py. Off-TPU the Pallas rows run in
-INTERPRET mode, which measures the Python interpreter, not a kernel;
-they are reported with `interpreted: true` and must not be read as
-device numbers. The keep-or-cut decision table lives in BENCHMARKS.md.
+(`ops.kernels.ma_judgment_bf16_delta`) — at the headline shape,
+steady-state amortized like bench.py. Measures on a TPU only: without
+one it exits non-zero. `--small` is the CPU smoke: tiny shapes, Pallas
+rows in INTERPRET mode and marked `interpreted: true` — that times the
+Python interpreter, not a kernel, and is never a device number.
 
 Usage: python -m benchmarks.kernels_bench [--small] [--iters N]
 One JSON line per variant.
@@ -50,11 +48,18 @@ def main(argv=None):
     ap.add_argument("--small", action="store_true")
     ap.add_argument("--iters", type=int, default=None)
     args = ap.parse_args(argv)
-    on_tpu = jax.default_backend() == "tpu"
-    b = 1024 if args.small or not on_tpu else 32_768
-    th = 512 if args.small or not on_tpu else 10_080
+    from foremast_tpu.device import (
+        device_info,
+        enable_compile_cache,
+        require_tpu,
+    )
+
+    enable_compile_cache()
+    device = device_info() if args.small else require_tpu()
+    b = 1024 if args.small else 32_768
+    th = 512 if args.small else 10_080
     tc = 30
-    iters = args.iters or (20 if on_tpu else 3)
+    iters = args.iters or (3 if args.small else 20)
 
     batch = jax.device_put(throughput_batch(b, th, tc))
     slim, anchor, delta = scoring.make_bf16_delta_batch(batch)
@@ -90,16 +95,17 @@ def main(argv=None):
         )[0],
     }
     for name, fn in variants.items():
-        interpreted = name.startswith("pallas") and not on_tpu
-        if interpreted and b * th > 1024 * 512:
-            continue  # interpreter mode at headline shapes never returns
+        # off-TPU (--small only) kernels.py picks interpret mode itself
+        interpreted = (
+            name.startswith("pallas") and device["platform"] != "tpu"
+        )
         sec = _time(fn, iters)
         print(
             json.dumps(
                 {
                     "config": "k-ma-judgment",
                     "variant": name,
-                    "backend": jax.default_backend(),
+                    **device,
                     "interpreted": interpreted,
                     "batch": b,
                     "hist_len": th,

@@ -293,7 +293,7 @@ def run_sharded_parity_child() -> None:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu") or "cpu"
+    env["JAX_PLATFORMS"] = "cpu"  # virtual HOST devices: a CPU check
     env["FOREMAST_DEVICE_MESH"] = "auto"
     env.pop("FOREMAST_SWEEP_SLICE_DOCS", None)
     out = subprocess.run(
@@ -648,6 +648,9 @@ def main(argv=None):
         "--small", action="store_true", help="CPU smoke shapes (CI)"
     )
     args = ap.parse_args(argv)
+    from foremast_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     services = 64 if args.small else args.services
     inject = 4 if args.small else args.inject
     result = run(services, inject, args.small)

@@ -14,7 +14,7 @@ Four phases, one JSON line each:
      CPU-host proxy for the per-chip bar, like rounds 7-15).
   3. **scenario matrix** — strategy x regime point-F1 sweep
      (benchmarks/scenarios.py), floors asserted in-run; extends the
-     `fleet_mix` table in BENCHMARKS.md with the strategy dimension.
+     `fleet_mix` table with the strategy dimension.
   4. **fan-in** — the canary fleet fed PURE-PUSH through the real
      ingest receiver by 1 vs 8 concurrent pushers (scenarios.
      FAN_IN_SHAPES): per-shape receiver apply rate, a warm tick judged
@@ -431,6 +431,9 @@ def main(argv=None):
         "--small", action="store_true", help="CPU smoke shapes (CI)"
     )
     args = ap.parse_args(argv)
+    from foremast_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     small = args.small
     if small:
         args.services = min(args.services, 64)

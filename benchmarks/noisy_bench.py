@@ -497,6 +497,9 @@ def main(argv=None):
         "--small", action="store_true", help="CPU smoke shapes (CI)"
     )
     args = ap.parse_args(argv)
+    from foremast_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     services = 96 if args.small else args.services
     inject = 4 if args.small else args.inject
     result = run(services, inject, args.small)

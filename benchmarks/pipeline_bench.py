@@ -189,6 +189,9 @@ def main(argv=None):
         "--small", action="store_true", help="CPU smoke shapes (CI)"
     )
     args = ap.parse_args(argv)
+    from foremast_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     if args.small:
         args.services = min(args.services, 48)
         args.hist_len = min(args.hist_len, 128)

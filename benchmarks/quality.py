@@ -522,6 +522,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--small", action="store_true")
     args = ap.parse_args(argv)
+    from foremast_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     _register_models()
     b = 32 if args.small else 256
     th = 240 if args.small else 1008  # ~10-42 cycles of the 24-step season

@@ -1,8 +1,7 @@
 """Machine-readable bench summaries: ``BENCH_rNN.json`` (ISSUE 15).
 
-BENCHMARKS.md pins each round's numbers as prose; CI cannot diff prose.
-Every ``make bench-*`` entry point now ALSO folds its headline result
-into one JSON artifact per benchmark round at the repo root:
+Every ``make bench-*`` entry point folds its headline result into one
+JSON artifact per benchmark round at the repo root:
 
     BENCH_r17.json
     {
@@ -20,9 +19,8 @@ diffable across PRs. One file per round, one key per bench — re-running
 a bench inside the same round overwrites only its own key.
 
 Round resolution: ``FOREMAST_BENCH_ROUND`` when set (re-running a bench
-for an already-pinned round), else the highest ``## Round N`` heading
-in BENCHMARKS.md **plus one** — a bench run is, by definition, the
-round being measured for the NEXT BENCHMARKS.md entry.
+for an existing round), else the highest existing ``BENCH_rNN.json``
+**plus one** — a bench run is, by definition, the next round.
 
 ``--small`` smoke runs never write (tier-1 tests must not dirty the
 tree); pass ``path`` to redirect (tests use a tmpdir).
@@ -34,11 +32,7 @@ import json
 import os
 import re
 
-# BENCHMARKS.md headings carry the round as "## <title> (round N, ...)"
-# (a plain "## Round N" also counts, future-proofing)
-_ROUND_RE = re.compile(
-    r"^## (?:Round (\d+)|[^\n]*\(round (\d+))", re.MULTILINE | re.IGNORECASE
-)
+_ROUND_RE = re.compile(r"^BENCH_r(\d+)\.json$")
 
 
 def _repo_root() -> str:
@@ -55,12 +49,10 @@ def current_round(root: str | None = None) -> int:
             pass
     root = _repo_root() if root is None else root
     try:
-        with open(os.path.join(root, "BENCHMARKS.md")) as f:
-            rounds = [
-                int(a or b) for a, b in _ROUND_RE.findall(f.read())
-            ]
+        names = os.listdir(root)
     except OSError:
-        rounds = []
+        names = []
+    rounds = [int(m.group(1)) for m in map(_ROUND_RE.match, names) if m]
     return (max(rounds) + 1) if rounds else 1
 
 
@@ -95,7 +87,13 @@ def write_summary(
         rnd = current_round()
         path = os.path.join(_repo_root(), f"BENCH_r{rnd:02d}.json")
     else:
-        rnd = current_round(os.path.dirname(path) or ".")
+        # an explicit BENCH_rNN.json names its own round
+        named = _ROUND_RE.match(os.path.basename(path))
+        rnd = (
+            int(named.group(1))
+            if named
+            else current_round(os.path.dirname(path) or ".")
+        )
     doc = {"round": rnd, "generated_by": "benchmarks.report", "results": {}}
     try:
         with open(path) as f:

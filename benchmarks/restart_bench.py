@@ -565,6 +565,9 @@ def main(argv=None):
         "--ring-points", type=int, default=512, help=argparse.SUPPRESS
     )
     args = ap.parse_args(argv)
+    from foremast_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     if args.child:
         return run_child(args)
     if args.small:

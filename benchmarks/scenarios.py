@@ -362,8 +362,8 @@ def label_shape_routing_cell(
 
 def scenario_matrix(b: int, th: int, tc: int, seed: int = 0) -> list[dict]:
     """The full strategy x regime sweep, one row dict per cell —
-    `make bench-mixed` prints these and BENCHMARKS.md pins them
-    (extends the `fleet_mix` table with the strategy dimension)."""
+    `make bench-mixed` prints these (extends the `fleet_mix` table
+    with the strategy dimension)."""
     rows = []
     for strategy in STRATEGIES:
         for regime in REGIMES:

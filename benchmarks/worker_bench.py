@@ -447,6 +447,9 @@ def main(argv=None):
         help="cProfile the warm ticks into OUT.pstats",
     )
     args = ap.parse_args(argv)
+    from foremast_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     if args.small:
         args.services = min(args.services, 128)
         args.hist_len = min(args.hist_len, 512)

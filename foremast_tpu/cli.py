@@ -246,35 +246,6 @@ def cmd_serve(args: argparse.Namespace) -> int:
     return 0
 
 
-def _enable_compile_cache() -> None:
-    """FOREMAST_COMPILE_CACHE_DIR: point JAX's persistent compilation
-    cache at a durable directory so the 20-40 s per-bucket warmup
-    compiles (`BrainWorker.warmup`) are paid once per binary, not once
-    per process restart — a worker pod restarting on the same image
-    reloads every judgment program from the cache. Must run before the
-    first jax computation; warmup logs hit/miss from the entry counts."""
-    path = os.environ.get("FOREMAST_COMPILE_CACHE_DIR")
-    if not path:
-        return
-    import jax
-
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    # the default gates skip fast/small compiles; the worker wants EVERY
-    # judgment bucket persisted, including sub-second CPU-sized ones
-    for flag, value in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(flag, value)
-        except Exception:  # noqa: BLE001 — older jaxlib without the flag
-            pass
-    logging.getLogger("foremast_tpu.cli").info(
-        "persistent compile cache enabled at %s", path
-    )
-
-
 def _mount_ingest(
     inner, gauge_port: int, router=None, snapshot_dir=None,
     chaos=None, degrade=None, handoff=None, dirty=None,
@@ -406,7 +377,9 @@ def cmd_worker(args: argparse.Namespace) -> int:
 
     setup_logging()  # structured JSON logs at INFO (operational events —
     # claims, warmup, checkpoint, takeovers — are info-level)
-    _enable_compile_cache()  # before ANY jax computation below
+    from foremast_tpu.device import device_info, enable_compile_cache
+
+    cache_dir = enable_compile_cache()  # before ANY jax computation below
     native.ensure_built()  # startup-time compile, never in the hot path
     config = BrainConfig.from_env()
 
@@ -466,6 +439,17 @@ def cmd_worker(args: argparse.Namespace) -> int:
 
         univariate = sharded_univariate(config)
     judge = MultivariateJudge(config, univariate=univariate)
+    # one line saying what this process actually got: a TPU pod that
+    # silently came up on CPU must be visible in its first log lines
+    # (after the judge exists, so --sharded's init_distributed has run)
+    dev = device_info()
+    logging.getLogger("foremast_tpu.cli").info(
+        "worker backend: platform=%s device_kind=%s devices=%d mesh=%s "
+        "compile_cache=%s",
+        dev["platform"], dev["device_kind"], dev["device_count"],
+        dict(univariate.mesh.shape) if univariate is not None else None,
+        cache_dir,
+    )
 
     if pod_mode:
         # followers never dial ES/Prometheus: only the leader needs
@@ -1191,7 +1175,7 @@ def build_parser() -> argparse.ArgumentParser:
         action="store_true",
         help="precompile the scoring programs for the canonical shapes "
         "(claim-limit batch, 7-day history) at startup instead of "
-        "paying the 20-40 s XLA compile inside the first real tick",
+        "paying the XLA compiles inside the first real tick",
     )
     p.add_argument(
         "--gauge-port",

@@ -493,15 +493,6 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
         "leader's value",
     ),
     EnvKnob(
-        "FOREMAST_COMPILE_CACHE_DIR",
-        None,
-        "path",
-        "JAX persistent compilation cache directory: the 20-40 s "
-        "per-bucket warmup compiles are paid once per binary and "
-        "reloaded across process restarts (hit/miss logged at "
-        "`worker --warmup`). Unset = in-memory compile cache only",
-    ),
-    EnvKnob(
         "FOREMAST_ARENA_BYTES",
         "268435456",
         "int",
@@ -1078,8 +1069,8 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
         "int",
         "benchmark-round override for the BENCH_rNN.json summaries "
         "(benchmarks/report.py): set when re-running a bench for an "
-        "already-pinned BENCHMARKS.md round; unset, the round is the "
-        "highest pinned round + 1",
+        "existing round; unset, the round is the highest existing "
+        "BENCH_rNN.json + 1",
     ),
     # -- multi-tenant QoS plane (ISSUE 20)
     EnvKnob(

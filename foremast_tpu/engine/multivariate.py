@@ -101,8 +101,8 @@ MVN_CONFIRM_MARGIN = 1.0
 # job's metric count doesn't fit. `auto` means "pick the best model for
 # the job's shape", so its univariate branch uses the structure screen
 # (flat -> global mean, seasonal/trend -> fitted Holt-Winters; quality
-# table in BENCHMARKS.md). Explicitly-configured bivariate/lstm keep the
-# reference's deployed default for their misfit jobs — the operator chose
+# floors in benchmarks/quality.py). Explicitly-configured bivariate/lstm
+# keep the reference's deployed default for their misfit jobs — the operator chose
 # a specific algorithm, not "best available" (`foremast-brain.yaml:24-25`).
 FALLBACK_UNIVARIATE = "moving_average_all"
 FALLBACK_AUTO = "auto_univariate"
@@ -888,7 +888,8 @@ class MultivariateJudge:
         # calibration is exact only for Gaussian residuals; real HW
         # residuals are heavier-tailed, so points BETWEEN the two cutoffs
         # (borderline by construction — measured FPs land 1.1-1.6x the
-        # base cutoff while true anomalies clear 2x, BENCHMARKS.md) flag
+        # base cutoff while true anomalies clear 2x, benchmarks/quality.py)
+        # flag
         # only with corroboration: the AE reconstruction flags the same
         # point, or a NEIGHBORING point also exceeds the base cutoff (a
         # sustained shift). Fail-fast + AutoRollback semantics

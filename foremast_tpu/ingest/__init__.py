@@ -2,8 +2,8 @@
 
 The scrape-vs-remote-write inversion: instead of the worker HTTP-GETing
 every document's `query_range` URL from Prometheus each tick (the
-reference brain's shape, SURVEY §3.2 — and ~half of a cold tick's wall
-clock, BENCHMARKS.md round 6), pushers remote-write samples INTO the
+reference brain's shape, SURVEY §3.2 — and a large share of a cold
+tick's wall clock), pushers remote-write samples INTO the
 worker's resident ring TSDB and a warm fetch becomes an in-memory
 columnar gather — the same shape as serving an inference stack from a
 resident feature store instead of a remote database.

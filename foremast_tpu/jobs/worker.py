@@ -1044,8 +1044,8 @@ class BrainWorker:
         """Precompile the scoring programs for the canonical shapes.
 
         XLA compiles one program per (B, Th, Tc) bucket triple, and the
-        first compile of the 7-day-history judgment costs 20-40 s on a
-        TPU — paid, without this, inside the first PRODUCTION tick. The
+        first compile of the 7-day-history judgment is paid, without
+        this, inside the first PRODUCTION tick. The
         warmup judges synthetic windows through the SHIPPED judge path at
         EVERY power-of-two batch bucket up to the claim-limit bucket
         (real claim sizes vary, so the first tick can land in any of
@@ -1088,13 +1088,14 @@ class BrainWorker:
             )
             for i in range(b_max)
         ]
-        # persistent-compile-cache accounting (FOREMAST_COMPILE_CACHE_DIR,
-        # enabled at CLI startup): entry counts before/after the sweep
-        # are the honest hit/miss signal — a warm binary adds zero
-        # entries and pays only cache loads
+        # persistent-compile-cache accounting (device.enable_compile_cache
+        # at CLI startup): entry counts before/after the sweep are the
+        # honest hit/miss signal — a warm binary adds zero entries
         import os as _os
 
-        cache_dir = _os.environ.get("FOREMAST_COMPILE_CACHE_DIR")
+        import jax
+
+        cache_dir = jax.config.jax_compilation_cache_dir
 
         def _cache_entries():
             try:
@@ -1146,11 +1147,10 @@ class BrainWorker:
                     cache_after, cache_dir,
                 )
             else:
-                # 0 entries both sides (persistence gates never fired —
-                # e.g. an older jaxlib ignoring the min-compile-time
-                # override) or the dir shrank under us: either way the
-                # compiles were NOT cached; claiming HIT here would tell
-                # the operator the opposite of what happened
+                # 0 entries both sides (nothing persisted) or the dir
+                # shrank under us: either way the compiles were NOT
+                # cached; claiming HIT here would tell the operator the
+                # opposite of what happened
                 log.warning(
                     "compile cache %s persisted nothing during warmup "
                     "(%d entries before, %d after) — persistence "

@@ -99,8 +99,7 @@ def moving_average_all(values: jax.Array, mask: jax.Array) -> Forecast:
 
     Uses `masked_moments` — mean and variance in ONE fused reduction over
     the [B, 10k] history (the two-pass mean-then-centered-squares form
-    reads the 7-day window twice, and this model is pure HBM bandwidth;
-    headline note in BENCHMARKS.md).
+    reads the 7-day window twice, and this model is pure HBM bandwidth).
     """
     b, t_len = values.shape
     if t_len == 0:  # empty-history batch: unmeasurable, not a crash

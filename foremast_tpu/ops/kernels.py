@@ -46,13 +46,11 @@ LANE = 128
 def use_pallas() -> bool:
     """Kernel dispatch gate: FOREMAST_PALLAS=1 opts in.
 
-    Default OFF: measured on a v5e chip at the bench.py shapes, XLA's own
-    fusion of the scoring program beats this kernel at every batch size
-    (B=4096: 379k vs 363k windows/s; B=32768: 1.89M vs 1.26M) — the rank
-    tests dominate and the MA-stats pass is memory-bound either way. The
-    kernel remains the building block for shapes/fusions XLA handles
-    poorly (e.g. much longer histories that blow VMEM-friendly fusion, or
-    future multi-stat one-pass variants)."""
+    Default OFF: no shipped path dispatches the kernel — the rank tests
+    dominate the scoring program and the MA-stats pass is memory-bound
+    either way. Whether it earns a path is a chip measurement that has
+    not been made (ROADMAP D6/S3); `chip_smoke.py` only proves it still
+    lowers and agrees with the XLA program."""
     return os.environ.get("FOREMAST_PALLAS", "") == "1"
 
 

@@ -83,14 +83,9 @@ def _broadcast_raw(obj=None):
     else:
         payload = None
         size = np.zeros(1, np.int64)
-    # np.asarray + explicit dtype restore: depending on jax/collectives
-    # version the broadcast returns the payload UPCAST to a wider
-    # integer type (observed with 0.4.x gloo CPU collectives: uint8 in,
-    # int out — element values correct, so `.tobytes()` silently
-    # interleaves zero bytes and the pickle stream corrupts)
     size = np.asarray(mhu.broadcast_one_to_all(size))
     buf = payload if leader else np.zeros(int(size[0]), np.uint8)
-    buf = np.asarray(mhu.broadcast_one_to_all(buf)).astype(np.uint8)
+    buf = np.asarray(mhu.broadcast_one_to_all(buf))
     return obj if leader else pickle.loads(buf.tobytes())
 
 

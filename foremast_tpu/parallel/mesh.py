@@ -81,17 +81,16 @@ def shard_rows_take(tree, rows, mesh: Mesh):
     all-gather it, which is exactly the cross-chip leg the sharded
     arena exists to delete."""
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
 
     spec = jax.tree.map(
         lambda a: P(DATA_AXIS, *([None] * (np.ndim(a) - 1))), tree
     )
-    return shard_map(
+    return jax.shard_map(
         lambda rs, t: jax.tree.map(lambda a: jnp.take(a, rs, axis=0), t),
         mesh=mesh,
         in_specs=(P(DATA_AXIS), spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )(rows, tree)
 
 
@@ -228,18 +227,6 @@ def assert_partitioned(arr, n_data: int) -> None:
 # ---------------------------------------------------------------------------
 
 
-def _distributed_initialized() -> bool:
-    """`jax.distributed.is_initialized()` with a 0.4.x fallback (the
-    accessor only gained the public spelling in later jax; on 0.4.x the
-    global client being set IS the initialized marker)."""
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    from jax._src import distributed as _dist
-
-    return _dist.global_state.client is not None
-
-
 def init_distributed(
     coordinator_address: str | None = None,
     num_processes: int | None = None,
@@ -268,23 +255,13 @@ def init_distributed(
     )
     if coordinator_address is None and num_processes is None:
         return False  # single-host: nothing to coordinate
-    if _distributed_initialized():
+    if jax.distributed.is_initialized():
         # idempotent: a prior initialize (ours, the runtime's TPU-pod
         # auto-init, or an embedding application's) wins. Re-calling
         # jax.distributed.initialize here would raise the generic
         # "must be called before any JAX calls" error, not a clean
         # already-initialized signal.
         return True
-    # CPU multi-process needs an explicit collectives backend on older
-    # jax (0.4.x): without gloo, cross-process programs raise
-    # "Multiprocess computations aren't implemented on the CPU backend".
-    # Set unconditionally BEFORE backends initialize (probing the
-    # backend here would itself initialize it); the option only affects
-    # the CPU client and disappears once the default grows collectives.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — newer jax handles this itself
-        pass
     # a connect or barrier failure surfaces to the caller — swallowing it
     # would leave this process on a local-only "global" mesh while its
     # peers hang at the init barrier

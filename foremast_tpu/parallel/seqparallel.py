@@ -34,16 +34,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.5 top-level export
-    from jax import shard_map
-except ImportError:  # 0.4.x: experimental module, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_04(f, **kw)
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from foremast_tpu.ops.forecasters import _linrec_assoc as _compose

@@ -420,8 +420,7 @@ def test_elastic_bench_small_smoke(capsys):
 def test_plane_bench_small_smoke():
     """Watch-plane scale benchmark (VERDICT r5 #7) at CI shapes: the
     informer resync and the controller poll tick must run and stay
-    inside the ~1 s budget (at 10k monitors the measured full-scale
-    numbers are ~12/48 ms — BENCHMARKS.md)."""
+    inside the ~1 s budget."""
     from benchmarks.plane_bench import run
 
     out = run(monitors=64, ticks=2)
@@ -450,7 +449,7 @@ def test_mixed_univariate_joint_worker_tick():
     fleet warm (univariate docs on the columnar fast path, joint docs on
     the slow path — in the same tick). Small CI shapes; at benchmark
     size (per_uni=24, per_joint=4) every kind measures F1 = 1.0 with 0
-    false alarms (BENCHMARKS.md mixed-tick row)."""
+    false alarms."""
     from benchmarks.quality import mixed_fleet_tick
 
     by_kind, false_alarms = mixed_fleet_tick(4, 2, 240, 30)
@@ -526,12 +525,13 @@ def test_mixed_bench_label_shape_routing_small():
 def test_bench_report_round_and_merge(tmp_path, monkeypatch):
     """BENCH_rNN.json emission (ISSUE 15 satellite): summaries merge
     per bench under one round file, --small runs never write, and the
-    round resolves from BENCHMARKS.md's highest pinned round + 1."""
+    round resolves from the highest existing BENCH_rNN.json + 1."""
     from benchmarks import report
 
     # the env override must not leak into the resolution assertions
     monkeypatch.delenv("FOREMAST_BENCH_ROUND", raising=False)
 
+    assert report.current_round(str(tmp_path)) == 1  # no artifacts yet
     path = str(tmp_path / "BENCH_r99.json")
     assert report.write_summary("latency", {"p99": 0.4}, small=True) is None
     out = report.write_summary("latency", {"p99": 0.4}, path=path)
@@ -539,19 +539,20 @@ def test_bench_report_round_and_merge(tmp_path, monkeypatch):
     report.write_summary("mixed", {"wps": 1.0}, path=path)
     with open(path) as f:
         doc = json.load(f)
+    assert doc["round"] == 99  # an explicit BENCH_rNN.json names its round
     assert set(doc["results"]) == {"latency", "mixed"}
     assert doc["results"]["latency"]["asserts_passed"] is True
     assert doc["results"]["latency"]["p99"] == 0.4
-    # round resolution: highest pinned round + 1, in BOTH heading
-    # spellings ("## Round N" and "## <title> (round N, ...)")
-    md = tmp_path / "BENCHMARKS.md"
-    md.write_text(
-        "## Round 3\n\nstuff\n\n"
-        "## Columnar canary: fast path (round 12, `make bench-mixed`)\n"
-    )
-    assert report.current_round(str(tmp_path)) == 13
-    # the REAL BENCHMARKS.md resolves to a round past every pinned one
-    assert report.current_round() >= 17
+    # round resolution: highest existing artifact + 1; other files and
+    # near-miss names do not count
+    (tmp_path / "BENCH_r07.json").write_text("{}")
+    (tmp_path / "BENCH_r100.json.tmp").write_text("{}")
+    assert report.current_round(str(tmp_path)) == 100
+    monkeypatch.setenv("FOREMAST_BENCH_ROUND", "12")
+    assert report.current_round(str(tmp_path)) == 12
+    monkeypatch.delenv("FOREMAST_BENCH_ROUND")
+    # the REAL checkout resolves to a round past every committed artifact
+    assert report.current_round() >= 21
     # a foreign-schema artifact (e.g. the driver's own BENCH_rNN.json)
     # is never clobbered — loud failure, not silent overwrite
     foreign = tmp_path / "BENCH_r01.json"

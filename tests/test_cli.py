@@ -181,33 +181,38 @@ def test_score_honors_env_config(tmp_path, capsys, monkeypatch):
     assert resp["status"] == "anomaly"
 
 
-def test_enable_compile_cache_sets_jax_config(tmp_path, monkeypatch):
-    """FOREMAST_COMPILE_CACHE_DIR points JAX's persistent compilation
-    cache at a durable dir (and creates it) so warmup compiles survive
-    process restarts; unset, the knob must be a no-op."""
+def test_compile_cache_resolver(tmp_path, monkeypatch):
+    """device.enable_compile_cache: with JAX_COMPILATION_CACHE_DIR set
+    JAX has already read it, so the resolver sets NO directory; unset,
+    the cache sits at the fixed <checkout>/.jax_cache (the path is part
+    of the cache key — never a temp dir). Both threshold overrides are
+    applied either way."""
+    import os
+
     import jax
 
-    from foremast_tpu.cli import _enable_compile_cache
+    from foremast_tpu import device
 
     flags = (
         "jax_compilation_cache_dir",
         "jax_persistent_cache_min_compile_time_secs",
         "jax_persistent_cache_min_entry_size_bytes",
     )
-    prev = {f: getattr(jax.config, f) for f in flags if hasattr(jax.config, f)}
-    target = tmp_path / "xla-cache"
-    monkeypatch.setenv("FOREMAST_COMPILE_CACHE_DIR", str(target))
+    prev = {f: getattr(jax.config, f) for f in flags}
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     try:
-        _enable_compile_cache()
-        assert jax.config.jax_compilation_cache_dir == str(target)
-        assert target.is_dir()
+        # set from outside: whatever JAX holds stays untouched
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert device.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = os.path.join(repo, ".jax_cache")
+        assert device.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
     finally:
-        # restore: a tmp_path-bound cache dir must not outlive the test
         for f, v in prev.items():
             jax.config.update(f, v)
-
-    monkeypatch.delenv("FOREMAST_COMPILE_CACHE_DIR")
-    _enable_compile_cache()  # unset: no-op, config untouched
-    assert jax.config.jax_compilation_cache_dir == prev.get(
-        "jax_compilation_cache_dir"
-    )

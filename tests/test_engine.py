@@ -667,7 +667,7 @@ def test_device_state_counters_monotone_across_rebuilds():
 
 
 def test_bf16_delta_scorer_matches_f32_and_keeps_low_cv_bands():
-    """FOREMAST_BF16_DELTA variant (BENCHMARKS.md roofline): the
+    """FOREMAST_BF16_DELTA variant (half the history bytes): the
     anchor-shifted bf16-delta moving_average_all scorer must reproduce
     f32 verdicts/flags on realistic data, and — the round-3 refusal
     case — keep band geometry on LOW-CV series (value 100 +- 0.1, where

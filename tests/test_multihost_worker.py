@@ -85,10 +85,6 @@ import os, sys, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:  # CPU multi-process collectives (older jax needs explicit gloo)
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:
-    pass
 addr, pid = sys.argv[1], int(sys.argv[2])
 jax.distributed.initialize(addr, 2, pid)
 

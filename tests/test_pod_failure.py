@@ -86,10 +86,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 os.environ["FOREMAST_POD_TIMEOUT_SECONDS"] = "60"
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:
-    pass
 addr, pid, es_url = sys.argv[1], int(sys.argv[2]), sys.argv[3]
 jax.distributed.initialize(addr, 2, pid)
 
