@@ -66,24 +66,17 @@ def main():
     res = run(batch)
     jax.block_until_ready(res.verdict)
 
-    import contextlib
     import statistics
 
-    from foremast_tpu.observe.profile import trace_scoring
-
-    # Median of REPEATS timed loops. FOREMAST_PROFILE=<dir> dumps a
-    # jax.profiler trace of the FIRST timed loop only (one loop is enough
-    # to read, and repeats would triple the trace).
+    # Median of REPEATS timed loops.
     REPEATS = 3
     times = []
-    for rep in range(REPEATS):
-        ctx = trace_scoring() if rep == 0 else contextlib.nullcontext()
-        with ctx:
-            t0 = time.perf_counter()
-            for _ in range(ITERS):
-                res = run(batch)
-            jax.block_until_ready(res.verdict)
-            times.append(time.perf_counter() - t0)
+    for _rep in range(REPEATS):
+        t0 = time.perf_counter()
+        for _ in range(ITERS):
+            res = run(batch)
+        jax.block_until_ready(res.verdict)
+        times.append(time.perf_counter() - t0)
 
     windows_per_sec = B * ITERS / statistics.median(times)
     result = {

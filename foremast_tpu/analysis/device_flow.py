@@ -338,7 +338,7 @@ def _sink_findings(taint: _Taint, fn: FunctionInfo) -> list[Finding]:
                 "stages",
                 hint="keep the value device-resident until the designated "
                 "gather (`ColumnarPending.wait` / `_fetch`), or — if this "
-                "function IS a gather/decode stage — annotate the def with "
+                "function IS a gather/decode stage — mark the def with "
                 "`# foremast: device-boundary` and document the contract "
                 "(docs/static-analysis.md)",
             )

@@ -114,7 +114,7 @@ def _arena_findings(fn) -> list[Finding]:
                 "placement (ShardedJudge._arena_sharding, ISSUE 19); "
                 "code that touches them from parallel/ must declare it "
                 "honors that layout",
-                hint="annotate the enclosing def (or this line) with "
+                hint="mark the enclosing def (or this line) with "
                 "`# foremast: sharded-arena` after checking the access "
                 "keeps row placement aligned with batch position (local "
                 "indices into shard_map gathers, no row-axis re-blocking) "

@@ -1018,12 +1018,6 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
         "disables the buffer (stage histograms stay on)",
     ),
     EnvKnob(
-        "FOREMAST_PROFILE",
-        None,
-        "path",
-        "dump jax.profiler traces around scoring",
-    ),
-    EnvKnob(
         "FOREMAST_LOCK_WITNESS",
         None,
         "bool",

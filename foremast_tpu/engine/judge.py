@@ -22,7 +22,7 @@ import numpy as np
 
 from foremast_tpu.config import BrainConfig
 from foremast_tpu.engine import scoring
-from foremast_tpu.observe.spans import span
+from foremast_tpu.observe.spans import note, span
 from foremast_tpu.ops.windows import MetricWindows
 
 log = logging.getLogger("foremast_tpu.judge")
@@ -678,7 +678,7 @@ class HealthJudge:
                 stage="arena_assemble",
                 rows=len(keys),
                 device=True,
-            ):
+            ) as sp:
                 assigned = arena.assign(keys, force, n_real)
                 if assigned is not None and assigned[1]:
                     m_scat = max(len(entries[i][2]) for i in assigned[1])
@@ -690,6 +690,8 @@ class HealthJudge:
                         assigned = arena.assign(keys, force, n_real)
                     if assigned is not None and assigned[1]:
                         arena.scatter(assigned[0], assigned[1], entries)
+                if assigned is not None:
+                    note(sp, scattered=len(assigned[1]))
             if assigned is not None:
                 with span(
                     "judge.score", stage="score", rows=len(keys), device=True

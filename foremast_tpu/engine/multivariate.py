@@ -75,7 +75,7 @@ from foremast_tpu.models.residual_mvn import (
     fit_residual_mvn_bf16_delta,
     residual_mvn_d2_robust,
 )
-from foremast_tpu.observe.spans import span
+from foremast_tpu.observe.spans import note, span
 from foremast_tpu.ops.forecasters import Forecast
 from foremast_tpu.ops.windows import MetricWindows
 
@@ -283,6 +283,10 @@ def lstm_joint_score_from_rows(state, rows, x, mask, cut, cutoff, hi_cutoff, gap
     reconstruction check and the echo-robust residual-MVN check, and
     applies the confirmation-band corroboration rule — exactly the
     `_judge_lstm_group` scoring tail, with zero per-tick state upload.
+    Its four phases carry `jax.named_scope`s (`gather_rows`, `ae_score`,
+    `hw_continue`, `mvn_judge` — here, in `score_rows_cutoff` and in
+    `residual_mvn._d2`), so a device trace's op names say which phase an
+    op belongs to.
 
     state: TreeArena pytree — `ae` (stacked AEParams), `level`/`trend`/
     `season`/`phase` (per-metric HW terminal state, season tiled to the
@@ -295,10 +299,11 @@ def lstm_joint_score_from_rows(state, rows, x, mask, cut, cutoff, hi_cutoff, gap
     ae_flags, _err = score_rows_cutoff(
         state["ae"], rows, x, mask[:, None, :], cut
     )
-    st = jax.tree.map(
-        lambda leaf: jnp.take(leaf, rows, axis=0),
-        {k: v for k, v in state.items() if k != "ae"},
-    )
+    with jax.named_scope("gather_rows"):
+        st = jax.tree.map(
+            lambda leaf: jnp.take(leaf, rows, axis=0),
+            {k: v for k, v in state.items() if k != "ae"},
+        )
     return _lstm_joint_judgment(
         ae_flags[:, 0, :], st, x, mask, cutoff, hi_cutoff, gaps
     )
@@ -316,10 +321,12 @@ def lstm_joint_score_from_rows_sharded(
     zero cross-chip transfer — before the identical judgment tail."""
     from foremast_tpu.parallel import mesh as meshlib
 
-    gathered = meshlib.shard_rows_take(state, rows, mesh)
-    ae_flags, _err = score_many_cutoff(
-        gathered["ae"], x, mask[:, None, :], cut
-    )
+    with jax.named_scope("gather_rows"):
+        gathered = meshlib.shard_rows_take(state, rows, mesh)
+    with jax.named_scope("ae_score"):
+        ae_flags, _err = score_many_cutoff(
+            gathered["ae"], x, mask[:, None, :], cut
+        )
     st = {k: v for k, v in gathered.items() if k != "ae"}
     return _lstm_joint_judgment(
         ae_flags[:, 0, :], st, x, mask, cutoff, hi_cutoff, gaps
@@ -333,36 +340,41 @@ def _lstm_joint_judgment(ae_flags, st, x, mask, cutoff, hi_cutoff, gaps):
     per-capacity) non-AE state dict."""
     s, f = x.shape[0], x.shape[-1]
     m = st["season"].shape[-1]
-    gap = gaps.astype(jnp.int32)
-    # phase advances by the TRUE gap (mod m); only the trend
-    # extrapolation is bounded — same rule as the object path and the
-    # univariate scorer's _advance_gap
-    phase = ((st["phase"] + gap[:, None]) % m).astype(jnp.int32)
-    level = st["level"] + st["trend"] * jnp.minimum(
-        gap, scoring.GAP_TREND_CAP_STEPS
-    ).astype(jnp.float32)[:, None]
-    hw = Forecast(
-        pred=jnp.zeros((s * f, 0), jnp.float32),
-        scale=jnp.zeros((s * f,), jnp.float32),
-        level=level.reshape(-1),
-        trend=st["trend"].reshape(-1),
-        season=st["season"].reshape(s * f, m),
-        season_phase=phase.reshape(-1),
-    )
+    with jax.named_scope("hw_continue"):
+        gap = gaps.astype(jnp.int32)
+        # phase advances by the TRUE gap (mod m); only the trend
+        # extrapolation is bounded — same rule as the object path and
+        # the univariate scorer's _advance_gap
+        phase = ((st["phase"] + gap[:, None]) % m).astype(jnp.int32)
+        level = st["level"] + st["trend"] * jnp.minimum(
+            gap, scoring.GAP_TREND_CAP_STEPS
+        ).astype(jnp.float32)[:, None]
+        hw = Forecast(
+            pred=jnp.zeros((s * f, 0), jnp.float32),
+            scale=jnp.zeros((s * f,), jnp.float32),
+            level=level.reshape(-1),
+            trend=st["trend"].reshape(-1),
+            season=st["season"].reshape(s * f, m),
+            season_phase=phase.reshape(-1),
+        )
     mvn = MVNState(hw=hw, mu=st["rmu"], cov=st["cov"], valid=st["valid"])
     cur_sf = jnp.swapaxes(x[:, 0], 1, 2)  # [S, F, tc]
+    # the two passes' `hw_continue` loops and `mvn_judge` solves carry
+    # their scopes from `residual_mvn._d2`
     d2 = residual_mvn_d2_robust(mvn, cur_sf, cutoff)
-    # confirmation band (see _judge_lstm_group): strong evidence flags
-    # alone; borderline needs AE agreement or a BORDERLINE neighbor
-    valid = st["valid"][:, None] & mask
-    over = (d2 > cutoff[:, None]) & valid
-    strong = (d2 > hi_cutoff[:, None]) & valid
-    border = over & ~strong
-    neighbor = jnp.pad(border[:, :-1], ((0, 0), (1, 0))) | jnp.pad(
-        border[:, 1:], ((0, 0), (0, 1))
-    )
-    mvn_flags = strong | (border & (ae_flags | neighbor))
-    return ae_flags | mvn_flags
+    with jax.named_scope("mvn_judge"):
+        # confirmation band (see _judge_lstm_group): strong evidence
+        # flags alone; borderline needs AE agreement or a BORDERLINE
+        # neighbor
+        valid = st["valid"][:, None] & mask
+        over = (d2 > cutoff[:, None]) & valid
+        strong = (d2 > hi_cutoff[:, None]) & valid
+        border = over & ~strong
+        neighbor = jnp.pad(border[:, :-1], ((0, 0), (1, 0))) | jnp.pad(
+            border[:, 1:], ((0, 0), (0, 1))
+        )
+        mvn_flags = strong | (border & (ae_flags | neighbor))
+        return ae_flags | mvn_flags
 
 
 class MultivariateJudge:
@@ -1290,48 +1302,55 @@ class MultivariateJudge:
         claim-size jitter cannot force recompiles."""
         s0, f, tcb = cur.shape
         thr = float(self.config.anomaly.rule_for(None).threshold)
-        m_need = (
-            1
-            if mode == "bivariate"
-            else max(e[3][2].shape[-1] for e in entries)
-        )
-        arena = self._joint_arena_for(mode, f, m_need)
-        # batch target shape FIRST (pow2 bucket + data-axis rounding,
-        # same rule as judge_columnar) — a sharded arena's assign must
-        # see the PADDED position list, because row placement is a
-        # function of position // (B / shards)
-        sb = bucket_length(s0)
-        mult = self._joint_multiple()
-        if mult > 1 and sb % mult:
-            sb += mult - sb % mult
+        # Stage spans, in order, siblings on the tick thread: joint_prep
+        # (pack) -> arena_assemble -> joint_prep (pack) -> h2d -> score
+        # -> decode. Every host array is built BEFORE the h2d span and
+        # the score span holds the jitted call alone.
+        with span("judge.joint_prep", stage="pack", rows=s0):
+            m_need = (
+                1
+                if mode == "bivariate"
+                else max(e[3][2].shape[-1] for e in entries)
+            )
+            arena = self._joint_arena_for(mode, f, m_need)
+            # batch target shape FIRST (pow2 bucket + data-axis rounding,
+            # same rule as judge_columnar) — a sharded arena's assign
+            # must see the PADDED position list, because row placement is
+            # a function of position // (B / shards)
+            sb = bucket_length(s0)
+            mult = self._joint_multiple()
+            if mult > 1 and sb % mult:
+                sb += mult - sb % mult
+            if arena is not None:
+                re_ = arena.row_entry
+                force = [
+                    i
+                    for i, (k, e) in enumerate(zip(keys, entries))
+                    if re_.get(k) is not None and re_.get(k) is not e
+                ]
+                keys_a, entries_a = keys, entries
+                if arena.shards > 1 and sb != s0:
+                    # shard-qualified pad keys (ISSUE 19): one stable pad
+                    # row per data-axis block (same contract as the
+                    # univariate "__pad__col__@N" family — a single
+                    # shared key would migrate between blocks as s0
+                    # jitters); mask all-False keeps the pad rows' flags
+                    # inert
+                    per = sb // arena.shards
+                    keys_a = list(keys) + [
+                        f"__pad__joint__@{(s0 + j) // per}"
+                        for j in range(sb - s0)
+                    ]
+                    entries_a = list(entries) + [entries[0]] * (sb - s0)
         rows = None
         state = None
         if arena is not None:
-            re_ = arena.row_entry
-            force = [
-                i
-                for i, (k, e) in enumerate(zip(keys, entries))
-                if re_.get(k) is not None and re_.get(k) is not e
-            ]
-            keys_a, entries_a = keys, entries
-            if arena.shards > 1 and sb != s0:
-                # shard-qualified pad keys (ISSUE 19): one stable pad
-                # row per data-axis block (same contract as the
-                # univariate "__pad__col__@N" family — a single shared
-                # key would migrate between blocks as s0 jitters);
-                # mask all-False keeps the pad rows' flags inert
-                per = sb // arena.shards
-                keys_a = list(keys) + [
-                    f"__pad__joint__@{(s0 + j) // per}"
-                    for j in range(sb - s0)
-                ]
-                entries_a = list(entries) + [entries[0]] * (sb - s0)
             with span(
                 "judge.arena_assemble",
                 stage="arena_assemble",
                 rows=s0,
                 device=True,
-            ):
+            ) as sp:
                 assigned = arena.assign(keys_a, force, s0)
                 if assigned is not None:
                     rows_idx, scat = assigned
@@ -1345,97 +1364,64 @@ class MultivariateJudge:
                         arena.scatter(rows_idx, scat, trees)
                     state = arena.state
                     rows = rows_idx
-        if rows is None:
-            # arena disabled or batch over the hard byte cap: one-off
-            # host stack + upload — counted, never silent (same contract
-            # as the univariate fallback)
-            if arena is not None:
-                self._joint_counters_base["fallbacks"] += 1
-                log.warning(
-                    "joint arena fallback: %d %s rows exceed the hard "
-                    "cap — full state restack this tick; raise "
-                    "FOREMAST_ARENA_MAX_BYTES",
-                    s0,
-                    mode,
+                    note(sp, scattered=len(scat))
+        with span("judge.joint_prep", stage="pack", rows=sb):
+            stacked = None
+            if rows is None:
+                # arena disabled or batch over the hard byte cap: one-off
+                # host stack + upload — counted, never silent (same
+                # contract as the univariate fallback)
+                if arena is not None:
+                    self._joint_counters_base["fallbacks"] += 1
+                    log.warning(
+                        "joint arena fallback: %d %s rows exceed the hard "
+                        "cap — full state restack this tick; raise "
+                        "FOREMAST_ARENA_MAX_BYTES",
+                        s0,
+                        mode,
+                    )
+                stacked = jax.tree.map(
+                    lambda *ls: np.stack(ls),
+                    *[self._row_tree(mode, e, m_need) for e in entries],
                 )
-            trees = [
-                self._row_tree(mode, e, m_need) for e in entries
-            ]
-            state = jax.tree.map(
-                lambda *ls: jnp.asarray(np.stack(ls)), *trees
-            )
-            rows = np.arange(s0, dtype=np.int64)
-        # data-axis rounding (ISSUE 13): same rule as judge_columnar —
-        # a sharded univariate judge means the joint programs partition
-        # over the same mesh, so S must divide by its data axis. A
-        # sharded arena assigned real pad rows above (rows is already
-        # sb-long); the replicated/stacked layouts pad by duplicating
-        # row 0 with an all-False mask: flags all-False, dropped on the
-        # [:s0] decode.
-        self.batch_rows_total += sb
-        self.pad_rows_total += sb - s0
-        if sb != s0:
-            pad = sb - s0
-            cur = np.concatenate(
-                [cur, np.zeros((pad, f, tcb), np.float32)]
-            )
-            mask = np.concatenate([mask, np.zeros((pad, tcb), bool)])
-            if len(rows) != sb:
-                rows = np.concatenate(
-                    [rows, np.full(pad, rows[0], rows.dtype)]
+                rows = np.arange(s0, dtype=np.int64)
+            # data-axis rounding (ISSUE 13): same rule as judge_columnar
+            # — a sharded univariate judge means the joint programs
+            # partition over the same mesh, so S must divide by its data
+            # axis. A sharded arena assigned real pad rows above (rows is
+            # already sb-long); the replicated/stacked layouts pad by
+            # duplicating row 0 with an all-False mask: flags all-False,
+            # dropped on the [:s0] decode.
+            self.batch_rows_total += sb
+            self.pad_rows_total += sb - s0
+            if sb != s0:
+                pad = sb - s0
+                cur = np.concatenate(
+                    [cur, np.zeros((pad, f, tcb), np.float32)]
                 )
-            if gaps is not None:
-                gaps = np.concatenate([gaps, np.zeros(pad, np.int32)])
-        # sharded-arena dispatch (ISSUE 19): when the joint arena row
-        # space is block-partitioned over the data axis, ship LOCAL
-        # (per-shard) indices through the same placement hook as the
-        # batch buffers and run the shard_map from-rows programs —
-        # device-local gather, zero cross-chip transfer. The stacked
-        # fallback (state is not arena.state) keeps global rows + the
-        # replicated programs.
-        sharded = (
-            arena is not None
-            and arena.shards > 1
-            and state is arena.state
-        )
-        if sharded:
-            (rows_j,) = self._place_joint(
-                (rows % arena.cap_s).astype(np.int32)
+                mask = np.concatenate([mask, np.zeros((pad, tcb), bool)])
+                if len(rows) != sb:
+                    rows = np.concatenate(
+                        [rows, np.full(pad, rows[0], rows.dtype)]
+                    )
+                if gaps is not None:
+                    gaps = np.concatenate([gaps, np.zeros(pad, np.int32)])
+            # sharded-arena dispatch (ISSUE 19): when the joint arena row
+            # space is block-partitioned over the data axis, ship LOCAL
+            # (per-shard) indices through the same placement hook as the
+            # batch buffers and run the shard_map from-rows programs —
+            # device-local gather, zero cross-chip transfer. The stacked
+            # fallback keeps global rows + the replicated programs.
+            sharded = (
+                arena is not None and arena.shards > 1 and stacked is None
             )
-            rows_j = jnp.asarray(rows_j)
-            mesh = self.univariate.mesh
-        else:
-            rows_j = jnp.asarray(rows)
-        with span(
-            "judge.score", stage="score", rows=sb, device=True
-        ):
+            if sharded:
+                rows = (rows % arena.cap_s).astype(np.int32)
+            thr_arr = np.full(sb, thr, np.float32)
             if mode == "bivariate":
-                bx, by, bm = self._place_joint(
-                    cur[:, 0], cur[:, 1], mask
-                )
-                if sharded:
-                    flags = detect_bivariate_from_rows_sharded(
-                        state["mean"],
-                        state["cov"],
-                        rows_j,
-                        jnp.asarray(bx),
-                        jnp.asarray(by),
-                        jnp.asarray(bm),
-                        jnp.full((sb,), thr, jnp.float32),
-                        mesh=mesh,
-                    )
-                else:
-                    flags = detect_bivariate_from_rows(
-                        state["mean"],
-                        state["cov"],
-                        rows_j,
-                        jnp.asarray(bx),
-                        jnp.asarray(by),
-                        jnp.asarray(bm),
-                        jnp.full((sb,), thr, jnp.float32),
-                    )
+                host = (cur[:, 0], cur[:, 1], mask)
+                operands = (thr_arr,)
             else:
-                thr_arr = np.full(sb, thr, np.float32)
                 cut = ae_cutoff(
                     np.asarray([e[1] for e in entries] + [1.0] * (sb - s0)),
                     np.asarray([e[2] for e in entries] + [1.0] * (sb - s0)),
@@ -1447,35 +1433,52 @@ class MultivariateJudge:
                     chi2_quantile(thr + MVN_CONFIRM_MARGIN, f),
                     np.float32,
                 )
-                xh, mh = self._place_joint(
+                host = (
                     np.ascontiguousarray(cur.transpose(0, 2, 1))[:, None],
                     mask,
                 )
-                gaps_j = jnp.asarray(
-                    gaps if gaps is not None else np.zeros(sb, np.int32)
+                operands = (
+                    cut,
+                    cutoff,
+                    hi,
+                    gaps if gaps is not None else np.zeros(sb, np.int32),
                 )
+        with span("judge.h2d", stage="h2d", rows=sb, device=True) as sp:
+            # everything the dispatch hands to the device: batch buffers
+            # and (sharded) local rows through the placement hook, the
+            # per-row operands, and the stacked fallback's whole state
+            handed = [rows, *host, *operands]
+            if stacked is not None:
+                handed += jax.tree.leaves(stacked)
+                state = jax.tree.map(jnp.asarray, stacked)
+            note(sp, bytes=sum(int(a.nbytes) for a in handed))
+            if sharded:
+                (rows,) = self._place_joint(rows)
+            rows_j = jnp.asarray(rows)
+            placed = [jnp.asarray(a) for a in self._place_joint(*host)]
+            operands = [jnp.asarray(a) for a in operands]
+        mesh = self.univariate.mesh if sharded else None
+        with span(
+            "judge.score", stage="score", rows=sb, device=True
+        ):
+            if mode == "bivariate":
                 if sharded:
-                    flags = lstm_joint_score_from_rows_sharded(
-                        state,
-                        rows_j,
-                        jnp.asarray(xh),
-                        jnp.asarray(mh),
-                        jnp.asarray(cut),
-                        jnp.asarray(cutoff),
-                        jnp.asarray(hi),
-                        gaps_j,
-                        mesh=mesh,
+                    flags = detect_bivariate_from_rows_sharded(
+                        state["mean"], state["cov"], rows_j,
+                        *placed, *operands, mesh=mesh,
                     )
                 else:
-                    flags = lstm_joint_score_from_rows(
-                        state,
-                        rows_j,
-                        jnp.asarray(xh),
-                        jnp.asarray(mh),
-                        jnp.asarray(cut),
-                        jnp.asarray(cutoff),
-                        jnp.asarray(hi),
-                        gaps_j,
+                    flags = detect_bivariate_from_rows(
+                        state["mean"], state["cov"], rows_j,
+                        *placed, *operands,
                     )
+            elif sharded:
+                flags = lstm_joint_score_from_rows_sharded(
+                    state, rows_j, *placed, *operands, mesh=mesh
+                )
+            else:
+                flags = lstm_joint_score_from_rows(
+                    state, rows_j, *placed, *operands
+                )
         with span("judge.decode", stage="decode", rows=sb, device=True):
             return np.asarray(flags)[:s0]

@@ -72,6 +72,8 @@ import queue
 import threading
 import time
 
+from foremast_tpu.observe.spans import span
+
 log = logging.getLogger("foremast_tpu.pipeline")
 
 DEFAULT_DEPTH = 2
@@ -120,6 +122,8 @@ class PipelineStats:
         "judge_seconds",
         "write_seconds",
         "judge_stall_seconds",
+        "write_wait_seconds",
+        "drain_seconds",
         "write_queue_peak",
         "wall_seconds",
     )
@@ -138,6 +142,11 @@ class PipelineStats:
         # time the judge stage spent waiting for its chunk's windows —
         # the device sat idle for exactly this long
         self.judge_stall_seconds = 0.0
+        # the tick thread's two other waits (each also a `wait` stage
+        # span): blocked handing a judged chunk to a full writer queue,
+        # and the end-of-run wait for the writer to finish
+        self.write_wait_seconds = 0.0
+        self.drain_seconds = 0.0
         self.write_queue_peak = 0
         self.wall_seconds = 0.0
 
@@ -161,6 +170,8 @@ class PipelineStats:
             "judge_seconds": round(self.judge_seconds, 4),
             "write_seconds": round(self.write_seconds, 4),
             "device_idle_seconds": round(self.judge_stall_seconds, 4),
+            "write_wait_seconds": round(self.write_wait_seconds, 4),
+            "drain_seconds": round(self.drain_seconds, 4),
             "write_queue_peak": self.write_queue_peak,
             "wall_seconds": round(self.wall_seconds, 4),
             "overlap_ratio": round(self.overlap_ratio(), 4),
@@ -326,6 +337,14 @@ class ChunkPipeline:
                 (chunk, self.prefetch_pool.submit(timed_fetch, chunk))
             )
 
+        def put_judged(item, i: int) -> None:
+            # blocks while the writer is `depth` chunks behind
+            t0 = time.perf_counter()
+            with span("pipeline.wait_writer", stage="wait", slice=i):
+                wq.put(item)
+            stats.write_wait_seconds += time.perf_counter() - t0
+
+        i = -1  # index of the chunk being judged (the wait spans' attr)
         try:
             for _ in range(self.depth - 1):
                 submit_next()
@@ -334,8 +353,10 @@ class ChunkPipeline:
                     break  # writer failed; don't burn device time on
                     # a judgment whose result could never be written
                 chunk, fut = pending.popleft()
+                i += 1
                 t0 = time.perf_counter()
-                fetch_s, payload = fut.result()
+                with span("pipeline.wait_prefetch", stage="wait", slice=i):
+                    fetch_s, payload = fut.result()
                 stats.judge_stall_seconds += time.perf_counter() - t0
                 stats.fetch_seconds += fetch_s
                 if payload is END:
@@ -363,12 +384,12 @@ class ChunkPipeline:
                     # the finally block drains it before `error`
                     # propagates off the tick thread
                     stats.judge_seconds += time.perf_counter() - t1
-                    wq.put((chunk, se.result))
+                    put_judged((chunk, se.result), i)
                     raise se.error
                 stats.judge_seconds += time.perf_counter() - t1
                 if write_errors:
                     break  # writer failed mid-judgment; stop feeding
-                wq.put((chunk, result))
+                put_judged((chunk, result), i)
                 # measured after the put: the peak reflects queued
                 # chunks only, so it never exceeds the documented
                 # `depth` bound even while the put above is blocking
@@ -384,8 +405,11 @@ class ChunkPipeline:
             # stage thread outlives the tick. The sentinel put cannot
             # deadlock on a full queue — the writer only exits on the
             # sentinel, so it keeps freeing slots until it sees it.
-            wq.put(_DONE)
-            wt.join()
+            t0 = time.perf_counter()
+            with span("pipeline.drain", stage="wait", slice=i):
+                wq.put(_DONE)
+                wt.join()
+            stats.drain_seconds += time.perf_counter() - t0
             stats.write_seconds += write_seconds[0]
             for chunk, fut in pending:
                 if fut.cancel():

@@ -58,7 +58,7 @@ from foremast_tpu.mesh.routing import doc_route_key
 from foremast_tpu.metrics.promql import decode_config
 from foremast_tpu.metrics.source import MetricSource
 from foremast_tpu.observe.logs import ctx_log
-from foremast_tpu.observe.spans import inherit_span, span
+from foremast_tpu.observe.spans import inherit_span, note, span
 
 log = logging.getLogger("foremast_tpu.worker")
 
@@ -1711,97 +1711,102 @@ class BrainWorker:
         demoted: list = []
         counts = {"univariate": 0, "bivariate": 0, "lstm": 0}
         groups: dict = {}
-        for (doc, end_epoch, jinfo), series in ok_joint:
-            mode = jinfo[0]
-            times = [s[0] for s in series]
-            vals = [s[1] for s in series]
-            t0 = np.asarray(times[0], np.int64)
-            # all-equal shortcut requires STRICTLY INCREASING stamps:
-            # align_series dedups repeated timestamps (first occurrence)
-            # and sorts — a raw trace with duplicates must take the same
-            # path so fast and object verdicts cannot diverge
-            if (
-                len(t0) > 0
-                and bool(np.all(np.diff(t0) > 0))
-                and all(
-                    len(t) == len(t0) and np.array_equal(t, t0)
-                    for t in times[1:]
-                )
-            ):
-                ct = t0
-                cv = np.stack(
-                    [np.asarray(v, np.float32) for v in vals]
-                )
-            else:
-                ct, cv = align_series(times, vals)
-            n = len(ct)
-            if n == 0:
-                # no joint observation: UNKNOWN, object-path parity
-                # (`_unknown` — baseline-less pairwise is (1.0, False))
-                self._decide_status(doc, UNKNOWN, {}, now, end_epoch)
-                self._log_judged(doc)
-                updated.append(doc)
-                counts[mode] += 1
-                if observe:
-                    observe(doc.status, len(jinfo[1]))
-                if hook:
-                    vs = [
-                        MetricVerdict(
-                            job_id=doc.id,
-                            alias=alias,
-                            verdict=UNKNOWN,
-                            anomaly_pairs=[],
-                            upper=np.zeros(len(vals[f_i]), np.float32),
-                            lower=np.zeros(len(vals[f_i]), np.float32),
-                            p_value=1.0,
-                            dist_differs=False,
-                        )
-                        for f_i, alias in enumerate(jinfo[1])
-                    ]
-                    try:
-                        hook(doc, vs)
-                    except Exception:
-                        log.exception(
-                            "on_verdict hook failed for %s", doc.id
-                        )
-                continue
-            tcb = bucket_length(n)
-            if jinfo[0] == "lstm" and tcb != jinfo[6][0]:
-                # window bucket drifted from the one the AE was fitted
-                # at: the model no longer applies — refit on the slow
-                # path instead of scoring through the wrong program
-                demoted.append(doc)
-                continue
-            groups.setdefault((mode, len(jinfo[1])), []).append(
-                (doc, end_epoch, jinfo, ct, cv, n)
-            )
-
-        for (mode, f), items in groups.items():
-            if mode == "lstm":
-                # ONE dispatch per (lstm, F) group, padded to the
-                # group's widest fitted window bucket (VERDICT r5 #10:
-                # per-bucket sub-dispatches serialized refinement
-                # sweeps on 2,048-window programs). Exact by
-                # construction: the AE scan carries state through
-                # masked steps unchanged and the decoder's outputs at
-                # step i never depend on later steps, and the MVN
-                # d^2 is causal — so SUFFIX padding (each item keeps
-                # its own n/mask) cannot change any real point's flag.
-                # Admission still pins each item's bucket to its fitted
-                # meta (drift demotes to the slow path above); only the
-                # dispatch shape is merged, univariate-style.
-                subgroups = [
-                    (max(it[2][6][0] for it in items), items)
-                ]
-            else:
-                subgroups = [
-                    (
-                        bucket_length(max(it[5] for it in items)),
-                        items,
+        empty: list = []
+        with span(
+            "worker.pack_joint", stage="pack", docs=len(ok_joint)
+        ) as sp:
+            for (doc, end_epoch, jinfo), series in ok_joint:
+                mode = jinfo[0]
+                times = [s[0] for s in series]
+                vals = [s[1] for s in series]
+                t0 = np.asarray(times[0], np.int64)
+                # all-equal shortcut requires STRICTLY INCREASING stamps:
+                # align_series dedups repeated timestamps (first
+                # occurrence) and sorts — a raw trace with duplicates
+                # must take the same path so fast and object verdicts
+                # cannot diverge
+                if (
+                    len(t0) > 0
+                    and bool(np.all(np.diff(t0) > 0))
+                    and all(
+                        len(t) == len(t0) and np.array_equal(t, t0)
+                        for t in times[1:]
                     )
-                ]
-            for tcb, sub in subgroups:
-                s = len(sub)
+                ):
+                    ct = t0
+                    cv = np.stack(
+                        [np.asarray(v, np.float32) for v in vals]
+                    )
+                else:
+                    ct, cv = align_series(times, vals)
+                n = len(ct)
+                if n == 0:
+                    # no joint observation: decided UNKNOWN below
+                    empty.append((doc, end_epoch, jinfo, vals))
+                    continue
+                tcb = bucket_length(n)
+                if jinfo[0] == "lstm" and tcb != jinfo[6][0]:
+                    # window bucket drifted from the one the AE was
+                    # fitted at: the model no longer applies — refit on
+                    # the slow path instead of scoring through the wrong
+                    # program
+                    demoted.append(doc)
+                    continue
+                groups.setdefault((mode, len(jinfo[1])), []).append(
+                    (doc, end_epoch, jinfo, ct, cv, n)
+                )
+            note(sp, demoted=len(demoted))
+
+        if empty:
+            with span("worker.decide", stage="decide", docs=len(empty)):
+                for doc, end_epoch, jinfo, vals in empty:
+                    # UNKNOWN, object-path parity (`_unknown` —
+                    # baseline-less pairwise is (1.0, False))
+                    self._decide_status(doc, UNKNOWN, {}, now, end_epoch)
+                    self._log_judged(doc)
+                    updated.append(doc)
+                    counts[jinfo[0]] += 1
+                    if observe:
+                        observe(doc.status, len(jinfo[1]))
+                    if hook:
+                        vs = [
+                            MetricVerdict(
+                                job_id=doc.id,
+                                alias=alias,
+                                verdict=UNKNOWN,
+                                anomaly_pairs=[],
+                                upper=np.zeros(len(vals[f_i]), np.float32),
+                                lower=np.zeros(len(vals[f_i]), np.float32),
+                                p_value=1.0,
+                                dist_differs=False,
+                            )
+                            for f_i, alias in enumerate(jinfo[1])
+                        ]
+                        try:
+                            hook(doc, vs)
+                        except Exception:
+                            log.exception(
+                                "on_verdict hook failed for %s", doc.id
+                            )
+
+        for (mode, f), sub in groups.items():
+            # ONE dispatch per (mode, F) group. lstm pads to the group's
+            # widest fitted window bucket (VERDICT r5 #10: per-bucket
+            # sub-dispatches serialized refinement sweeps on
+            # 2,048-window programs). Exact by construction: the AE scan
+            # carries state through masked steps unchanged and the
+            # decoder's outputs at step i never depend on later steps,
+            # and the MVN d^2 is causal — so SUFFIX padding (each item
+            # keeps its own n/mask) cannot change any real point's flag.
+            # Admission still pins each item's bucket to its fitted meta
+            # (drift demotes to the slow path above); only the dispatch
+            # shape is merged, univariate-style.
+            s = len(sub)
+            with span("worker.pack_joint", stage="pack", docs=s, rows=s):
+                if mode == "lstm":
+                    tcb = max(it[2][6][0] for it in sub)
+                else:
+                    tcb = bucket_length(max(it[5] for it in sub))
                 cur = np.zeros((s, f, tcb), np.float32)
                 mask = np.zeros((s, tcb), bool)
                 gaps = np.zeros(s, np.int32) if mode == "lstm" else None
@@ -1821,15 +1826,19 @@ class BrainWorker:
                             )
                         )
                         gaps[i] = max(k - 1, 0)
-                flags = judge.joint_columnar(
-                    mode, keys, entries, metas, cur, mask, gaps
-                )
+            flags = judge.joint_columnar(
+                mode, keys, entries, metas, cur, mask, gaps
+            )
+            with span("worker.decide", stage="decide", docs=s) as sd:
+                unhealthy = points = 0
                 for i, (doc, end_epoch, jinfo, ct, cv, n) in enumerate(sub):
                     fl = flags[i, :n]
                     jv = UNHEALTHY if fl.any() else HEALTHY
                     values_map = {}
                     if jv == UNHEALTHY:
                         ft = ct[fl]
+                        unhealthy += 1
+                        points += f * len(ft)
                         for f_i, alias in enumerate(jinfo[1]):
                             pairs = np.empty(2 * len(ft), np.float64)
                             pairs[0::2] = ft
@@ -1853,6 +1862,7 @@ class BrainWorker:
                             log.exception(
                                 "on_verdict hook failed for %s", doc.id
                             )
+                note(sd, unhealthy=unhealthy, payload_points=points)
         return updated, demoted, counts
 
     def _joint_verdicts(self, doc, jinfo, ct, cv, n, fl, jv, thr):
@@ -1921,7 +1931,7 @@ class BrainWorker:
         evictions) revalidates per row by entry identity instead of
         discarding the cache — see _revalidate.
         """
-        fast, fastc, fastj, slow = self._admit_fast(docs, now)
+        fast, fastc, fastj, slow = self._admit_split(docs, now)
         if not fast and not fastc and not fastj:
             return 0, slow
         ok_items, ok_citems, ok_joint, failed, released = self._fetch_fast(
@@ -1962,7 +1972,7 @@ class BrainWorker:
             "worker.write_back", stage="write_back", docs=len(updated_all)
         ):
             self._store_update_many(updated_all)
-        self._observe_verdicts(updated_all)
+        self._observe_written(updated_all)
         return (
             len(ok_items)
             + len(ok_citems)
@@ -1971,6 +1981,18 @@ class BrainWorker:
             + len(released),
             slow,
         )
+
+    def _admit_split(self, docs, now: float):
+        """`_admit_fast` under its stage span (prefetch thread in a
+        sliced sweep, tick thread in `_fast_tick`)."""
+        with span("worker.admit", stage="admit", docs=len(docs)) as sp:
+            groups = self._admit_fast(docs, now)
+            fast, fastc, fastj, slow = groups
+            note(
+                sp, fast=len(fast), canary=len(fastc),
+                joint=len(fastj), slow=len(slow),
+            )
+        return groups
 
     def _admit_fast(self, docs, now: float):
         """The fast-tick admission walk — shared by the monolithic
@@ -2177,7 +2199,7 @@ class BrainWorker:
         wait pair — which is what pins sliced-vs-monolithic byte
         parity by construction (and keeps `judge_columnar` the one
         instrumentable judgment seam)."""
-        packed = self._pack_uni(ok_items, canary)
+        packed = self._pack_uni_spanned(ok_items, canary)
         res = self._uni.judge_columnar(
             packed.values,
             packed.mask,
@@ -2193,6 +2215,14 @@ class BrainWorker:
             base_mask=packed.base_m,
         )
         return self._decode_uni(packed, res, now)
+
+    def _pack_uni_spanned(self, ok_items, canary: bool):
+        """`_pack_uni` under its stage span (prefetch thread in a sliced
+        sweep, tick thread in `_judge_uni_fast`)."""
+        with span("worker.pack_uni", stage="pack", docs=len(ok_items)) as sp:
+            packed = self._pack_uni(ok_items, canary)
+            note(sp, rows=len(packed.keys))
+        return packed
 
     def _pack_uni(self, ok_items, canary: bool):
         """The host-side packing half (prefetch-thread-safe: pure numpy
@@ -2492,6 +2522,17 @@ class BrainWorker:
         led.pending = {}
         led.observed = set()
 
+    def _observe_written(
+        self, docs, led: _TickLedger | None = None
+    ) -> None:
+        """`_observe_verdicts` under its stage span, at every write-back
+        point (the writer thread in both pipelines, the tick thread in
+        `_fast_tick`)."""
+        with span(
+            "worker.observe_verdicts", stage="housekeeping", docs=len(docs)
+        ):
+            self._observe_verdicts(docs, led)
+
     def _observe_verdicts(
         self, docs, led: _TickLedger | None = None
     ) -> None:
@@ -2610,29 +2651,23 @@ class BrainWorker:
         per-doc judgment is byte-identical to the monolithic tick
         because both compose the same pack/dispatch/decode helpers."""
         t0 = time.perf_counter()
-        self._tick_deadline = self._degrade.deadline(t0)
         now = time.time() if now is None else now
-        self._flush_write_behind()
-        led = self._begin_pending(None)
+        led = self._cycle_head(t0, None)
         docs = self._claim_cycle(led, None)
         claim_mono = self._tick_claim_mono
         if docs and self._deadline_exceeded():
             self._release_docs(docs, REASON_DEADLINE, led, claim_mono)
             docs = []
         if not docs:
-            # idle sweep: same housekeeping as the monolithic idle tick
-            self._finish_pending(led)
-            self._refine_provisional(now)
-            self._maybe_persist()
-            if self.metrics:
-                self.metrics.tick_seconds.observe(time.perf_counter() - t0)
+            self._idle_sweep_tail(led, now, t0)
             return 0
 
         import itertools
 
         from foremast_tpu.jobs import pipeline as _pl
 
-        pool = _SweepPool(docs, tenancy=self._tenancy)
+        with span("worker.sweep_head", stage="housekeeping", docs=len(docs)):
+            pool = _SweepPool(docs, tenancy=self._tenancy)
         counters = {
             "slices": 0, "slow_docs": 0, "promoted": 0,
             "inflight_requeued": 0, "preempt_microticks": 0,
@@ -2682,7 +2717,12 @@ class BrainWorker:
             totals["fast"] += n_fast
 
         def boundary():
-            self._preempt_between_slices(pool, led, now, counters)
+            with span("worker.boundary", stage="housekeeping"):
+                micro = self._preempt_between_slices(pool, led, counters)
+            if micro:
+                # outside the boundary's span: the nested cycle brings
+                # its own stage spans, and stage spans never nest
+                self._preempt_microtick(micro, counters)
 
         # _sweep_active pins _tick_claim_mono for nested micro-ticks
         # (see _claim_cycle); flipped back in the SAME finally that
@@ -2736,17 +2776,18 @@ class BrainWorker:
             }
             if self.metrics and hasattr(self.metrics, "observe_sweep"):
                 self.metrics.observe_sweep(stats, counters)
-        if counters["slow_docs"] == 0:
-            # all-warm sweep: the cheap moment to upgrade provisional
-            # fits, exactly the monolithic tick's rule
-            self._refine_provisional(now)
-        if self.metrics:
-            if hasattr(self.metrics, "observe_arena"):
-                self.metrics.observe_arena(
-                    self._uni.device_state_counters()
-                )
-            self.metrics.tick_seconds.observe(time.perf_counter() - t0)
-        self._tick_done(totals["docs"], totals["fast"], t0, led=led)
+        with span("worker.sweep_tail", stage="housekeeping"):
+            if counters["slow_docs"] == 0:
+                # all-warm sweep: the cheap moment to upgrade provisional
+                # fits, exactly the monolithic tick's rule
+                self._refine_provisional(now)
+            if self.metrics:
+                if hasattr(self.metrics, "observe_arena"):
+                    self.metrics.observe_arena(
+                        self._uni.device_state_counters()
+                    )
+                self.metrics.tick_seconds.observe(time.perf_counter() - t0)
+            self._tick_done(totals["docs"], totals["fast"], t0, led=led)
         return totals["docs"]
 
     def _prepare_slice(
@@ -2756,7 +2797,7 @@ class BrainWorker:
         fetch, and columnar packing for one slice. No store writes and
         no device work — those belong to the writer and tick threads."""
         prep = _SlicePrep(docs, claim_mono)
-        fast, fastc, fastj, prep.slow = self._admit_fast(docs, now)
+        fast, fastc, fastj, prep.slow = self._admit_split(docs, now)
         if fast or fastc or fastj:
             (
                 prep.ok_items,
@@ -2766,9 +2807,13 @@ class BrainWorker:
                 prep.released,
             ) = self._fetch_fast(fast, fastc, fastj)
             if prep.ok_items:
-                prep.uni_packed = self._pack_uni(prep.ok_items, False)
+                prep.uni_packed = self._pack_uni_spanned(
+                    prep.ok_items, False
+                )
             if prep.ok_citems:
-                prep.canary_packed = self._pack_uni(prep.ok_citems, True)
+                prep.canary_packed = self._pack_uni_spanned(
+                    prep.ok_citems, True
+                )
         return prep
 
     def _dispatch_slice(
@@ -2883,7 +2928,7 @@ class BrainWorker:
                     self._store_update_many(
                         updated, claim_mono=prep.claim_mono
                     )
-            self._observe_verdicts(updated, led)
+            self._observe_written(updated, led)
             n_fast = len(updated) + len(prep.failed) + len(prep.released)
             return n_fast + len(prep.slow), n_fast
         finally:
@@ -2920,8 +2965,8 @@ class BrainWorker:
             )
 
     def _preempt_between_slices(
-        self, pool, led: _TickLedger, now: float, counters: dict
-    ) -> None:
+        self, pool, led: _TickLedger, counters: dict
+    ) -> list:
         """The slice-boundary preemption point (ISSUE 15 tentpole).
 
         Pending dirty arrivals are triaged against the sweep itself:
@@ -2936,16 +2981,15 @@ class BrainWorker:
             of the dirty set with the ORIGINAL stamp; once the slice's
             write releases the doc, the next boundary claims it.
           * anything else (docs outside this sweep's claim: new jobs,
-            already-written re-check docs) — a NESTED micro-tick runs
-            between slices, the unchanged `_tick` body on its own
-            ledger, every degradation contract intact.
+            already-written re-check docs) — RETURNED for a NESTED
+            micro-tick between slices (`_preempt_microtick`), the
+            unchanged `_tick` body on its own ledger, every degradation
+            contract intact.
         """
         dirty = self.dirty
         if dirty is None or not len(dirty):
-            return
+            return []
         entries = dirty.take(self.microtick_docs)
-        if not entries:
-            return
         micro_entries = []
         for rk, stamp in entries:
             if pool.promote(rk):
@@ -2960,8 +3004,11 @@ class BrainWorker:
                 dirty.count("inflight_requeued")
             else:
                 micro_entries.append((rk, stamp))
-        if not micro_entries:
-            return
+        return micro_entries
+
+    def _preempt_microtick(self, micro_entries: list, counters: dict) -> None:
+        """The nested micro-tick of a slice boundary, under its own
+        `worker.microtick` span (a child of the sweep's root)."""
         counters["preempt_microticks"] += 1
         # the nested cycle swaps the innermost-ledger pointer and the
         # tick deadline; restore both so the sweep's remaining slices
@@ -2974,9 +3021,10 @@ class BrainWorker:
         saved_deadline = self._tick_deadline
         saved_ledger = self._ledger
         try:
-            counters["preempt_docs"] += self._tick(
-                None, micro=micro_entries
-            )
+            with span("worker.microtick", worker=self.worker_id):
+                counters["preempt_docs"] += self._tick(
+                    None, micro=micro_entries
+                )
         finally:
             self._tick_deadline = saved_deadline
             self._ledger = saved_ledger
@@ -3009,7 +3057,9 @@ class BrainWorker:
         # stamp is conservative: they age out earlier, never later.
         if not self._sweep_active:
             self._tick_claim_mono = time.monotonic()
-        with span("worker.claim", stage="claim", limit=self.claim_limit):
+        with span(
+            "worker.claim", stage="claim", limit=self.claim_limit
+        ) as sp:
             try:
                 docs = self.store.claim(
                     self.worker_id,
@@ -3027,6 +3077,7 @@ class BrainWorker:
                         by_tenant[t] = by_tenant.get(t, 0) + 1
                     for t, c in by_tenant.items():
                         self._tenant_acct.count_claims(t, c)
+                note(sp, docs=len(docs))
                 return docs
             except Exception as e:
                 # a store outage must degrade to an idle tick, not kill
@@ -3044,6 +3095,31 @@ class BrainWorker:
                 self._requeue_pending(led)
                 return []
 
+    def _cycle_head(self, t0: float, micro) -> _TickLedger:
+        """What every cycle (monolithic tick, micro-tick, sliced sweep)
+        does before its claim, under one housekeeping span."""
+        with span("worker.sweep_head", stage="housekeeping"):
+            self._tick_deadline = self._degrade.deadline(t0)
+            # replay any write-behind backlog FIRST: the store may have
+            # healed, and re-check docs buffered as preprocess_completed
+            # must become claimable before this tick's claim
+            self._flush_write_behind()
+            # reactive (ISSUE 12): a micro-tick owns the dirty entries it
+            # took; a full sweep drains the rest as its catch-all
+            return self._begin_pending(micro)
+
+    def _idle_sweep_tail(self, led: _TickLedger, now: float, t0: float) -> None:
+        """An idle sweep still did the claim round-trip (real store I/O)
+        and must be visible on the tick histogram; an idle WORKER is not
+        an idle RING (receiver threads keep pushing), so snapshot
+        cadence and provisional-fit refinement run here."""
+        with span("worker.sweep_tail", stage="housekeeping"):
+            self._finish_pending(led)
+            self._refine_provisional(now)
+            self._maybe_persist()
+            if self.metrics:
+                self.metrics.tick_seconds.observe(time.perf_counter() - t0)
+
     # An unexpected exception mid-judgment deliberately leaves this
     # cycle's claims to the stuck-claim takeover (the window is a
     # first-class claim parameter — `store.claim(..., max_stuck_seconds,
@@ -3056,15 +3132,8 @@ class BrainWorker:
     # foremast: ignore[status-machine]
     def _tick(self, now: float | None = None, micro=None) -> int:
         t0 = time.perf_counter()
-        self._tick_deadline = self._degrade.deadline(t0)
         now = time.time() if now is None else now
-        # replay any write-behind backlog FIRST: the store may have
-        # healed, and re-check docs buffered as preprocess_completed
-        # must become claimable before this tick's claim
-        self._flush_write_behind()
-        # reactive (ISSUE 12): a micro-tick owns the dirty entries it
-        # took; a full sweep drains the rest as its catch-all
-        led = self._begin_pending(micro)
+        led = self._cycle_head(t0, micro)
         docs = self._claim_cycle(led, micro)
         if docs and self._deadline_exceeded():
             # the claim alone blew the tick budget (store brownout):
@@ -3073,19 +3142,13 @@ class BrainWorker:
             self._release_docs(docs, REASON_DEADLINE, led)
             docs = []
         if not docs:
-            # idle cycles still did the claim round-trip (real store I/O)
-            # and must be visible on the tick histogram; an idle WORKER
-            # is not an idle RING (receiver threads keep pushing), so
-            # snapshot cadence and provisional-fit refinement run here
-            # (sweeps only — micro-ticks stay lean)
-            self._finish_pending(led)
+            # sweeps only — micro-ticks stay lean
             if micro is not None:
-                self._tick_done(0, 0, t0, micro=True, led=led)
+                with span("worker.sweep_tail", stage="housekeeping"):
+                    self._finish_pending(led)
+                    self._tick_done(0, 0, t0, micro=True, led=led)
                 return 0
-            self._refine_provisional(now)
-            self._maybe_persist()
-            if self.metrics:
-                self.metrics.tick_seconds.observe(time.perf_counter() - t0)
+            self._idle_sweep_tail(led, now, t0)
             return 0
 
         # the all-warm re-check subset takes the columnar fast path;
@@ -3095,37 +3158,44 @@ class BrainWorker:
         if self._uni is not None:
             n_fast, docs = self._fast_tick(docs, now)
             if not docs:
-                # all-warm steady tick: the cheap moment to upgrade
-                # provisional fits — invalidations land their refits on
-                # the NEXT tick's slow path, in bounded batches
-                # (sweeps only; micro-ticks leave housekeeping alone)
-                if micro is None:
-                    self._refine_provisional(now)
-                if self.metrics:
-                    if hasattr(self.metrics, "observe_arena"):
-                        self.metrics.observe_arena(
-                            self._uni.device_state_counters()
-                        )
+                with span("worker.sweep_tail", stage="housekeeping"):
+                    # all-warm steady tick: the cheap moment to upgrade
+                    # provisional fits — invalidations land their refits
+                    # on the NEXT tick's slow path, in bounded batches
+                    # (sweeps only; micro-ticks leave housekeeping alone)
                     if micro is None:
-                        self.metrics.tick_seconds.observe(
-                            time.perf_counter() - t0
-                        )
-                self._tick_done(
-                    n_fast, n_fast, t0, micro=micro is not None, led=led
-                )
+                        self._refine_provisional(now)
+                    if self.metrics:
+                        if hasattr(self.metrics, "observe_arena"):
+                            self.metrics.observe_arena(
+                                self._uni.device_state_counters()
+                            )
+                        if micro is None:
+                            self.metrics.tick_seconds.observe(
+                                time.perf_counter() - t0
+                            )
+                    self._tick_done(
+                        n_fast, n_fast, t0, micro=micro is not None, led=led
+                    )
                 return n_fast
 
         self._run_slow_chunks(docs, now, led, self._tick_claim_mono)
-        if self.metrics:
-            if self._uni is not None and hasattr(
-                self.metrics, "observe_arena"
-            ):
-                self.metrics.observe_arena(self._uni.device_state_counters())
-            if micro is None:
-                self.metrics.tick_seconds.observe(time.perf_counter() - t0)
-        self._tick_done(
-            n_fast + len(docs), n_fast, t0, micro=micro is not None, led=led
-        )
+        with span("worker.sweep_tail", stage="housekeeping"):
+            if self.metrics:
+                if self._uni is not None and hasattr(
+                    self.metrics, "observe_arena"
+                ):
+                    self.metrics.observe_arena(
+                        self._uni.device_state_counters()
+                    )
+                if micro is None:
+                    self.metrics.tick_seconds.observe(
+                        time.perf_counter() - t0
+                    )
+            self._tick_done(
+                n_fast + len(docs), n_fast, t0,
+                micro=micro is not None, led=led,
+            )
         return n_fast + len(docs)
 
     def _run_slow_chunks(
@@ -3318,7 +3388,7 @@ class BrainWorker:
                         log.exception(
                             "on_verdict hook failed for %s", doc.id
                         )
-        self._observe_verdicts(ok_docs, led)
+        self._observe_written(ok_docs, led)
 
     def _log_judged(self, doc) -> None:
         """One correlatable line per service-created judgment: emitted

@@ -291,8 +291,13 @@ def score_rows_cutoff(params, rows, x, mask, cutoff):
     counterpart of `scoring.score_from_arena`, so a warm joint re-check
     tick ships only the current windows and a row-index vector, never
     the ~60 KB/model parameter stack. Returns (flags [S, B, T], errors)."""
-    gathered = jax.tree.map(lambda leaf: jnp.take(leaf, rows, axis=0), params)
-    return score_many_cutoff(gathered, x, mask, cutoff)
+    # named scopes: the phase rides every op's name in a device trace
+    with jax.named_scope("gather_rows"):
+        gathered = jax.tree.map(
+            lambda leaf: jnp.take(leaf, rows, axis=0), params
+        )
+    with jax.named_scope("ae_score"):
+        return score_many_cutoff(gathered, x, mask, cutoff)
 
 
 # Mesh-placement contract for the from-rows entry points (ISSUE 13,

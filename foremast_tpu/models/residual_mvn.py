@@ -184,20 +184,23 @@ def _d2(state: MVNState, cur: jax.Array, upd: jax.Array) -> jax.Array:
     b, f, tc = cur.shape
     a, bt, g = HW_PARAMS
     flat = cur.reshape(b * f, tc)
-    pred, _ = hw_continue(
-        state.hw,
-        flat,
-        jnp.repeat(upd, f, axis=0),
-        state.hw.season.shape[-1],
-        a,
-        bt,
-        g,
-    )
-    resid = (flat - pred).reshape(b, f, tc)
-    d = resid - state.mu[:, :, None]  # [B, F, Tc]
-    # solve per job: cov [B,F,F] x X = d  -> d^T cov^-1 d per time step
-    sol = jnp.linalg.solve(state.cov, d)  # [B, F, Tc]
-    return jnp.sum(d * sol, axis=1)  # [B, Tc]
+    # named scopes: the phase rides every op's name in a device trace
+    with jax.named_scope("hw_continue"):
+        pred, _ = hw_continue(
+            state.hw,
+            flat,
+            jnp.repeat(upd, f, axis=0),
+            state.hw.season.shape[-1],
+            a,
+            bt,
+            g,
+        )
+    with jax.named_scope("mvn_judge"):
+        resid = (flat - pred).reshape(b, f, tc)
+        d = resid - state.mu[:, :, None]  # [B, F, Tc]
+        # solve per job: cov [B,F,F] x X = d -> d^T cov^-1 d per step
+        sol = jnp.linalg.solve(state.cov, d)  # [B, F, Tc]
+        return jnp.sum(d * sol, axis=1)  # [B, Tc]
 
 
 @jax.jit

@@ -1,4 +1,4 @@
-"""Observability: spans, gauge export, structured logs, profiler hooks."""
+"""Observability: spans, gauge export, structured logs."""
 
 from foremast_tpu.observe.gauges import (
     BrainGauges,
@@ -7,7 +7,6 @@ from foremast_tpu.observe.gauges import (
     start_metrics_server,
 )
 from foremast_tpu.observe.logs import JsonFormatter, ctx_log, setup_logging
-from foremast_tpu.observe.profile import annotate, trace_scoring
 from foremast_tpu.observe.spans import (
     Span,
     SpanRing,
@@ -26,8 +25,6 @@ __all__ = [
     "JsonFormatter",
     "ctx_log",
     "setup_logging",
-    "annotate",
-    "trace_scoring",
     "Span",
     "SpanRing",
     "Tracer",

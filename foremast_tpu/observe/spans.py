@@ -27,9 +27,15 @@ never needs plumbing and un-instrumented callers pay one contextvar
 read per call site.
 
 Host spans around device work pass ``device=True``, which additionally
-wraps the region in ``jax.profiler.TraceAnnotation`` — with
-``FOREMAST_PROFILE`` set, host spans and XLA device traces land on one
-Perfetto timeline.
+wraps the region in ``jax.profiler.TraceAnnotation`` — always, so in any
+profiler session (``python3 -m chipbench.run --trace 1`` takes one) host
+spans sit beside the XLA device ops on one timeline.
+
+The worker tick's stage spans (``TICK_STAGES``) close the tick's span
+tree: on each of a sweep's threads (prefetch, tick, writer) the stage
+spans are SIBLINGS that never nest, so ``foremast_tick_stage_seconds``
+sums are self time per thread. They open per slice, per dispatch group
+or per wait — never per document.
 """
 
 from __future__ import annotations
@@ -40,9 +46,9 @@ import contextvars
 import json
 import logging
 import os
+import random
 import threading
 import time
-import uuid
 import weakref
 
 log = logging.getLogger("foremast_tpu.observe.spans")
@@ -62,18 +68,25 @@ STAGE_BUCKETS = (
     0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
 )
 
-# The canonical tick stages (docs/observability.md): claim → metric_fetch
-# → fit → arena_assemble → score → decode → decide → write_back. Kept
-# here so the metrics lint and the docs can't drift from the emitters.
+# The canonical tick stages, in tick order (docs/observability.md has the
+# table: stage, span names, thread, attrs). `wait` is the tick thread
+# blocked on the sweep pipeline's other threads; `housekeeping` is the
+# sweep's head, slice boundaries and tail. Kept here so the metrics lint
+# and the docs can't drift from the emitters.
 TICK_STAGES = (
     "claim",
+    "admit",
     "metric_fetch",
+    "pack",
     "fit",
     "arena_assemble",
+    "h2d",
     "score",
     "decode",
     "decide",
     "write_back",
+    "wait",
+    "housekeeping",
 )
 
 
@@ -81,11 +94,32 @@ TICK_STAGES = (
 _CLOCK_ANCHOR = time.time() - time.perf_counter()
 
 
+def clock() -> float:
+    """Now, on the span timeline: the import-time wall-clock anchor plus
+    the monotonic clock. Whoever stamps a marker that has to line up
+    with span timestamps (a profiler sync annotation) stamps THIS, not
+    `time.time()`: the two drift apart by whatever the wall clock was
+    slewed or stepped since import."""
+    return _CLOCK_ANCHOR + time.perf_counter()
+
+
+# Span and trace IDs come from a per-process PRNG seeded once from the
+# OS, not from uuid4(): that reads os.urandom on every call, a syscall
+# made with the GIL released, and on a host whose syscalls take
+# microseconds another busy Python thread (the sweep's prefetch stage)
+# then takes the GIL and keeps it for a whole switch interval. Measured
+# on the benchmark's TPU host: 5.4 ms a span under contention against
+# 0.6 us for getrandbits (PERF.md section 6, PR 24).
+_ids = random.Random()
+os.register_at_fork(after_in_child=_ids.seed)  # a forked child re-seeds
+
+
 def new_trace_id() -> str:
-    """Mint a correlation ID in the span-pipeline format. Public so
-    callers that stamp IDs without an active span (the service's
-    tracing-off path) stay format-compatible with span-derived ones."""
-    return uuid.uuid4().hex[:16]
+    """Mint a correlation ID in the span-pipeline format (16 hex
+    digits). Public so callers that stamp IDs without an active span
+    (the service's tracing-off path) stay format-compatible with
+    span-derived ones."""
+    return f"{_ids.getrandbits(64):016x}"
 
 
 _new_id = new_trace_id
@@ -115,12 +149,12 @@ class Span:
         self.stage = stage
         self.attrs = attrs or {}
         self.duration = 0.0
-        self._t0 = time.perf_counter()
         # wall-clock ts derived from ONE anchor + the monotonic clock:
         # if NTP steps the wall clock mid-tick, per-span time.time()
         # would shift later spans past/before their parent on the
         # Perfetto timeline while durations stay monotonic
-        self.ts = _CLOCK_ANCHOR + self._t0
+        self.ts = clock()
+        self._t0 = self.ts - _CLOCK_ANCHOR
 
     def to_event(self) -> dict:
         args = {
@@ -189,6 +223,21 @@ class SpanRing:
 @contextlib.contextmanager
 def _null_span():
     yield None
+
+
+@contextlib.contextmanager
+def _annotation_only(name: str):
+    """The `jax.profiler.TraceAnnotation` of a `device=True` span (alone,
+    when no tracer is wired). Yields None like `_null_span`, so
+    `with span(...) as s` is a Span or None at every call site."""
+    try:
+        import jax
+
+        cm = jax.profiler.TraceAnnotation(name)
+    except Exception:  # noqa: BLE001 - tracing must never break scoring
+        cm = _null_span()
+    with cm:
+        yield None
 
 
 class Tracer:
@@ -279,16 +328,8 @@ class Tracer:
             # concurrent /debug/state read sees old-or-new, never a mix)
             self.last_stage_seconds = {}
         token = _ACTIVE.set((self, s))
-        dev_cm = _null_span()
-        if device:
-            try:
-                import jax
-
-                dev_cm = jax.profiler.TraceAnnotation(name)
-            except Exception:  # noqa: BLE001 - tracing must never break scoring
-                pass
         try:
-            with dev_cm:
+            with _annotation_only(name) if device else _null_span():
                 yield s
         finally:
             s.duration = time.perf_counter() - s._t0
@@ -415,15 +456,16 @@ def span(name: str, stage: str | None = None, device: bool = False, **attrs):
     when tracing is off."""
     active = _ACTIVE.get()
     if active is None:
-        if device:
-            try:
-                import jax
-
-                return jax.profiler.TraceAnnotation(name)
-            except Exception:  # noqa: BLE001
-                return _null_span()
-        return _null_span()
+        return _annotation_only(name) if device else _null_span()
     return active[0].span(name, stage=stage, device=device, **attrs)
+
+
+def note(s: "Span | None", **attrs) -> None:
+    """Add counts known only at the END of a span's region (docs
+    demoted, bytes handed to the device) to its attrs; a no-op on the
+    None an un-traced `with span(...) as s` yields."""
+    if s is not None:
+        s.attrs.update(attrs)
 
 
 def inherit_span(fn):

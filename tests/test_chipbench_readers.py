@@ -1,0 +1,161 @@
+"""The three per-layer readers ISSUE 24 adds to chipbench, on hand-written
+records: exact arithmetic, the None / 0.0 cases, and another thread's
+spans that must not count for the tick thread."""
+
+from __future__ import annotations
+
+import pytest
+
+from chipbench.readers import idle_gap_pct, span_attr_per_kwin, thread_unspanned_pct
+
+TICK, PREFETCH = 11, 22
+
+
+def _span(name, ts, dur, tid=TICK, trace="t1", stage=None, parent="r", **attrs):
+    args = {"trace_id": trace, "span_id": name, "parent_id": parent, **attrs}
+    if stage:
+        args["stage"] = stage
+    return {"name": name, "ts": float(ts), "dur": float(dur), "tid": tid, "args": args}
+
+
+def _root(ts, dur, trace="t1", tid=TICK):
+    return _span("worker.tick", ts, dur, tid=tid, trace=trace, parent="")
+
+
+def _record(spans, sweeps=1, windows=4000):
+    return {"spans": spans, "sweeps": [{}] * sweeps, "windows": windows}
+
+
+# -- thread_unspanned_pct ---------------------------------------------------
+
+
+def test_unspanned_is_root_time_outside_the_tick_threads_stage_spans():
+    rec = _record([
+        _root(1000, 100),
+        _span("worker.claim", 1000, 10, stage="claim"),
+        _span("judge.decode", 1020, 50, stage="decode"),
+    ])
+    assert thread_unspanned_pct.read(rec, {}) == pytest.approx(40.0)
+
+
+def test_unspanned_ignores_other_threads_and_stageless_spans():
+    rec = _record([
+        _root(1000, 100),
+        _span("worker.claim", 1000, 10, stage="claim"),
+        # the prefetch thread fetches for the whole tick: none of it is
+        # the tick thread's time
+        _span("worker.fetch", 1000, 100, tid=PREFETCH, stage="metric_fetch"),
+        # a span with no stage names no stage's seconds
+        _span("arena.scatter", 1010, 90),
+    ])
+    assert thread_unspanned_pct.read(rec, {}) == pytest.approx(90.0)
+
+
+def test_unspanned_takes_the_union_and_clips_to_the_root():
+    rec = _record([
+        _root(1000, 100),
+        _span("a", 990, 30, stage="claim"),       # clipped to [1000, 1020)
+        _span("b", 1010, 30, stage="pack"),       # overlaps a: adds [1020, 1040)
+        _span("c", 1090, 50, stage="decide"),     # clipped to [1090, 1100)
+        _span("d", 1200, 50, stage="decide"),     # outside the root
+    ])
+    assert thread_unspanned_pct.read(rec, {}) == pytest.approx(50.0)
+
+
+def test_unspanned_reads_the_windows_roots_only():
+    # set-up's tick (wholly un-spanned) is in the ring too: the window is
+    # the LAST len(sweeps) roots, summed
+    rec = _record(
+        [
+            _root(0, 500, trace="setup"),
+            _root(1000, 100, trace="t1"),
+            _span("x", 1000, 100, trace="t1", stage="pack"),
+            _root(2000, 300, trace="t2"),
+            _span("y", 2000, 100, trace="t2", stage="pack"),
+            # a nested micro-tick's span is no root
+            _span("worker.tick", 2100, 50, trace="t2", parent="r"),
+        ],
+        sweeps=2,
+    )
+    assert thread_unspanned_pct.read(rec, {}) == pytest.approx(100.0 * 200 / 400)
+
+
+@pytest.mark.parametrize(
+    "rec",
+    [
+        _record([], sweeps=2),
+        _record([_span("worker.claim", 0, 10, stage="claim")]),
+        _record([_root(0, 100)], sweeps=0),
+        {"sweeps": [{}], "windows": 1},
+    ],
+)
+def test_unspanned_is_none_without_a_root(rec):
+    assert thread_unspanned_pct.read(rec, {}) is None
+
+
+# -- idle_gap_pct -----------------------------------------------------------
+
+GAP = {"gap": "host, no stage span"}
+
+
+def test_idle_gap_is_the_named_entrys_share_of_the_window():
+    rec = {"trace": {"window_s": 20.0, "idle_gaps": [
+        ["host, no stage span", 11.0], ["worker.fetch[metric_fetch]", 2.0],
+    ]}}
+    assert idle_gap_pct.read(rec, GAP) == pytest.approx(55.0)
+    assert idle_gap_pct.read(rec, {"gap": "worker.fetch[metric_fetch]"}) == pytest.approx(10.0)
+
+
+def test_idle_gap_is_zero_when_every_gap_has_a_name():
+    rec = {"trace": {"window_s": 20.0, "idle_gaps": [["judge.decode[decode]", 1.0]]}}
+    assert idle_gap_pct.read(rec, GAP) == 0.0
+
+
+@pytest.mark.parametrize(
+    "rec",
+    [
+        {},
+        {"trace": None},
+        {"trace": {"window_s": 20.0, "idle_gaps": []}},
+        {"trace": {"window_s": None, "idle_gaps": [["host, no stage span", 1.0]]}},
+    ],
+)
+def test_idle_gap_is_none_without_a_trace(rec):
+    assert idle_gap_pct.read(rec, GAP) is None
+
+
+# -- span_attr_per_kwin -----------------------------------------------------
+
+H2D = {"span": "judge.h2d", "attr": "bytes", "scale": 1e-6}
+
+
+def test_span_attr_sums_the_windows_spans_per_thousand_windows():
+    rec = _record(
+        [
+            _root(0, 500, trace="setup"),
+            _span("judge.h2d", 10, 5, trace="setup", stage="h2d", bytes=9_000_000),
+            _root(1000, 100, trace="t1"),
+            _span("judge.h2d", 1010, 5, trace="t1", stage="h2d", bytes=3_000_000),
+            _span("judge.h2d", 1050, 5, trace="t1", stage="h2d", bytes=1_000_000),
+            _span("judge.score", 1060, 5, trace="t1", stage="score", bytes=7),
+        ],
+        windows=4000,
+    )
+    assert span_attr_per_kwin.read(rec, H2D) == pytest.approx(1.0)  # 4 MB / 4 kwin
+    assert span_attr_per_kwin.read(
+        rec, {"span": "judge.score", "attr": "bytes"}
+    ) == pytest.approx(7 / 4)
+
+
+@pytest.mark.parametrize(
+    "spans",
+    [
+        [],
+        # the parent commit: the root is there, the span is not
+        [_root(1000, 100), _span("judge.score", 1010, 5, stage="score")],
+        # the span without the attribute
+        [_root(1000, 100), _span("judge.h2d", 1010, 5, stage="h2d")],
+    ],
+)
+def test_span_attr_is_none_where_the_program_records_none(spans):
+    assert span_attr_per_kwin.read(_record(spans), H2D) is None

@@ -16,7 +16,6 @@ from foremast_tpu.observe import (
     JsonFormatter,
     make_verdict_hook,
     setup_logging,
-    trace_scoring,
 )
 
 
@@ -109,12 +108,6 @@ def test_json_logging(capsys):
     log.info("hello")
     rec = json.loads(buf.getvalue().strip())
     assert rec["msg"] == "hello" and rec["level"] == "info"
-
-
-def test_trace_scoring_noop(monkeypatch):
-    monkeypatch.delenv("FOREMAST_PROFILE", raising=False)
-    with trace_scoring():
-        pass  # must not start a trace or raise
 
 
 def test_worker_metrics_counters(demo_traces):

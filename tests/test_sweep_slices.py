@@ -437,15 +437,24 @@ def test_pipeline_on_drained_gets_unjudged_prefetches():
     """A judge abort drains completed-but-unjudged prefetches through
     on_drained so a side-effecting fetch stage can give work back."""
     drained = []
+    prefetching_2 = threading.Event()
+
+    def fetch(c):
+        if c == 2:
+            prefetching_2.set()
+        return f"prep-{c}"
 
     def judge(c, p):
         if c == 1:
+            # abort only once chunk 2's prefetch is under way: one not
+            # yet started is cancelled on the abort and owes nothing
+            assert prefetching_2.wait(10)
             raise RuntimeError("boom")
         return p
 
     pool = _pool()
     pipe = pl.ChunkPipeline(
-        lambda c: f"prep-{c}",
+        fetch,
         judge,
         lambda c, r: None,
         depth=3,
