@@ -1690,16 +1690,16 @@ class BrainWorker:
     def _judge_joint_fast(self, ok_joint, now: float):
         """Columnar warm judgment of admitted joint docs.
 
-        Aligns each doc's fetched current windows (the cheap all-equal
-        timestamp case short-circuits the intersect), groups by (model
-        kind, feature count, window bucket), and runs ONE arena-gathered
-        program per group (`MultivariateJudge.joint_columnar`). Statuses
+        Aligns the slice's fetched current windows (`pack_slice`: the
+        all-equal timestamp case is tested and stacked for the whole
+        slice at once, the rest intersect per doc), groups by (model
+        kind, feature count), and runs ONE arena-gathered program per
+        group (`MultivariateJudge.joint_columnar`). Statuses
         and anomaly pairs replicate the object path's `_emit` exactly;
         docs whose window bucket drifted from the fitted one are DEMOTED
         to the slow path (refit) rather than mis-scored. Returns
         (updated_docs, demoted_docs, per-kind counts)."""
-        from foremast_tpu.engine.judge import bucket_length
-        from foremast_tpu.engine.multivariate import align_series
+        from foremast_tpu.jobs.joint_pack import pack_slice
 
         observe = self.metrics.observe_doc if self.metrics else None
         hook = self.on_verdict
@@ -1708,54 +1708,18 @@ class BrainWorker:
             np.float32(judge.config.anomaly.rule_for(None).threshold)
         )
         updated: list = []
-        demoted: list = []
         counts = {"univariate": 0, "bivariate": 0, "lstm": 0}
-        groups: dict = {}
-        empty: list = []
         with span(
             "worker.pack_joint", stage="pack", docs=len(ok_joint)
         ) as sp:
-            for (doc, end_epoch, jinfo), series in ok_joint:
-                mode = jinfo[0]
-                times = [s[0] for s in series]
-                vals = [s[1] for s in series]
-                t0 = np.asarray(times[0], np.int64)
-                # all-equal shortcut requires STRICTLY INCREASING stamps:
-                # align_series dedups repeated timestamps (first
-                # occurrence) and sorts — a raw trace with duplicates
-                # must take the same path so fast and object verdicts
-                # cannot diverge
-                if (
-                    len(t0) > 0
-                    and bool(np.all(np.diff(t0) > 0))
-                    and all(
-                        len(t) == len(t0) and np.array_equal(t, t0)
-                        for t in times[1:]
-                    )
-                ):
-                    ct = t0
-                    cv = np.stack(
-                        [np.asarray(v, np.float32) for v in vals]
-                    )
-                else:
-                    ct, cv = align_series(times, vals)
-                n = len(ct)
-                if n == 0:
-                    # no joint observation: decided UNKNOWN below
-                    empty.append((doc, end_epoch, jinfo, vals))
-                    continue
-                tcb = bucket_length(n)
-                if jinfo[0] == "lstm" and tcb != jinfo[6][0]:
-                    # window bucket drifted from the one the AE was
-                    # fitted at: the model no longer applies — refit on
-                    # the slow path instead of scoring through the wrong
-                    # program
-                    demoted.append(doc)
-                    continue
-                groups.setdefault((mode, len(jinfo[1])), []).append(
-                    (doc, end_epoch, jinfo, ct, cv, n)
-                )
-            note(sp, demoted=len(demoted))
+            groups, empty, demoted, bulk, aligned = pack_slice(ok_joint)
+            note(
+                sp,
+                bulk=bulk,
+                aligned=aligned,
+                empty=len(empty),
+                demoted=len(demoted),
+            )
 
         if empty:
             with span("worker.decide", stage="decide", docs=len(empty)):
@@ -1789,7 +1753,7 @@ class BrainWorker:
                                 "on_verdict hook failed for %s", doc.id
                             )
 
-        for (mode, f), sub in groups.items():
+        for group in groups:
             # ONE dispatch per (mode, F) group. lstm pads to the group's
             # widest fitted window bucket (VERDICT r5 #10: per-bucket
             # sub-dispatches serialized refinement sweeps on
@@ -1801,31 +1765,10 @@ class BrainWorker:
             # Admission still pins each item's bucket to its fitted meta
             # (drift demotes to the slow path above); only the dispatch
             # shape is merged, univariate-style.
+            mode, f, sub = group.mode, group.f, group.sub
             s = len(sub)
             with span("worker.pack_joint", stage="pack", docs=s, rows=s):
-                if mode == "lstm":
-                    tcb = max(it[2][6][0] for it in sub)
-                else:
-                    tcb = bucket_length(max(it[5] for it in sub))
-                cur = np.zeros((s, f, tcb), np.float32)
-                mask = np.zeros((s, tcb), bool)
-                gaps = np.zeros(s, np.int32) if mode == "lstm" else None
-                keys, entries, metas = [], [], []
-                for i, (doc, end_epoch, jinfo, ct, cv, n) in enumerate(sub):
-                    cur[i, :, :n] = cv[:, :n]
-                    mask[i, :n] = True
-                    keys.append(jinfo[3])
-                    entries.append(jinfo[4])
-                    metas.append(jinfo[6])
-                    if mode == "lstm":
-                        meta = jinfo[6]
-                        k = int(
-                            round(
-                                (float(ct[0]) - meta[4])
-                                / max(meta[3], 1.0)
-                            )
-                        )
-                        gaps[i] = max(k - 1, 0)
+                cur, mask, gaps, keys, entries, metas = group.fill()
             flags = judge.joint_columnar(
                 mode, keys, entries, metas, cur, mask, gaps
             )

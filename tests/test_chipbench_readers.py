@@ -159,3 +159,49 @@ def test_span_attr_sums_the_windows_spans_per_thousand_windows():
 )
 def test_span_attr_is_none_where_the_program_records_none(spans):
     assert span_attr_per_kwin.read(_record(spans), H2D) is None
+
+
+def test_pack_bulk_layer_reads_bulk_from_the_slices_first_pack_span():
+    """ISSUE 25's layer file through the reader it names: only a slice's
+    first `worker.pack_joint` span carries `bulk`; the dispatch groups'
+    (with `rows`) and set-up's do not count."""
+    import json
+    import os
+
+    from chipbench import readers
+
+    path = os.path.join(
+        os.path.dirname(readers.__file__), os.pardir, "layers",
+        "pack_bulk_docs_per_kwin.sweep.json",
+    )
+    with open(path) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "span_attr_per_kwin"
+    rec = _record(
+        [
+            _root(0, 500, trace="setup"),
+            _span("worker.pack_joint", 10, 5, trace="setup", stage="pack",
+                  docs=8192, bulk=8192, aligned=0, empty=0, demoted=0),
+            _root(1000, 100, trace="t1"),
+            _span("worker.pack_joint", 1010, 5, trace="t1", stage="pack",
+                  docs=600, bulk=600, aligned=0, empty=0, demoted=0),
+            _span("worker.pack_joint", 1020, 5, trace="t1", stage="pack",
+                  docs=600, rows=600),
+            _span("worker.pack_joint", 1050, 5, trace="t1", stage="pack",
+                  docs=400, bulk=400, aligned=0, empty=0, demoted=0),
+            _span("worker.pack_joint", 1060, 5, trace="t1", stage="pack",
+                  docs=400, rows=400),
+        ],
+        windows=4000,  # 1,000 4-alias docs
+    )
+    assert span_attr_per_kwin.read(rec, spec["params"]) == pytest.approx(250.0)
+    # one doc in ten through align_series reads 225
+    for sp in rec["spans"]:
+        if "bulk" in sp["args"]:
+            sp["args"]["aligned"] = sp["args"]["bulk"] // 10
+            sp["args"]["bulk"] -= sp["args"]["aligned"]
+    assert span_attr_per_kwin.read(rec, spec["params"]) == pytest.approx(225.0)
+    # the parent commit's spans carry no `bulk`: the metric is left out
+    for sp in rec["spans"]:
+        sp["args"].pop("bulk", None)
+    assert span_attr_per_kwin.read(rec, spec["params"]) is None

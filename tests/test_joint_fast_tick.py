@@ -319,3 +319,396 @@ def test_lstm_mixed_window_buckets_merge_into_one_dispatch():
     # both lstm docs rode the columnar path (merged dispatch)
     assert a._fast_kinds["lstm"] == 2
     assert b._fast_kinds["lstm"] == 0
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 25: the slice's joint windows are packed in bulk (jobs/joint_pack.py).
+# The per-doc loop it replaced stays HERE as the oracle: for the same
+# `ok_joint`, everything handed to `joint_columnar`, every status / payload
+# / hook verdict and the order of `updated` must be identical.
+# ---------------------------------------------------------------------------
+
+
+def _oracle_judge_joint_fast(self, ok_joint, now):
+    """`BrainWorker._judge_joint_fast` as PR 24 had it (spans left out)."""
+    from foremast_tpu.engine import (
+        HEALTHY, UNHEALTHY, UNKNOWN, MetricVerdict,
+    )
+    from foremast_tpu.engine.judge import bucket_length
+    from foremast_tpu.engine.multivariate import align_series
+
+    hook = self.on_verdict
+    judge = self._mvj
+    thr = float(np.float32(judge.config.anomaly.rule_for(None).threshold))
+    updated, demoted, empty = [], [], []
+    counts = {"univariate": 0, "bivariate": 0, "lstm": 0}
+    groups = {}
+    for (doc, end_epoch, jinfo), series in ok_joint:
+        mode = jinfo[0]
+        times = [s[0] for s in series]
+        vals = [s[1] for s in series]
+        t0 = np.asarray(times[0], np.int64)
+        if (
+            len(t0) > 0
+            and bool(np.all(np.diff(t0) > 0))
+            and all(
+                len(t) == len(t0) and np.array_equal(t, t0)
+                for t in times[1:]
+            )
+        ):
+            ct = t0
+            cv = np.stack([np.asarray(v, np.float32) for v in vals])
+        else:
+            ct, cv = align_series(times, vals)
+        n = len(ct)
+        if n == 0:
+            empty.append((doc, end_epoch, jinfo, vals))
+            continue
+        tcb = bucket_length(n)
+        if jinfo[0] == "lstm" and tcb != jinfo[6][0]:
+            demoted.append(doc)
+            continue
+        groups.setdefault((mode, len(jinfo[1])), []).append(
+            (doc, end_epoch, jinfo, ct, cv, n)
+        )
+    for doc, end_epoch, jinfo, vals in empty:
+        self._decide_status(doc, UNKNOWN, {}, now, end_epoch)
+        updated.append(doc)
+        counts[jinfo[0]] += 1
+        if hook:
+            hook(doc, [
+                MetricVerdict(
+                    job_id=doc.id, alias=alias, verdict=UNKNOWN,
+                    anomaly_pairs=[],
+                    upper=np.zeros(len(vals[f_i]), np.float32),
+                    lower=np.zeros(len(vals[f_i]), np.float32),
+                    p_value=1.0, dist_differs=False,
+                )
+                for f_i, alias in enumerate(jinfo[1])
+            ])
+    for (mode, f), sub in groups.items():
+        s = len(sub)
+        if mode == "lstm":
+            tcb = max(it[2][6][0] for it in sub)
+        else:
+            tcb = bucket_length(max(it[5] for it in sub))
+        cur = np.zeros((s, f, tcb), np.float32)
+        mask = np.zeros((s, tcb), bool)
+        gaps = np.zeros(s, np.int32) if mode == "lstm" else None
+        keys, entries, metas = [], [], []
+        for i, (doc, end_epoch, jinfo, ct, cv, n) in enumerate(sub):
+            cur[i, :, :n] = cv[:, :n]
+            mask[i, :n] = True
+            keys.append(jinfo[3])
+            entries.append(jinfo[4])
+            metas.append(jinfo[6])
+            if mode == "lstm":
+                meta = jinfo[6]
+                k = int(round((float(ct[0]) - meta[4]) / max(meta[3], 1.0)))
+                gaps[i] = max(k - 1, 0)
+        flags = judge.joint_columnar(
+            mode, keys, entries, metas, cur, mask, gaps
+        )
+        for i, (doc, end_epoch, jinfo, ct, cv, n) in enumerate(sub):
+            fl = flags[i, :n]
+            jv = UNHEALTHY if fl.any() else HEALTHY
+            values_map = {}
+            if jv == UNHEALTHY:
+                ft = ct[fl]
+                for f_i, alias in enumerate(jinfo[1]):
+                    pairs = np.empty(2 * len(ft), np.float64)
+                    pairs[0::2] = ft
+                    pairs[1::2] = cv[f_i][fl]
+                    values_map[alias] = pairs.tolist()
+            self._decide_status(doc, jv, values_map, now, end_epoch)
+            updated.append(doc)
+            counts[mode] += 1
+            if hook:
+                hook(doc, self._joint_verdicts(
+                    doc, jinfo, ct, cv, n, fl, jv, thr
+                ))
+    return updated, demoted, counts
+
+
+class _StubJudge:
+    """Stands where `MultivariateJudge` does: records what
+    `joint_columnar` is handed and flags every real point where some
+    alias reads over 2.0 (about one clean doc in two carries a flag)."""
+
+    def __init__(self):
+        from types import SimpleNamespace as NS
+
+        self.config = NS(
+            anomaly=NS(rule_for=lambda _name: NS(threshold=4.0))
+        )
+        self.calls = []
+
+    def joint_columnar(self, mode, keys, entries, metas, cur, mask, gaps=None):
+        self.calls.append((mode, keys, entries, metas, cur, mask, gaps))
+        return mask & (cur.max(axis=1) > 2.0)
+
+
+T0 = int(NOW) - 30 * 60
+
+
+def _stamps(n=CUR_LEN):
+    return T0 + 60 * np.arange(n, dtype=np.int64)
+
+
+def _spec(mode="lstm", f=4, n=CUR_LEN, fitted=None, times=None, seed=0):
+    """One doc of a slice: (mode, fitted bucket, [(times, values)] * F);
+    `times` None = each alias brings its own equal array."""
+    from foremast_tpu.engine.judge import bucket_length
+
+    vals = np.random.default_rng(seed).standard_normal((f, n))
+    series = [
+        (_stamps(n) if times is None else times, vals[a].astype(np.float32))
+        for a in range(f)
+    ]
+    if fitted is None:
+        # the bivariate kind fits no window bucket: nothing to drift from
+        fitted = bucket_length(n) if mode == "lstm" else 0
+    return mode, fitted, series
+
+
+def _edit(spec, alias, times=None, values=None):
+    mode, fitted, series = spec
+    series = list(series)
+    t, v = series[alias]
+    series[alias] = (t if times is None else times,
+                     v if values is None else values)
+    return mode, fitted, series
+
+
+def _case_shared_stamps():
+    shared = _stamps()
+    return [_spec(times=shared, seed=i) for i in range(6)]
+
+
+def _case_distinct_equal_stamps():
+    return [_spec(seed=i) for i in range(6)]
+
+
+def _case_list_series():
+    out = []
+    for i in range(4):
+        mode, fitted, series = _spec(seed=i)
+        out.append((mode, fitted, [
+            (t.tolist(), [float(x) for x in v]) for t, v in series
+        ]))
+    return out
+
+
+def _case_alias_missing_a_point():
+    ragged = _spec(seed=1)
+    t, v = ragged[2][2]
+    ragged = _edit(ragged, 2, times=np.delete(t, 7), values=np.delete(v, 7))
+    return [_spec(seed=0), ragged, _spec(seed=2)]
+
+
+def _case_duplicated_stamp():
+    t = _stamps()
+    t[11] = t[10]
+    return [_spec(seed=0), _spec(times=t, seed=1), _spec(seed=2)]
+
+
+def _case_non_monotone_stamps():
+    t = _stamps()
+    t[[4, 20]] = t[[20, 4]]
+    return [_spec(times=t, seed=0), _spec(seed=1)]
+
+
+def _case_unequal_stamps_same_length():
+    shifted = _edit(_spec(seed=1), 3, times=_stamps() + 60)
+    return [_spec(seed=0), shifted, _spec(seed=2)]
+
+
+def _case_float_stamps_and_float64_values():
+    mode, fitted, series = _spec(seed=3)
+    series = [(t.astype(np.float64), v.astype(np.float64) * 1.0000001)
+              for t, v in series]
+    return [_spec(seed=0), (mode, fitted, series)]
+
+
+def _case_one_empty_alias():
+    gone = _edit(_spec(seed=1), 1, times=np.zeros(0, np.int64),
+                 values=np.zeros(0, np.float32))
+    nothing = (
+        "lstm", 32,
+        [(np.zeros(0, np.int64), np.zeros(0, np.float32))] * 4,
+    )
+    return [_spec(seed=0), gone, nothing, _spec(seed=2)]
+
+
+def _case_mixed_lengths_one_dispatch():
+    # 30 -> bucket 32, 45 -> bucket 64: two classes, ONE lstm dispatch
+    return [_spec(n=(30, 45)[i % 3 == 1], seed=i) for i in range(7)]
+
+
+def _case_bucket_drift_demotes():
+    # 33 points against a model fitted at 32: both the bulk test and
+    # (ragged besides) the per-doc fallback must demote
+    drifted = _spec(n=33, fitted=32, seed=1)
+    ragged = _spec(n=34, fitted=32, seed=2)
+    t, v = ragged[2][0]
+    ragged = _edit(ragged, 0, times=t[:-1], values=v[:-1])
+    return [_spec(seed=0), drifted, ragged, _spec(seed=3)]
+
+
+def _case_bivariate():
+    return [_spec(mode="bivariate", f=2, n=(30, 40)[i % 2], seed=i)
+            for i in range(5)]
+
+
+def _case_fallback_in_the_middle_keeps_its_row():
+    docs = [_spec(seed=i) for i in range(9)]
+    t, v = docs[4][2][1]
+    docs[4] = _edit(docs[4], 1, times=np.delete(t, 0), values=np.delete(v, 0))
+    return docs
+
+
+def _case_kinds_interleaved():
+    # group order = order of each kind's first packed doc; the first
+    # lstm doc is demoted, so the bivariate group dispatches first
+    return [
+        _spec(n=33, fitted=32, seed=0),
+        _spec(mode="bivariate", f=2, seed=1),
+        _spec(seed=2),
+        _spec(f=3, seed=3),
+        _spec(mode="bivariate", f=2, seed=4),
+        _spec(seed=5),
+    ]
+
+
+_PACK_CASES = {
+    # name: (builder, bulk, aligned, empty, demoted, dispatches)
+    "shared_stamps": (_case_shared_stamps, 6, 0, 0, 0, 1),
+    "distinct_equal_stamps": (_case_distinct_equal_stamps, 6, 0, 0, 0, 1),
+    "list_series": (_case_list_series, 4, 0, 0, 0, 1),
+    "alias_missing_a_point": (_case_alias_missing_a_point, 2, 1, 0, 0, 1),
+    "duplicated_stamp": (_case_duplicated_stamp, 2, 1, 0, 0, 1),
+    "non_monotone_stamps": (_case_non_monotone_stamps, 1, 1, 0, 0, 1),
+    "unequal_stamps_same_length": (
+        _case_unequal_stamps_same_length, 2, 1, 0, 0, 1),
+    "float_stamps_float64_values": (
+        _case_float_stamps_and_float64_values, 2, 0, 0, 0, 1),
+    "one_empty_alias": (_case_one_empty_alias, 2, 0, 2, 0, 1),
+    "mixed_lengths_one_dispatch": (
+        _case_mixed_lengths_one_dispatch, 7, 0, 0, 0, 1),
+    "bucket_drift_demotes": (_case_bucket_drift_demotes, 2, 0, 0, 2, 1),
+    "bivariate": (_case_bivariate, 5, 0, 0, 0, 1),
+    "fallback_in_the_middle": (
+        _case_fallback_in_the_middle_keeps_its_row, 8, 1, 0, 0, 1),
+    "kinds_interleaved": (_case_kinds_interleaved, 5, 0, 0, 1, 3),
+}
+
+
+def _slice_of(specs):
+    """`ok_joint` as `_fetch_fast` hands it over, on fresh documents."""
+    from foremast_tpu.jobs.models import Document
+
+    ok_joint = []
+    for i, (mode, fitted, series) in enumerate(specs):
+        f = len(series)
+        aliases = tuple(f"m{a}" for a in range(f))
+        # history's last stamp 1, 1.5, 2.5 or 3.5 steps before the
+        # window's first: the halves pin round()'s half-to-even
+        last_ts = T0 - (60, 90, 150, 210)[i % 4]
+        meta = (fitted, np.full(f, 0.1 * i), np.full(f, 1.0), 60.0,
+                last_ts, 512)
+        jinfo = (
+            mode, aliases, tuple(f"u{i}-{a}" for a in aliases),
+            (mode, f"app{i}", aliases), ("entry", i),
+            ("jmeta", mode, f"app{i}"), meta,
+        )
+        doc = Document(id=f"job-{i}", app_name=f"app{i}")
+        ok_joint.append(((doc, NOW + 3600.0, jinfo), series))
+    return ok_joint
+
+
+def _run_pack(judge_fn, specs):
+    from benchmarks.worker_bench import ArraySource
+    from foremast_tpu.jobs.store import InMemoryStore
+
+    seen = []
+    worker = BrainWorker(
+        InMemoryStore(), ArraySource(), config=BrainConfig(),
+        worker_id="pack-w",
+        on_verdict=lambda doc, vs: seen.append((doc.id, vs)),
+    )
+    worker._mvj = _StubJudge()
+    ok_joint = _slice_of(specs)
+    updated, demoted, counts = judge_fn(worker, ok_joint, NOW)
+    return {
+        "calls": worker._mvj.calls,
+        "updated": [
+            (d.id, d.status, d.status_code, d.reason, d.anomaly_info)
+            for d in updated
+        ],
+        "demoted": [d.id for d in demoted],
+        "counts": counts,
+        "hook": seen,
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_PACK_CASES))
+def test_bulk_pack_matches_the_per_doc_loop(case):
+    """For the same `ok_joint` the bulk pack hands `joint_columnar` the
+    same bytes in the same rows as the per-doc loop did, and decides,
+    demotes and reports the same docs in the same order."""
+    from foremast_tpu.jobs.joint_pack import pack_slice
+
+    build, bulk, aligned, empty, demoted, dispatches = _PACK_CASES[case]
+    want = _run_pack(_oracle_judge_joint_fast, build())
+    got = _run_pack(BrainWorker._judge_joint_fast, build())
+
+    assert len(got["calls"]) == len(want["calls"]) == dispatches
+    for g, w in zip(got["calls"], want["calls"]):
+        assert g[0] == w[0]  # mode
+        assert g[1] == w[1]  # keys, row for row
+        assert g[2] == w[2]  # entries
+        assert len(g[3]) == len(w[3])
+        assert all(a[4] == b[4] and a[0] == b[0] for a, b in zip(g[3], w[3]))
+        for a, b in zip(g[4:], w[4:]):  # cur, mask, gaps
+            if b is None:
+                assert a is None
+                continue
+            assert a.dtype == b.dtype and a.shape == b.shape
+            assert a.tobytes() == b.tobytes()
+    assert got["updated"] == want["updated"]  # statuses, payloads, ORDER
+    assert got["demoted"] == want["demoted"]
+    assert got["counts"] == want["counts"]
+    assert [d for d, _ in got["hook"]] == [d for d, _ in want["hook"]]
+    for (_, va), (_, vb) in zip(got["hook"], want["hook"]):
+        assert len(va) == len(vb)
+        for x, y in zip(va, vb):
+            assert (x.alias, x.verdict, x.anomaly_pairs) == (
+                y.alias, y.verdict, y.anomaly_pairs
+            )
+            np.testing.assert_array_equal(x.upper, y.upper)
+            np.testing.assert_array_equal(x.lower, y.lower)
+    # the case exercises what its name says: which way each doc went
+    _, empties, drifted, n_bulk, n_aligned = pack_slice(_slice_of(build()))
+    assert (n_bulk, n_aligned, len(empties), len(drifted)) == (
+        bulk, aligned, empty, demoted
+    )
+    if case != "one_empty_alias":
+        assert any(s[1] == STATUS_COMPLETED_UNHEALTH for s in got["updated"])
+
+
+def test_bulk_pack_decide_reads_views_not_copies():
+    """`decide` reads each bulk doc's `ct`/`cv` as views into the class
+    stacks: one timestamp stack and one value stack a class, no per-doc
+    copy."""
+    from foremast_tpu.jobs.joint_pack import pack_slice
+
+    groups, empty, demoted, bulk, aligned = pack_slice(
+        _slice_of(_case_mixed_lengths_one_dispatch())
+    )
+    assert (bulk, aligned, empty, demoted) == (7, 0, [], [])
+    (group,) = groups
+    bases = {id(it[3].base) for it in group.sub} | {
+        id(it[4].base) for it in group.sub
+    }
+    assert len(bases) == 4  # (T, V) x (30-point class, 45-point class)
+    assert all(it[3].base is not None for it in group.sub)

@@ -16,7 +16,9 @@ sliced, pipelined warm sweep:
     of 2N docs records as many spans as one of N docs at the same slice
     count;
   * `PipelineStats` carries the tick thread's three waits, and they are
-    the `wait` stage.
+    the `wait` stage;
+  * a slice's first `worker.pack_joint` span says which way its docs were
+    packed (ISSUE 25), and the bulk pack opens no span of its own.
 """
 
 from __future__ import annotations
@@ -31,7 +33,7 @@ from prometheus_client import CollectorRegistry
 from benchmarks.worker_bench import build_mixed_fleet
 from foremast_tpu.config import BrainConfig
 from foremast_tpu.engine import multivariate
-from foremast_tpu.jobs import BrainWorker
+from foremast_tpu.jobs import BrainWorker, joint_pack
 from foremast_tpu.jobs.pipeline import PipelineStats
 from foremast_tpu.observe import spans
 from foremast_tpu.observe.spans import TICK_STAGES, Tracer, current_span
@@ -129,8 +131,8 @@ def swept(tmp_path_factory):
     mp.setattr(w._mvj, "_place_joint", rec.wrap("_place_joint", w._mvj._place_joint))
     mp.setattr(multivariate, "ae_cutoff", rec.wrap("ae_cutoff", multivariate.ae_cutoff))
     mp.setattr(
-        multivariate, "align_series",
-        rec.wrap("align_series", multivariate.align_series),
+        joint_pack, "align_series",
+        rec.wrap("align_series", joint_pack.align_series),
     )
     mp.setattr(w.store, "claim", rec.wrap("store.claim", w.store.claim))
     mp.setattr(
@@ -233,6 +235,56 @@ def test_stage_span_attrs_count_at_the_boundary(swept):
     decided = [a for a in by_name["worker.decide"] if "unhealthy" in a]
     assert sum(a["docs"] for a in decided) == 2  # the joint docs
     assert {a["slice"] for a in by_name["pipeline.wait_prefetch"]} == {0, 1, 2}
+
+
+# (f) ISSUE 25: the slice's first pack span (the one over the alignment,
+# before any dispatch group's `rows`) says which way its docs went
+def test_first_pack_joint_span_counts_bulk_and_aligned(swept):
+    packs = [
+        e["args"] for e in swept["events"] if e["name"] == "worker.pack_joint"
+    ]
+    (first,) = [a for a in packs if "rows" not in a]
+    # the bivariate doc in bulk; the lstm doc, one alias a point short,
+    # through align_series
+    assert (first["docs"], first["bulk"], first["aligned"]) == (2, 1, 1)
+    assert (first["empty"], first["demoted"]) == (0, 0)
+    assert sorted(a["rows"] for a in packs if "rows" in a) == [1, 1]
+
+
+@pytest.mark.parametrize(
+    "case, dispatch_groups",
+    [
+        ("distinct_equal_stamps", 1),
+        ("mixed_lengths_one_dispatch", 1),  # two (n) classes, one group
+        ("fallback_in_the_middle", 1),
+        ("one_empty_alias", 1),
+        ("bucket_drift_demotes", 1),
+        ("kinds_interleaved", 3),
+    ],
+)
+def test_pack_joint_spans_one_a_slice_and_one_a_dispatch_group(
+    case, dispatch_groups, tmp_path
+):
+    from tests.test_joint_fast_tick import _PACK_CASES, _run_pack
+
+    tracer = Tracer(
+        service="test", registry=CollectorRegistry(), trace_dir=str(tmp_path)
+    )
+    with tracer.span("worker.tick"):
+        _run_pack(BrainWorker._judge_joint_fast, _PACK_CASES[case][0]())
+    packs = [
+        e["args"]
+        for e in tracer.ring.snapshot()
+        if e["name"] == "worker.pack_joint"
+    ]
+    assert len(packs) == 1 + dispatch_groups
+    first = packs[0]
+    assert "rows" not in first
+    assert (
+        first["bulk"] + first["aligned"] + first["empty"] + first["demoted"]
+        == first["docs"]
+    )
+    assert sum(a["rows"] for a in packs[1:]) == first["bulk"] + first["aligned"]
 
 
 # (c) a warm joint + univariate sweep observes every stage but `fit`
