@@ -60,3 +60,64 @@ def joint_score_flops(f: int, w_bucket: int, hidden: int = 32) -> int:
     and left out."""
     cell = 2 * (f * 4 * hidden) + 2 * (hidden * 4 * hidden)
     return w_bucket * (2 * cell + 2 * hidden * f)
+
+
+# -- the single-alias and 2-alias warm programs ------------------------------
+# Each takes (b, f, w_bucket, m) like `joint_score_bytes`, and each flops
+# function (f, w_bucket) like `joint_score_flops`, so that one reader serves
+# every kind; f is the group's alias count (1, 1, 2).
+
+
+def univariate_score_bytes(b: int, f: int, w_bucket: int, m: int) -> int:
+    """Least bytes one warm univariate dispatch over b rows must move: of
+    each row's state the five scalars and the window's own stretch of the
+    season (the forecast reads `w_bucket` of the row's m season points, so
+    m does not count: a program that reads the whole 4 m-byte row moves
+    more than it must), the windows and their mask in, five [b] operands
+    (row index, threshold, bound, lower floor, gap), a verdict byte and the
+    packed flags out."""
+    state = b * univariate_row_bytes(min(w_bucket, m))
+    windows = b * w_bucket * F32 + b * w_bucket
+    operands = 5 * b * F32
+    out = b + b * w_bucket // 8
+    return state + windows + operands + out
+
+
+def univariate_score_flops(f: int, w_bucket: int) -> int:
+    """Operations one doc's warm univariate judgment needs: the horizon
+    (level + trend * step + season, 3 a point), the band (2 a point) and
+    the two comparisons a point."""
+    return 7 * w_bucket
+
+
+def canary_score_bytes(b: int, f: int, w_bucket: int, m: int) -> int:
+    """The univariate dispatch plus the baseline windows and their mask
+    (judged at the same bucket) in, and (p, differs) out."""
+    baseline = b * w_bucket * F32 + b * w_bucket
+    return univariate_score_bytes(b, f, w_bucket, m) + baseline + b * (F32 + 1)
+
+
+def canary_score_flops(f: int, w_bucket: int) -> int:
+    """The univariate judgment plus the pairwise tests: the two-sample rank
+    blocks (x against y, x against x, y against y: a less-than and an equal
+    compare each and their row sums, 4 operations an entry) shared by
+    Mann-Whitney and Kruskal-Wallis, the signed-rank test's block over the
+    paired differences, and the sign counts of the two-group Friedman."""
+    two_sample = 3 * 4 * w_bucket * w_bucket
+    signed_rank = 4 * w_bucket * w_bucket
+    return univariate_score_flops(f, w_bucket) + two_sample + signed_rank + 6 * w_bucket
+
+
+def bivariate_score_bytes(b: int, f: int, w_bucket: int, m: int) -> int:
+    """Least bytes one warm bivariate dispatch over b rows must move: each
+    row's mean and covariance, the two windows and one mask in, two [b]
+    operands (row index, threshold), flags out."""
+    state = b * bivariate_row_bytes()
+    windows = 2 * b * w_bucket * F32 + b * w_bucket
+    return state + windows + 2 * b * F32 + b * w_bucket
+
+
+def bivariate_score_flops(f: int, w_bucket: int) -> int:
+    """The explicit 2x2 Mahalanobis form: two differences, the quadratic
+    form (9) and its division a point, and the determinant once."""
+    return 13 * w_bucket + 4

@@ -17,7 +17,8 @@ the windows that were sent. Numbers, each with a limit of its own:
                  cutoff costs nothing, one where it is far from any costs
                  that distance. The margins are the kind's own
                  (`chipbench/references/<kind>.py`); floor and limit are
-                 the configuration's `correct_limits`.
+                 the configuration's `correct_limits` (`flip_floor`, or a
+                 kind's own `flip_floor.<kind>`: the units differ).
   flip_margin    the widest such margin in the run. Printed, not compared:
                  it does not separate the program from the control
                  (PERF.md section 2).
@@ -28,6 +29,11 @@ the windows that were sent. Numbers, each with a limit of its own:
                  machine allows for the verdict. Exact: limit 0.
   unjudged       judgments due that never reached the store (the driver
                  adds released and failed documents). Exact: limit 0.
+
+`compared.<kind>` and `flip_rate.<kind>` give each fleet kind's share of
+`compared` and `flip_rate`, the latter per 1,000 of the kind's own
+judgments. `flip_rate.<kind>` is compared where the configuration's
+`correct_limits` give it a limit of its own, and printed otherwise.
 """
 
 from __future__ import annotations
@@ -67,6 +73,7 @@ class SweepJob:
                 "group": int(fl.group_of[slot]),
                 "status": status, "reason": reason, "info": info,
                 "sent": fl.sent[(slot, sweep)],
+                "base": fl.sent_base.get((slot, sweep)),
             })
         due = {key for key in fl.sent if first <= key[1] < last}
         self.missing = len(due - seen)
@@ -139,6 +146,7 @@ def judge_sweeps(job, cfg: dict, log, control: bool = False) -> tuple[dict, dict
     floor = float(limits["flip_floor"])
     margin, payload, bad, n_rows, n_diff, n_over = 0.0, 0.0, 0, 0, 0, 0
     detail = {}
+    by_kind: dict = {}
     for gi, g in enumerate(job.groups):
         rows = [r for r in job.rows if r["group"] == gi]
         if not rows:
@@ -162,8 +170,14 @@ def judge_sweeps(job, cfg: dict, log, control: bool = False) -> tuple[dict, dict
         m, d, at = flip_margin(prog, ref)
         margin = max(margin, m)
         n_diff += d
-        n_over += int((at > floor).sum())
+        # the margins are in the kind's own units, so a kind may state a
+        # floor of its own (`flip_floor.<kind>`)
+        over = int((at > float(limits.get("flip_floor." + g["kind"], floor))).sum())
+        n_over += over
         n_rows += len(rows)
+        seen = by_kind.setdefault(g["kind"], [0, 0])
+        seen[0] += len(rows)
+        seen[1] += over
         detail[g["kind"]] = {
             "judgments": len(rows),
             "anomalous_program": int(prog.any(axis=1).sum()),
@@ -188,6 +202,16 @@ def judge_sweeps(job, cfg: dict, log, control: bool = False) -> tuple[dict, dict
         "compared": {"value": float(n_rows), "limit": None},
         "windows_differ": {"value": float(n_diff), "limit": None},
     }
+    # each kind's own share of the two pooled numbers, per 1,000 of its own
+    # judgments: compared where the configuration gives the kind a limit of
+    # its own (a kind whose rows are a small share of the pool could hide
+    # under the pooled limit), printed otherwise
+    for kind, (k_rows, k_over) in by_kind.items():
+        own = limits.get("flip_rate." + kind)
+        numbers["compared." + kind] = {"value": float(k_rows), "limit": None}
+        numbers["flip_rate." + kind] = {
+            "value": 1000.0 * k_over / k_rows, "limit": None if own is None else float(own),
+        }
     return numbers, detail
 
 

@@ -37,16 +37,20 @@ class ControlJob:
         layout = series.slot_layout(self.groups)
         group_of, local = layout
         sample = series.sample(seed, len(group_of), int(traffic.get("sample_docs", 256)))
-        chunk = int(cfg["env"]["FOREMAST_COLD_CHUNK_DOCS"])
+        fit_pos = series.fit_positions(
+            self.groups, group_of, int(cfg["env"]["FOREMAST_COLD_CHUNK_DOCS"])
+        )
         self.rows = []
         for k in range(first, first + sweeps):
-            values, spikes = series.draw_sweep(
+            values, spikes, bases = series.draw_sweep(
                 seed, k, self.groups, layout, self.w, self.n_hist, self.fam, traffic
             )
             for s in np.unique(np.concatenate([sample, spikes[0]])):
+                g, i = group_of[s], local[s]
                 self.rows.append({
-                    "uid": int(s), "fit_pos": int(s % chunk), "sweep": k,
-                    "group": int(group_of[s]), "sent": values[group_of[s]][local[s]].copy(),
+                    "uid": int(s), "fit_pos": int(fit_pos[s]), "sweep": k,
+                    "group": int(g), "sent": values[g][i].copy(),
+                    "base": None if bases[g] is None else bases[g][i].copy(),
                 })
 
 
@@ -57,6 +61,11 @@ def control_margin(cfg: dict, traffic: dict, seed: int, sweeps: int, log=None) -
         "seed": seed, "compared": len(job.rows),
         "flip_rate": numbers["flip_rate"]["value"],
         "limit": numbers["flip_rate"]["limit"],
+        # each kind's own reading beside its own limit (None: not compared)
+        "by_kind": {
+            k[len("flip_rate."):]: [v["value"], v["limit"]]
+            for k, v in numbers.items() if k.startswith("flip_rate.")
+        },
         "flip_margin": numbers["flip_margin"]["value"],
         "windows_differ": int(numbers["windows_differ"]["value"]),
         "correct": compare.verdict(numbers), "detail": detail,

@@ -72,13 +72,27 @@ class Sweeps:
         self.restored = False
         log(f"fleet built: {self.fl.slots} docs, {int(self.fl.nwin.sum())} windows")
 
-    def sweep(self) -> dict:
-        fl, k = self.fl, self.k
+    def sweep(self, slice_docs: int | None = None) -> dict:
+        """One tick over fresh windows. `slice_docs` makes it one rung of
+        the set-up's ladder: the head of the queue, a sixth of the fleet
+        (the worker keeps eight claims' worth of admitted documents and
+        drops them all past that, so a rung's claim stays well over an
+        eighth of what a run admits), judged in slices of that size."""
+        fl, k, worker = self.fl, self.k, self.worker
         ts = time.perf_counter()
         now = fl.begin_sweep(k)
         fl.prefetch(k + 1)
         t = time.perf_counter()
-        n = self.worker.tick(now=now)
+        if slice_docs:
+            usual = worker.claim_limit, worker.sweep_slice_docs
+            worker.claim_limit = max(2 * slice_docs, fl.slots // 6)
+            worker.sweep_slice_docs = slice_docs
+            try:
+                n = worker.tick(now=now)
+            finally:
+                worker.claim_limit, worker.sweep_slice_docs = usual
+        else:
+            n = worker.tick(now=now)
         dt = time.perf_counter() - t
         followed = fl.end_sweep()
         self.k += 1
@@ -122,6 +136,16 @@ class Sweeps:
                 f"warm-up sweep {s['sweep']}: {s['docs']} docs in {s['seconds']:.2f} s, "
                 f"{s['followed']} went terminal; arena {worker._mvj.joint_state_counters()}"
             )
+        # A followed job queues at the end of the store, so a fleet of
+        # several kinds drifts: its later slices hold ever fewer docs of the
+        # kinds that seldom go terminal, and every power-of-two batch bucket
+        # on the way down is a program of its own. The configuration names
+        # the slice sizes whose buckets the warm-up sweeps do not meet; a
+        # rung each loads (a checkout's first run: compiles) them here, so
+        # that the window does not.
+        for n in self.cfg.get("warm_slices", []):
+            s = self.sweep(slice_docs=int(n))
+            log(f"warm-up rung, slices of {n}: {s['docs']} docs in {s['seconds']:.2f} s")
 
     def window(self, seconds: float = 0.0, sweeps: int = 1, opened=None,
                seed: int | None = None) -> dict:
@@ -139,6 +163,7 @@ class Sweeps:
         fl.writes.clear()
         fl.captured.clear()
         fl.sent.clear()
+        fl.sent_base.clear()
         fl.unexpected = 0
         probe = probes.Probe(self.worker, self.registry)
         before = probe.snapshot()

@@ -65,6 +65,9 @@ class ProceduralSource(MetricSource):
         fl = self.fleet
         if kind == 0:
             return fl.cur_times, fl.cur_values[fl.group_of[slot]][fl.local[slot], j]
+        if kind == 2:
+            base = fl.base_values[fl.group_of[slot]][fl.local[slot], j]
+            return fl.base_times[: len(base)], base
         uid = fl.uid[slot]
         block = self._hist.get(uid)
         if block is None:
@@ -99,12 +102,12 @@ class Fleet:
         # is fitted with (a fault of the program, PERF.md section 7), so the
         # reference has to be told it. The whole fleet is cold-fitted once, in
         # creation order, in slices of whole chunks: the index is the slot's
-        # place in its chunk.
+        # rank among the docs of its own kind in its chunk.
         slice_docs = int(cfg["env"]["FOREMAST_SWEEP_SLICE_DOCS"])
         chunk_docs = int(cfg["env"]["FOREMAST_COLD_CHUNK_DOCS"])
         if slice_docs % chunk_docs:
             raise SystemExit("FOREMAST_SWEEP_SLICE_DOCS has to hold whole cold chunks")
-        self.fit_pos = np.arange(self.slots, dtype=np.int64) % chunk_docs
+        self.fit_pos = series.fit_positions(self.groups, self.group_of, chunk_docs)
         epoch = int(cfg["epoch"])
         self.hist_times = epoch + self.step * np.arange(self.n_hist, dtype=np.int64)
         self.hist_end = int(self.hist_times[-1]) + self.step
@@ -121,6 +124,8 @@ class Fleet:
         self.sweep = -1
         self.cur_times = None
         self.cur_values = None
+        self.base_times = None
+        self.base_values = None
         self.spiked = (np.zeros(0, np.int64),) * 4
         self._next = None
         self._thread = None
@@ -130,6 +135,7 @@ class Fleet:
         self.terminal: list[int] = []
         self.captured: list[tuple] = []  # (slot, sweep, uid, fit_pos, status, reason, info)
         self.sent: dict[tuple, np.ndarray] = {}  # (slot, sweep) -> window [F, w]
+        self.sent_base: dict[tuple, np.ndarray] = {}  # canary docs: baseline [F, points]
         self.capture = np.zeros(self.slots, bool)
         self.sample = np.zeros(0, np.int64)
         self.unexpected = 0
@@ -139,7 +145,8 @@ class Fleet:
     def _create(self, slot: int) -> None:
         g = self.groups[self.group_of[slot]]
         uid = int(self.uid[slot])
-        cur, hist = [], []
+        canary = bool(g.get("baseline_window"))
+        cur, hist, base = [], [], []
         for j, a in enumerate(g["aliases"]):
             cu = f"http://prom/cur?q={a}:app{uid}&step={self.step}"
             hu = f"http://prom/hist?q={a}:app{uid}&end={self.hist_end}&step={self.step}"
@@ -147,14 +154,18 @@ class Fleet:
             self.source.index[hu] = (1, slot, j)
             cur.append(f"{a}== {cu}")
             hist.append(f"{a}== {hu}")
+            if canary:
+                bu = f"http://prom/base?q={a}:app{uid}&step={self.step}"
+                self.source.index[bu] = (2, slot, j)
+                base.append(f"{a}== {bu}")
         doc = Document(
             id=f"job-{uid}-{int(self.gen[slot])}",
             app_name=f"app{uid}",
             end_time=self.end_time,
             current_config=" ||".join(cur),
             historical_config=" ||".join(hist),
-            baseline_config="",
-            strategy="continuous",
+            baseline_config=" ||".join(base),
+            strategy="canary" if canary else "continuous",
         )
         self.store.create(doc)
         self.slot_of[doc.id] = slot
@@ -177,12 +188,12 @@ class Fleet:
         return float(self.hist_end + self.step * (sweep + self.w) + 5)
 
     def _draw(self, sweep: int):
-        values, spikes = series.draw_sweep(
+        values, spikes, bases = series.draw_sweep(
             self.seed, sweep, self.groups, (self.group_of, self.local), self.w,
             self.n_hist, self.fam, self.traffic,
         )
         times = self.hist_end + self.step * (sweep + np.arange(self.w, dtype=np.int64))
-        return times, values, spikes
+        return times, values, spikes, bases
 
     def prefetch(self, sweep: int) -> None:
         """Draw the next sweep's windows on a side thread while the
@@ -204,13 +215,22 @@ class Fleet:
         else:
             drawn = self._draw(sweep)
         self._next = None
-        self.cur_times, self.cur_values, self.spiked = drawn
+        self.cur_times, self.cur_values, self.spiked, self.base_values = drawn
+        # a baseline is the window the same length of time before the
+        # current one, a day earlier: the same phase of the daily cycle
+        points = max([self.w] + [b.shape[-1] for b in self.base_values if b is not None])
+        self.base_times = (
+            int(self.cur_times[0]) - 86_400 + self.step * np.arange(points, dtype=np.int64)
+        )
         self.sweep = sweep
         self.capture[:] = False
         self.capture[self.sample] = True
         self.capture[self.spiked[0]] = True
         for s in np.flatnonzero(self.capture):
-            self.sent[(int(s), sweep)] = self.cur_values[self.group_of[s]][self.local[s]].copy()
+            g, i = self.group_of[s], self.local[s]
+            self.sent[(int(s), sweep)] = self.cur_values[g][i].copy()
+            if self.base_values[g] is not None:
+                self.sent_base[(int(s), sweep)] = self.base_values[g][i].copy()
         return self.now(sweep)
 
     def draw_sample(self, n: int) -> None:
@@ -218,8 +238,10 @@ class Fleet:
 
     def end_sweep(self) -> int:
         """After a tick returns: follow every doc that went terminal with
-        a new job of the same service over the same history range.
-        Returns the docs followed."""
+        a new job of the same service over the same history range. The new
+        job queues where the store puts it, at the end (the order a claim
+        sorted by `modifiedAt` gives): a fleet's slices drift from the
+        proportions they were created in. Returns the docs followed."""
         term, self.terminal = self.terminal, []
         for slot in term:
             self._retire(slot)

@@ -2,7 +2,14 @@
 for the bytes (or operations) its dispatches must move, over the device
 time of the module found by its jit name. The bytes are a function of
 shapes kept in chipbench/bytes_model.py; the peak comes from
-chipbench/peaks.py by device kind. Nothing to read -> None, never 0."""
+chipbench/peaks.py by device kind. Nothing to read -> None, never 0.
+
+A module that serves several fleet kinds (the warm single-alias scorer:
+baseline-less docs and, compiled with the pairwise tests, the canary
+bucket, under one jit name) lists them under `kinds`, each with its own
+bytes function: the share is of the summed bytes over the module's whole
+device time. The bytes are linear in the rows, so the rows a kind's
+dispatches carried in all (`fast_docs.<kind>`) are all that is needed."""
 
 from chipbench import bytes_model, peaks
 
@@ -11,17 +18,19 @@ def read(record: dict, params: dict):
     t = record.get("trace") or {}
     mods = {k: v for k, v in (t.get("modules") or {}).items() if k.startswith(params["module"])}
     seconds = sum(v["seconds"] for v in mods.values())
-    count = sum(v["count"] for v in mods.values())
-    if seconds <= 0 or count <= 0:
+    if seconds <= 0:
         return None
     cfg = record["config"]
-    group = next(g for g in cfg["fleet"] if g["kind"] == params["kind"])
-    f = len(group["aliases"])
     w_bucket = bytes_model.window_bucket(cfg["window_points"])
-    # rows a dispatch really carried: the kind's doc-ticks over its dispatches
-    rows = record["counters"].get("fast_docs." + params["kind"], 0.0) / count
-    if rows <= 0:
+    kinds = params.get("kinds") or [{"kind": params["kind"], "bytes_fn": params["bytes_fn"]}]
+    least = 0.0
+    for k in kinds:
+        group = next((g for g in cfg["fleet"] if g["kind"] == k["kind"]), None)
+        rows = record["counters"].get("fast_docs." + k["kind"], 0.0)
+        if group is None or rows <= 0:
+            continue
+        fn = getattr(bytes_model, k["bytes_fn"])
+        least += fn(rows, len(group["aliases"]), w_bucket, cfg["season_steps"])
+    if least <= 0:
         return None
-    fn = getattr(bytes_model, params["bytes_fn"])
-    least = fn(rows, f, w_bucket, cfg["season_steps"]) / peaks.peaks(record["device_kind"])["hbm_bytes_per_s"]
-    return 100.0 * count * least / seconds
+    return 100.0 * least / peaks.peaks(record["device_kind"])["hbm_bytes_per_s"] / seconds

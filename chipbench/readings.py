@@ -3,12 +3,13 @@ a process's set-up is minutes long: the fleet is set up once, then
 
   * the program on each of `--seeds`: a short window (`--sweeps` sweeps at
     the cell's own load) of what that seed sends, and the comparison;
-  * each fault of chipbench/faults.py planted under the timed path, a
-    window, the comparison: it has to come out as not correct;
+  * each fault of each fleet kind (chipbench/faults/<kind>.py) planted
+    under the timed path, a window, the comparison: it has to come out as
+    not correct, by the number the fault names;
   * the control (chipbench/control.py) on each of `--control-seeds`.
 
     python3 -m chipbench.readings --workload <cell> --seeds 1,2,3 \\
-        --control-seeds 4,5,6 [--sweeps 4] [--faults 1] [--tiny]
+        --control-seeds 4,5,6 [--sweeps 4] [--control-sweeps 15] [--faults 1] [--tiny]
 
 One JSON line a reading on standard output. The benchmark's own runs do
 not run this.
@@ -45,6 +46,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", default="")
     ap.add_argument("--control-seeds", default="")
     ap.add_argument("--sweeps", type=int, default=4)
+    ap.add_argument("--control-sweeps", type=int, default=15, help="as many as a run's window holds")
     ap.add_argument("--faults", type=int, choices=(0, 1), default=1)
     ap.add_argument("--tiny", action="store_true")
     args = ap.parse_args(argv)
@@ -66,16 +68,21 @@ def main(argv=None) -> int:
             emit("program", seed, numbers, detail, window_s=win["window_s"],
                  windows=win["windows"], seconds=time.perf_counter() - t)
         if args.faults:
-            for name, plant in faults.FAULTS.items():
+            # the faults that leave docs unjudged go last: a doc left claimed
+            # stays so, and would read as unjudged under every later fault
+            planted = sorted(
+                faults.fleet_faults(cfg["fleet"]).items(), key=lambda kv: kv[1][1] == "unjudged"
+            )
+            for name, (plant, number) in planted:
                 seed = (seeds[-1] if seeds else 1) + 1
                 with plant():
                     win = sw.window(sweeps=args.sweeps, seed=seed)
                 numbers, detail = driver.judge(win, cfg, log)
-                emit("fault:" + name, seed, numbers, detail)
+                emit("fault:" + name, seed, numbers, detail, fails=number)
         sw.free()
     for seed in control_seeds:
         t = time.perf_counter()
-        out = control.control_margin(cfg, cell.traffic, seed, 15, log=log)
+        out = control.control_margin(cfg, cell.traffic, seed, args.control_sweeps, log=log)
         print(json.dumps({"reading": "control", **out, "seconds": time.perf_counter() - t},
                          default=float), flush=True)
     return 0

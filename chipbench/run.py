@@ -43,6 +43,10 @@ class Context:
     def window_open(self) -> None:
         import jax
 
+        # the window's edges and the sync marker are stamped on the span
+        # timeline (`spans.clock()`), the clock the program's spans carry
+        from foremast_tpu.observe.spans import clock
+
         self.setup_s = time.time() - T_START
         if self.args.trace:
             self.trace_dir = os.path.join(self.out_dir, "trace")
@@ -53,15 +57,17 @@ class Context:
             jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
             # a marker on both clocks: spans carry wall time, the trace its own
             with jax.profiler.TraceAnnotation("chipbench.sync"):
-                self.sync = time.time()
+                self.sync = clock()
                 time.sleep(0.002)
-        self.window_wall = time.time()
+        self.window_wall = clock()
         self.log(f"window open after {self.setup_s:.1f} s of set-up")
 
     def window_close(self) -> None:
         import jax
 
-        self.window_wall = (self.window_wall, time.time())
+        from foremast_tpu.observe.spans import clock
+
+        self.window_wall = (self.window_wall, clock())
         if self.args.trace:
             jax.profiler.stop_trace()
         self.log("window closed")

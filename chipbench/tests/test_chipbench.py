@@ -116,10 +116,9 @@ def test_fails_without_a_chip():
 # -- the faults a sweep cell can have come out as not correct ---------------
 
 
-def _run_broken(monkeypatch, capsys, fault) -> dict:
+def _run_broken(monkeypatch, capsys, cell, plant) -> dict:
     """Drive a whole run (no look for a chip: --tiny) with the timed path
     broken from the moment the window opens."""
-    from chipbench import faults
     from chipbench import run as runmod
 
     real_open = runmod.Context.window_open
@@ -127,34 +126,115 @@ def _run_broken(monkeypatch, capsys, fault) -> dict:
 
     def window_open(self):
         real_open(self)
-        stack.enter_context(faults.FAULTS[fault]())
+        stack.enter_context(plant())
 
     monkeypatch.setattr(runmod.Context, "window_open", window_open)
     with stack:
-        runmod.main(["--workload", CELLS[0], "--seed", "99", "--seconds", "2", "--trace", "0", "--tiny"])
+        runmod.main(["--workload", cell, "--seed", "99", "--seconds", "2", "--trace", "0", "--tiny"])
     return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("fault,number", [
-    ("half_of_the_batch_left_out", "unjudged"),
-    ("an_answer_altered", "flip_rate"),
-])
-def test_fault_comes_out_not_correct(monkeypatch, capsys, clean_env, fault, number):
-    line = _run_broken(monkeypatch, capsys, fault)
+def _cell_faults():
+    """Every fault of every fleet kind, in the cell whose fleet has the most
+    kinds beside it (each kind's faults are planted once)."""
+    from chipbench import faults
+
+    seen, out = set(), []
+    for cell in sorted(CELLS, key=lambda c: len(spec.Cell(c).config["fleet"])):
+        for name in faults.fleet_faults(spec.Cell(cell).config["fleet"]):
+            if name not in seen:
+                seen.add(name)
+                out.append((cell, name))
+    return out
+
+
+@pytest.mark.parametrize("cell,fault", _cell_faults())
+def test_fault_comes_out_not_correct(monkeypatch, capsys, clean_env, cell, fault):
+    from chipbench import faults
+
+    plant, number = faults.fleet_faults(spec.Cell(cell).config["fleet"])[fault]
+    line = _run_broken(monkeypatch, capsys, cell, plant)
     assert line["correct"] is False
     value, limit = line["numbers"][number]
     assert value > limit
 
 
-def test_control_in_bfloat16_comes_out_not_correct():
-    """The reference in bfloat16, put in the program's place at a size a
-    test can hold (the tiny fleet, every service compared, a window's 15 sweeps)."""
+def test_a_fleet_kind_finds_its_faults_by_name():
+    from chipbench import faults
+
+    both = {"half_of_the_batch_left_out": "unjudged", "an_answer_altered": "flip_rate"}
+    for kind in ("lstm", "univariate", "baseline", "bivariate"):
+        assert {k: v[1] for k, v in faults.faults_of(kind).items()} == both
+    with pytest.raises(SystemExit, match="faults/nokind.py"):
+        faults.faults_of("nokind")
+
+
+@pytest.mark.parametrize("cell,seed,room", [
+    ("hybrid4-daily.warm-sweep", 5, 2.0),
+    ("mixed-auto-daily.graded-sweep", 3, 2.0),
+])
+def test_control_in_bfloat16_comes_out_not_correct(cell, seed, room):
+    """Every kind's reference in bfloat16, put in the program's place at a
+    size a test can hold (the tiny fleet, every service compared, a window's
+    15 sweeps): one of the cell's numbers has to fail."""
     from chipbench import control
 
-    cell = spec.Cell(CELLS[0])
-    out = control.control_margin(cell.sized(True), cell.traffic, seed=5, sweeps=15)
+    cell = spec.Cell(cell)
+    out = control.control_margin(cell.sized(True), cell.traffic, seed=seed, sweeps=15)
     assert out["correct"] is False, out
-    assert out["flip_rate"] > 2 * out["limit"]
+    assert out["flip_rate"] > room * out["limit"]
+
+
+@pytest.mark.parametrize("kind", ["univariate", "baseline", "bivariate"])
+def test_a_kind_s_control_fails_the_kind_s_own_limit(kind):
+    """Each new kind's control on its own rows, under the cell's own mix
+    (its excursions straddle the kind's thresholds; half of the docs carry
+    one here, so that 256 services stand for a run's spiked docs): the
+    bfloat16 twin moves flags the float32 reference holds by more than the
+    floor, and reads over the limit the configuration gives the kind; the
+    float32 reference never disagrees with itself."""
+    from chipbench import control
+
+    cell = spec.Cell("mixed-auto-daily.graded-sweep")
+    cfg = cell.sized(True)
+    cfg["fleet"] = [dict(g, services=256) for g in cfg["fleet"] if g["kind"] == kind]
+    traffic = dict(cell.traffic, spike_doc_share=0.5)
+    job = control.ControlJob(cfg, traffic, seed=7, sweeps=8)
+    g = cfg["fleet"][0]
+    ref = compare.reference_of(kind)
+
+    def history(uid):
+        from chipbench import series
+
+        return series.history(job.history_seed, uid, len(g["aliases"]), job.n_hist, job.fam)
+
+    a = ref.judge(job.rows, g, cfg, history)
+    b = ref.judge(job.rows, g, cfg, history)
+    c = ref.judge(job.rows, g, cfg, history, control=True)
+    assert (a["flags"] == b["flags"]).all() and a["flags"].any()
+    assert a["flags"].shape == a["margins"].shape == (len(job.rows), job.w)
+    # bfloat16 moves the scores: a point the reference holds by a margin
+    # under the rounding it brings flips, one it holds widely may not
+    moved = c["flags"] != a["flags"]
+    assert moved.any()
+    assert not (moved & (a["margins"] > 2.0)).any()
+    numbers, _detail = compare.judge_sweeps(job, cfg, lambda m: None, control=True)
+    value, limit = numbers["flip_rate." + kind]["value"], numbers["flip_rate." + kind]["limit"]
+    assert limit is not None and value > limit, (value, limit)
+    assert compare.verdict(numbers) is False
+
+
+def test_a_kind_s_own_limit_decides():
+    """A kind whose rows are a small share of the pool cannot hide under
+    the pooled limit: its own number fails the run."""
+    numbers = {
+        "flip_rate": {"value": 1.0, "limit": 3.0},
+        "flip_rate.lstm": {"value": 0.0, "limit": None},
+        "flip_rate.bivariate": {"value": 9.0, "limit": 8.0},
+    }
+    assert compare.verdict(numbers) is False
+    numbers["flip_rate.bivariate"]["value"] = 7.0
+    assert compare.verdict(numbers) is True
 
 
 def test_a_fleet_kind_finds_its_reference_by_name(monkeypatch):
@@ -185,6 +265,90 @@ def test_a_fleet_kind_finds_its_reference_by_name(monkeypatch):
     assert out["flip_margin"] == 0.5 and out["flip_rate"] == 1000.0 and out["correct"] is False
     with pytest.raises(SystemExit, match="references/nokind.py"):
         compare.reference_of("nokind")
+
+
+# -- a fleet of several kinds -------------------------------------------------
+
+
+def _config(name: str) -> dict:
+    return json.load(open(os.path.join(ROOT, "chipbench", "configs", name + ".json")))
+
+
+def test_fit_position_of_a_one_group_fleet_is_slot_mod_chunk():
+    from chipbench import series
+
+    cfg = _config("hybrid4-daily")
+    group_of, _local = series.slot_layout(cfg["fleet"])
+    chunk = int(cfg["env"]["FOREMAST_COLD_CHUNK_DOCS"])
+    pos = series.fit_positions(cfg["fleet"], group_of, chunk)
+    assert (pos == np.arange(len(group_of)) % chunk).all()
+
+
+@pytest.mark.parametrize("light,slice_docs", [((16384, 8192, 8192), 16384), ((8192, 4096, 4096), 12288)])
+def test_fit_position_by_kind_in_the_mixed_fleet(light, slice_docs):
+    """Both fleets ISSUE 26 allows: every slice holds exactly 8,192 LSTM
+    docs, and an LSTM doc's place in its fit batch is its rank among the
+    LSTM docs of its cold chunk (the brute-force count agrees)."""
+    from chipbench import series
+
+    cfg = _config("mixed-auto-daily")
+    groups = [dict(g) for g in cfg["fleet"]]
+    for g, n in zip(groups[1:], light):
+        g["services"] = n
+    group_of, local = series.slot_layout(groups)
+    assert len(group_of) == 32768 + sum(light)
+    per_slice = [int((group_of[i:i + slice_docs] == 0).sum()) for i in range(0, len(group_of), slice_docs)]
+    assert per_slice == [8192] * 4
+    chunk = int(cfg["env"]["FOREMAST_COLD_CHUNK_DOCS"])
+    assert slice_docs % chunk == 0
+    pos = series.fit_positions(groups, group_of, chunk)
+    for c0 in (0, 5 * chunk, len(group_of) - chunk):
+        for gi in range(len(groups)):
+            members = np.flatnonzero(group_of[c0:c0 + chunk] == gi) + c0
+            assert (pos[members] == np.arange(len(members))).all()
+    lstm = np.flatnonzero(group_of == 0)
+    assert int((pos[lstm] != lstm % chunk).sum()) > 30000  # the old formula's misses
+    # groups of one kind and alias count share a fit batch
+    twins = [dict(groups[0], services=64), dict(groups[0], services=64, aliases=["a", "b", "c", "d"])]
+    g2, _ = series.slot_layout(twins)
+    assert (series.fit_positions(twins, g2, 32) == np.arange(128) % 32).all()
+
+
+def test_a_canary_group_s_docs_carry_strategy_and_baseline(clean_env):
+    from chipbench import fleet as fleetlib
+    from chipbench import series
+
+    cell = spec.Cell("mixed-auto-daily.graded-sweep")
+    cfg = cell.sized(True)
+    fl = fleetlib.Fleet(cfg, cell.traffic, seed=2**31 + 5)
+    for slot in range(fl.slots):
+        g = fl.groups[fl.group_of[slot]]
+        doc = fl.store._docs[fl.doc_id[slot]]
+        if g.get("baseline_window"):
+            assert doc.strategy == "canary"
+            assert doc.baseline_config.count("http://prom/base?") == len(g["aliases"])
+        else:
+            assert doc.strategy == "continuous" and doc.baseline_config == ""
+    fl.draw_sample(8)
+    fl.begin_sweep(3)
+    canary = next(i for i, g in enumerate(fl.groups) if g.get("baseline_window"))
+    slot = int(np.flatnonzero(fl.group_of == canary)[0])
+    uid, alias = int(fl.uid[slot]), fl.groups[canary]["aliases"][0]
+    t, v = fl.source.fetch(f"http://prom/base?q={alias}:app{uid}&step={fl.step}")
+    assert len(t) == len(v) == fl.groups[canary]["baseline_window"]["points"]
+    assert (np.diff(t) == fl.step).all() and t[0] == fl.cur_times[0] - 86_400
+    assert (v == fl.base_values[canary][fl.local[slot], 0]).all()
+    # the same seed sends the same windows and baselines; the baseline is
+    # a stream of its own, not the current window's
+    a = series.draw_sweep(7, 3, fl.groups, (fl.group_of, fl.local), fl.w, fl.n_hist, fl.fam, cell.traffic)
+    b = series.draw_sweep(7, 3, fl.groups, (fl.group_of, fl.local), fl.w, fl.n_hist, fl.fam, cell.traffic)
+    assert all((x == y).all() for x, y in zip(a[0], b[0])) and (a[2][canary] == b[2][canary]).all()
+    assert [x is None for x in a[2]] == [not g.get("baseline_window") for g in fl.groups]
+    assert not (a[2][canary] == a[0][canary]).all()
+    # two groups of one alias count do not send each other's windows
+    uni = next(i for i, g in enumerate(fl.groups) if g["kind"] == "univariate")
+    n = min(len(a[0][uni]), len(a[0][canary]))
+    assert not np.allclose(a[0][uni][:n], a[0][canary][:n])
 
 
 # -- the comparison's own arithmetic -----------------------------------------
@@ -248,7 +412,26 @@ def test_row_bytes_match_the_program():
             int(np.prod(leaf.shape)) * leaf.dtype.itemsize for leaf in jax.tree.leaves(tmpl)
         )
         assert bytes_model.lstm_row_bytes(f, 1440) == want
-    cfg = json.load(open(os.path.join(ROOT, "chipbench", "configs", "hybrid4-daily.json")))
+    bi = MultivariateJudge()._bi_template()
+    assert bytes_model.bivariate_row_bytes() == sum(
+        int(np.prod(leaf.shape)) * leaf.dtype.itemsize for leaf in jax.tree.leaves(bi)
+    )
+    mixed = _config("mixed-auto-daily")
+    assert mixed["row_bytes"] == {
+        "lstm": bytes_model.lstm_row_bytes(4, 1440),
+        "univariate": arena._row_bytes(1440),
+        "bivariate": bytes_model.bivariate_row_bytes(),
+    }
+    # every group of the mixed fleet names the function that counts its
+    # operations, and each kind's dispatch bytes hold its rows' state
+    for g in mixed["fleet"]:
+        module, fn = g["flops_fn"].rsplit(".", 1)
+        assert getattr(bytes_model, fn)(len(g["aliases"]), 32) > 0 and module == "bytes_model"
+    b = 4096
+    assert bytes_model.univariate_score_bytes(b, 1, 32, 1440) > b * arena._row_bytes(32)
+    assert bytes_model.canary_score_bytes(b, 1, 32, 1440) > bytes_model.univariate_score_bytes(b, 1, 32, 1440)
+    assert bytes_model.bivariate_score_bytes(b, 2, 32, 1440) > b * bytes_model.bivariate_row_bytes()
+    cfg = _config("hybrid4-daily")
     assert cfg["row_bytes"] == 61_585
     # the fleet fills whole slices (no pad row) and a power-of-two arena
     # (no reserved rows): live rows = capacity = 1.88 GiB
@@ -289,17 +472,54 @@ def test_reduction_of_the_recorded_trace():
 # -- data-driven: new files and entries alone add a cell ----------------------
 
 
+_STAND_IN_REFERENCE = '''"""A stand-in reference written by the test: it flags nothing and holds
+no point by any margin, so no disagreement with it counts."""
+import numpy as np
+
+
+def judge(rows, group, cfg, history, control=False, log=None):
+    assert history(rows[0]["uid"]).shape == (len(group["aliases"]), cfg["history_points"])
+    if group.get("baseline_window"):
+        assert all(r["base"].shape == (1, group["baseline_window"]["points"]) for r in rows)
+    k, w = len(rows), rows[0]["sent"].shape[-1]
+    return {"flags": np.zeros((k, w), bool), "margins": np.zeros((k, w), np.float32)}
+'''
+
+_STAND_IN_FAULTS = '''import contextlib
+
+FAULTS = {"none": (contextlib.nullcontext, "unjudged")}
+'''
+
+
 def test_new_cell_config_mix_metric_and_reader_need_no_edit(tmp_path):
+    """A later PR's cell, by files and entries alone: a configuration of
+    FOUR groups (4-alias, 2-alias, 1-alias and 1-alias with a baseline; the
+    new kinds under names of their own, with stand-in references and faults
+    the test writes), a mix, a metric and a reader. It runs `--tiny` through
+    BrainWorker on the CPU, and the LSTM group's judgments have to agree
+    with references/lstm.py: they do only where each LSTM doc's place in its
+    fit batch is its rank among the LSTM docs of its cold chunk."""
     root = tmp_path / "repo"
     shutil.copytree(
         os.path.join(ROOT, "chipbench"), root / "chipbench",
         ignore=shutil.ignore_patterns("__pycache__", "testdata"),
     )
     bench = json.loads(json.dumps(BENCH))
-    cfg = json.load(open(os.path.join(ROOT, "chipbench", "configs", "hybrid4-daily.json")))
-    cfg["name"] = "monitor6-daily"
-    cfg["fleet"][0]["aliases"] += ["cpu", "memory"]
-    json.dump(cfg, open(root / "chipbench" / "configs" / "monitor6-daily.json", "w"))
+    cfg = _config("hybrid4-daily")
+    cfg["name"] = "four-kinds"
+    lstm = cfg["fleet"][0]
+    cfg["tiny"]["fleet"] = [
+        dict(lstm, services=40),
+        {"kind": "pairx", "aliases": ["latency", "tps"], "services": 24},
+        {"kind": "solo", "aliases": ["cpu"], "services": 20},
+        {"kind": "solob", "aliases": ["latency"], "services": 12,
+         "baseline_window": {"points": 30, "shift_share": 0.2, "shift": 0.15}},
+    ]
+    cfg["fleet"] = [dict(g, services=g["services"] * 64) for g in cfg["tiny"]["fleet"]]
+    json.dump(cfg, open(root / "chipbench" / "configs" / "four-kinds.json", "w"))
+    for kind in ("pairx", "solo", "solob"):
+        (root / "chipbench" / "references" / f"{kind}.py").write_text(_STAND_IN_REFERENCE)
+        (root / "chipbench" / "faults" / f"{kind}.py").write_text(_STAND_IN_FAULTS)
     mix = json.load(open(os.path.join(ROOT, "chipbench", "traffic", "warm-sweep.json")))
     mix["spike_doc_share"] = 0.01
     json.dump(mix, open(root / "chipbench" / "traffic" / "stormy.json", "w"))
@@ -311,35 +531,40 @@ def test_new_cell_config_mix_metric_and_reader_need_no_edit(tmp_path):
          "moves": "windows_per_s", "source": "program_counter", "reader": "doc_ticks", "params": {"scale": 2}},
         open(root / "chipbench" / "layers" / "doc_ticks.sweep.json", "w"),
     )
-    bench["configs"].append({"name": "monitor6-daily", "source": "x", "reduced": [],
-                             "file": "chipbench/configs/monitor6-daily.json", "why": "x"})
-    bench["workloads"].append({"name": "monitor6-daily.stormy", "config": "monitor6-daily",
+    bench["configs"].append({"name": "four-kinds", "source": "x", "reduced": [],
+                             "file": "chipbench/configs/four-kinds.json", "why": "x"})
+    bench["workloads"].append({"name": "four-kinds.stormy", "config": "four-kinds",
                                "traffic": "stormy", "chips": 1, "why": "x"})
     bench["per_layer"].append({"name": "doc_ticks.sweep", "unit": "count", "better": "higher",
                                "source": "program_counter", "layer": "job plane jobs/worker.py",
-                               "moves": "windows_per_s", "workloads": ["monitor6-daily.stormy"]})
+                               "moves": "windows_per_s", "workloads": ["four-kinds.stormy"]})
     json.dump(bench, open(root / "BENCHMARK.json", "w"))
-    cell = spec.Cell("monitor6-daily.stormy", root=str(root))
-    assert len(cell.config["fleet"][0]["aliases"]) == 6
+    cell = spec.Cell("four-kinds.stormy", root=str(root))
+    assert [len(g["aliases"]) for g in cell.sized(True)["fleet"]] == [4, 2, 1, 1]
     assert cell.traffic["spike_doc_share"] == 0.01
     assert [m["name"] for m in cell.per_layer] == ["doc_ticks.sweep"]
-    sys.path.insert(0, str(root))
-    try:
-        import importlib
-
-        import chipbench.readers as readers_pkg
-
-        readers_pkg.__path__.append(str(root / "chipbench" / "readers"))
-        importlib.invalidate_caches()
-        got = spec.read_layers(cell, {"doc_ticks": 21})
-    finally:
-        sys.path.remove(str(root))
-        readers_pkg.__path__.pop()
-    assert got == {"doc_ticks.sweep": {"value": 42.0, "unit": "count"}}
     # the cells that were there are untouched by the additions
     assert [m["name"] for m in spec.Cell(CELLS[0], root=str(root)).per_layer] == [
         m["name"] for m in spec.Cell(CELLS[0]).per_layer
     ]
+    # the copy is run as a checkout of its own (its chipbench first on the
+    # path, the program from the repo behind it)
+    out = subprocess.run(
+        [sys.executable, "-m", "chipbench.run", "--workload", "four-kinds.stormy", "--seed", "31",
+         "--seconds", "2", "--trace", "1", "--tiny"],
+        cwd=root, env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=f"{root}{os.pathsep}{ROOT}"),
+        capture_output=True, text=True, timeout=900,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    numbers = line["numbers"]
+    assert line["correct"] is True and line["failed"] == 0, numbers
+    sweeps = numbers["compared.lstm"][0] / 40
+    assert sweeps >= 1 and numbers["compared"][0] == 96 * sweeps
+    for kind, n in (("pairx", 24), ("solo", 20), ("solob", 12)):
+        assert numbers["compared." + kind][0] == n * sweeps
+    assert numbers["flip_rate.lstm"][0] <= cell.config["correct_limits"]["flip_rate"]
+    assert line["metrics"]["doc_ticks.sweep"] == {"value": 2.0 * 96 * sweeps, "unit": "count"}
 
 
 def test_benchmark_json_keeps_to_the_contract():
@@ -355,6 +580,10 @@ def test_benchmark_json_keeps_to_the_contract():
         assert os.path.exists(os.path.join(ROOT, "chipbench", "readers", layer["reader"] + ".py"))
     for c in BENCH["configs"]:
         assert c["file"].startswith("chipbench/") and os.path.exists(os.path.join(ROOT, c["file"]))
+    # a why, a layer and a source: 1 to 200 characters on one line
+    said = [c[k] for c in BENCH["configs"] for k in ("source", "why")]
+    said += [w["why"] for w in BENCH["workloads"]] + [m["layer"] for m in BENCH["per_layer"]]
+    assert all(1 <= len(s) <= 200 and "\n" not in s and "\t" not in s for s in said)
     assert sum(w["chips"] == 4 for w in BENCH["workloads"]) <= max(1, len(BENCH["workloads"]) // 4)
 
 
@@ -382,7 +611,7 @@ def test_warm_joint_program_compiles_for_v5e_at_real_size(topo):
         lstm_joint_score_from_rows,
     )
 
-    cfg = json.load(open(os.path.join(ROOT, "chipbench", "configs", "hybrid4-daily.json")))
+    cfg = _config("hybrid4-daily")
     f = len(cfg["fleet"][0]["aliases"])
     b = int(cfg["env"]["FOREMAST_SWEEP_SLICE_DOCS"])
     cap = cfg["fleet"][0]["services"]

@@ -148,7 +148,13 @@ def _gaps(u: list, window_ns: tuple, spans: list, wall_to_ns, top: int) -> list:
             cover = [x for x in inside if x[0] <= lo and x[1] >= hi]
             name = max(cover, key=lambda x: x[2])[3] if cover else NO_SPAN
             named[name] = named.get(name, 0.0) + (hi - lo) / 1e9
-    return sorted(([k, v] for k, v in named.items()), key=lambda kv: -kv[1])[:top]
+    ranked = sorted(([k, v] for k, v in named.items()), key=lambda kv: -kv[1])
+    kept = ranked[:top]
+    # the part no stage span names is kept whatever its rank: a reader of
+    # it must never read 0.0 because it fell off the list
+    if NO_SPAN in named and all(k != NO_SPAN for k, _ in kept):
+        kept[-1] = [NO_SPAN, named[NO_SPAN]]
+    return kept
 
 
 def reduce_run(ctx, record: dict, on_chip: bool) -> dict:
