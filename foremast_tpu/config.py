@@ -138,6 +138,44 @@ class PairwiseConfig:
             raise ValueError(f"unknown pairwise algorithm {self.algorithm!r}")
 
 
+# Every value `ML_ALGORITHM` may take. The univariate names are the
+# engine's model registry (`engine.scoring.AI_MODEL` with what `models/`
+# registers on import; tests/test_config.py holds the two together), the
+# joint ones `engine.multivariate.MULTIVARIATE_ALGOS`. Anything else is
+# refused when the configuration is loaded: `select_mode` used to hand
+# an unknown name to the univariate judge, so a mistyped algorithm judged
+# with another detector, silently.
+UNIVARIATE_ALGORITHMS = frozenset(
+    {
+        "moving_average_all",
+        "moving_average",
+        "ewma",
+        "exponential_smoothing",
+        "double_exponential_smoothing",
+        "holtwinters",
+        "holt_winters",
+        "phase_means",
+        "auto_univariate",
+        "seasonal",
+        "prophet",
+        "seasonal_hourly",
+    }
+)
+JOINT_ALGORITHMS = frozenset(
+    {"auto", "bivariate_normal", "lstm_autoencoder", "backbone"}
+)
+KNOWN_ALGORITHMS = UNIVARIATE_ALGORITHMS | JOINT_ALGORITHMS
+
+
+def check_algorithm(name: str) -> str:
+    """`name` if `ML_ALGORITHM` may take it, ValueError otherwise."""
+    if name not in KNOWN_ALGORITHMS:
+        raise ValueError(
+            f"unknown ML_ALGORITHM {name!r}; known: {sorted(KNOWN_ALGORITHMS)}"
+        )
+    return name
+
+
 @dataclasses.dataclass(frozen=True)
 class BrainConfig:
     """Full engine config — env parity with `foremast-brain.yaml:21-81`."""
@@ -236,7 +274,9 @@ class BrainConfig:
             min_friedman_points=get("MIN_FRIEDMAN_DATA_POINTS", 20),
         )
         return BrainConfig(
-            algorithm=get("ML_ALGORITHM", "moving_average_all"),
+            algorithm=check_algorithm(
+                get("ML_ALGORITHM", "moving_average_all")
+            ),
             anomaly=anomaly,
             pairwise=pairwise,
             season_steps=get("ML_SEASON_STEPS", 1440),
@@ -296,7 +336,9 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
         "(per-series structure screen over {mean, HW or phase_means by "
         "season length, Fourier seasonal} — recommended for unknown "
         "metric mixes), `auto`, `bivariate_normal`, `lstm_autoencoder` "
-        "(hybrid: AE + seasonal-residual Gaussian)",
+        "(hybrid: AE + seasonal-residual Gaussian), `backbone` (the shared "
+        "sequence backbone, docs/backbone.md: `ML_THRESHOLD` is then a "
+        "score in nats). An unknown name is an error at load",
         "engine",
     ),
     EnvKnob(
@@ -447,6 +489,33 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
         "state, the same path univariate re-checks use. `0` routes every "
         "joint doc through the per-task object path (the pre-round-7 "
         "behavior — ~10x slower per joint doc at fleet scale)",
+    ),
+    EnvKnob(
+        "FOREMAST_BACKBONE_MODEL",
+        None,
+        "path",
+        "model file of `ML_ALGORITHM=backbone`: the sequence model's "
+        "config.json keys plus the `share` of it this process holds and "
+        "the seed of its weights (default: the packaged "
+        "`foremast_tpu/models/configs/command-a-plus-05-2026.json`; "
+        "docs/backbone.md)",
+    ),
+    EnvKnob(
+        "FOREMAST_BACKBONE_CONTEXT",
+        "10080",
+        "int",
+        "history points a backbone sequence keeps (the newest): all but "
+        "the last are prefilled into its cache row, whose leaves are sized "
+        "to that rounded up to a multiple of 128",
+    ),
+    EnvKnob(
+        "FOREMAST_BACKBONE_ROWS",
+        "64",
+        "int",
+        "capacity of the backbone's prefix cache in sequences (one per "
+        "alias of a document), allocated once and never grown: a row is "
+        "tens of MB (91.6 MB at the default model and context), so size it "
+        "to the fleet and to the HBM the weights leave",
     ),
     EnvKnob(
         "FOREMAST_CANARY_COLUMNAR",

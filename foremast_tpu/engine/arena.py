@@ -289,6 +289,10 @@ class RowArena:
         # operator counters report documents, and their hits/misses are
         # never counted (positions >= assign()'s n_real are pads)
         self.pad_live = 0
+        # called with the key of every row `assign` recycles: an owner
+        # whose rows cannot be scattered again from a host entry (the
+        # backbone's prefix cache) drops what pointed at the row
+        self.on_evict = None
         # multi-tenant QoS (ISSUE 20): None unless the process is
         # tenanted with >=2 tenants or an arena_rows envelope — the
         # untenanted arena keeps today's placement byte-for-byte
@@ -589,6 +593,8 @@ class RowArena:
                         del self.rows[old]
                         self.row_entry.pop(old, None)
                         self.evictions += 1
+                        if self.on_evict is not None:
+                            self.on_evict(old)
                         qos.note_drop(old)
                         qos.charge(tenant)
                         self.rows[k] = rv
@@ -652,6 +658,8 @@ class RowArena:
                         del self.rows[old]
                         self.row_entry.pop(old, None)
                         self.evictions += 1
+                        if self.on_evict is not None:
+                            self.on_evict(old)
                         if _is_pad_key(old):
                             self.pad_live -= 1
                         if qos is not None:
@@ -885,6 +893,18 @@ class RowArena:
             self._qos.flush()
         return rows, scatter
 
+    def release(self, keys) -> None:
+        """Give back the rows of `keys` (unsharded arenas): a row that was
+        assigned for one judgment alone."""
+        for k in keys:
+            r = self.rows.pop(k, None)
+            if r is None:
+                continue
+            self.row_entry.pop(k, None)
+            self.row_key[r] = None
+            self.stamp[r] = -1
+            self.free.append(r)
+
     def device_bytes(self) -> int:
         """HBM footprint of this arena's buffers on ONE device: the full
         capacity when replicated (total cost = this x device count — the
@@ -1031,12 +1051,22 @@ class TreeArena(RowArena):
         max_bytes: int | None = None,
         sharding=None,
         shards: int = 1,
+        fixed_rows: int | None = None,
     ):
         """`template`: pytree of `jax.ShapeDtypeStruct` (or anything with
-        .shape/.dtype) describing ONE row, without the capacity axis."""
+        .shape/.dtype) describing ONE row, without the capacity axis.
+
+        `fixed_rows` fixes the capacity: the whole arena is allocated at
+        the first assignment, the byte budgets are not consulted, it never
+        grows (growth is a host round trip of every row, and a row of a
+        prefix cache is tens of MB), and a batch larger than it is refused
+        (`assign` returns None)."""
         self.template = template
+        self.fixed_rows = None if fixed_rows is None else int(fixed_rows)
         leaves = jax.tree.leaves(template)
-        row_bytes = sum(
+        # the template's leaves are ShapeDtypeStructs: shapes and dtypes,
+        # no device value
+        row_bytes = sum(  # foremast: ignore[device-flow]
             int(np.prod(leaf.shape, dtype=np.int64))
             * np.dtype(leaf.dtype).itemsize
             for leaf in leaves
@@ -1047,12 +1077,14 @@ class TreeArena(RowArena):
             sharding=sharding,
             shards=shards,
         )
+        if self.fixed_rows is not None:
+            self.max_rows = self.hard_rows = self.fixed_rows
 
     def _min_rows(self) -> int:
         # joint rows are fat (an f=4 LSTM-AE row is ~60 KB vs the
         # univariate daily row's ~5.8 KB); pre-allocating StateArena's
         # 8,192-row floor would burn ~0.5 GB of HBM on a 10-job fleet
-        return 64
+        return 64 if self.fixed_rows is None else self.fixed_rows
 
     def _alloc(self, cap: int):
         return jax.tree.map(
@@ -1061,6 +1093,10 @@ class TreeArena(RowArena):
         )
 
     def _grow(self, pad: int):
+        if self.fixed_rows is not None:
+            raise RuntimeError(
+                f"TreeArena of fixed capacity {self.fixed_rows} asked to grow"
+            )
         return jax.tree.map(
             lambda s, leaf: jnp.concatenate(
                 [s, jnp.zeros((pad, *leaf.shape), leaf.dtype)]

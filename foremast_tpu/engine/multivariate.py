@@ -8,7 +8,12 @@ registry; here the same selection is explicit:
   * `ML_ALGORITHM=auto`             -> by metric count (the design.md rule)
   * `ML_ALGORITHM=bivariate_normal` -> joint 2-metric judgment (pairs only)
   * `ML_ALGORITHM=lstm_autoencoder` -> joint judgment for 2+ metrics
-  * anything else                   -> univariate per-metric (HealthJudge)
+  * `ML_ALGORITHM=backbone`         -> the shared sequence backbone, every
+                                       alias of every job one sequence
+                                       (engine/backbone.py)
+  * any univariate name             -> univariate per-metric (HealthJudge);
+                                       an unknown name is refused when the
+                                       configuration is loaded (config.py)
 
 Joint detectors align the job's metrics on common timestamps (a joint
 observation needs every coordinate), judge the joint series, and
@@ -84,7 +89,10 @@ log = logging.getLogger("foremast_tpu.engine.multivariate")
 ALGO_BIVARIATE = "bivariate_normal"
 ALGO_LSTM = "lstm_autoencoder"
 ALGO_AUTO = "auto"
-MULTIVARIATE_ALGOS = frozenset({ALGO_BIVARIATE, ALGO_LSTM, ALGO_AUTO})
+ALGO_BACKBONE = "backbone"
+MULTIVARIATE_ALGOS = frozenset(
+    {ALGO_BIVARIATE, ALGO_LSTM, ALGO_AUTO, ALGO_BACKBONE}
+)
 
 # Sigmas ABOVE the configured threshold at which residual-MVN evidence is
 # strong enough to flag alone; below it (but above the configured cutoff)
@@ -109,7 +117,10 @@ FALLBACK_AUTO = "auto_univariate"
 
 
 def select_mode(algorithm: str, n_metrics: int) -> str:
-    """'univariate' | 'bivariate' | 'lstm' for a job with n_metrics."""
+    """'univariate' | 'bivariate' | 'lstm' | 'backbone' for a job with
+    n_metrics."""
+    if algorithm == ALGO_BACKBONE:
+        return "backbone"
     if algorithm == ALGO_AUTO:
         if n_metrics <= 1:
             return "univariate"
@@ -426,6 +437,11 @@ class MultivariateJudge:
             "shard_moves": 0,
             "fallbacks": 0,
         }
+        # the shared sequence backbone (ISSUE 27), built at its first
+        # use: its weights are gigabytes
+        self._backbone = None
+        # backbone sequence key -> the joint cache key of its document
+        self._backbone_doc: dict = {}
         # joint columnar batch-padding accounting (ISSUE 13) — the
         # joint-path counterpart of HealthJudge.pad_rows_total; the
         # worker's device_mesh varz sums both
@@ -444,12 +460,15 @@ class MultivariateJudge:
         uni: list[MetricTask] = []
         bi: list[list[MetricTask]] = []
         lstm: list[list[MetricTask]] = []
+        backbone: list[list[MetricTask]] = []
         for job_tasks in by_job.values():
             mode = select_mode(self.config.algorithm, len(job_tasks))
             if mode == "bivariate":
                 bi.append(job_tasks)
             elif mode == "lstm":
                 lstm.append(job_tasks)
+            elif mode == "backbone":
+                backbone.append(job_tasks)
             else:
                 uni.extend(job_tasks)
 
@@ -460,6 +479,8 @@ class MultivariateJudge:
             out.extend(self._judge_bivariate(bi))
         if lstm:
             out.extend(self._judge_lstm(lstm))
+        if backbone:
+            out.extend(self._judge_backbone(backbone))
         return out
 
     # -- shared helpers --------------------------------------------------
@@ -678,6 +699,120 @@ class MultivariateJudge:
                     )
                 )
         return out
+
+    # -- the shared sequence backbone (ISSUE 27) ---------------------------
+
+    @property
+    def backbone(self):
+        """The process's one `BackboneDetector`; its cache is counted with
+        the joint arenas."""
+        if self._backbone is None:
+            from foremast_tpu.engine.backbone import BackboneDetector
+
+            self._backbone = BackboneDetector()
+            self._joint_arenas[("backbone", 0)] = self._backbone.arena
+        return self._backbone
+
+    def backbone_counters(self) -> dict | None:
+        return None if self._backbone is None else self._backbone.counters()
+
+    def _drop_evicted(self) -> None:
+        """A recycled cache row takes its document's warm entry with it:
+        the next tick finds none and prefills again."""
+        evicted = self._backbone.evicted
+        while evicted:
+            doc_key = self._backbone_doc.pop(evicted.pop(), None)
+            if doc_key is not None:
+                self.cache.pop(doc_key)
+
+    def _judge_backbone(
+        self, jobs: list[list[MetricTask]]
+    ) -> list[MetricVerdict]:
+        """Slow path of kind `backbone`: each alias of a job is one
+        sequence; those with no cache row are prefilled ("fit"), then the
+        window program scores every sequence's current window and a
+        timestamp is anomalous where any alias's score exceeds the
+        threshold (nats). Jobs go through in groups of at most the cache's
+        capacity in sequences."""
+        det = self.backbone
+        min_pts = max(self.config.min_historical_points, 2)
+        all_joints = [self._joint(job_tasks) for job_tasks in jobs]
+        all_pw = self._pairwise(all_joints)
+        out: list[MetricVerdict] = []
+        group: list = []
+        seqs = 0
+        for j, p in zip(all_joints, all_pw):
+            if len(j.hist_t) < min_pts or len(j.cur_t) == 0:
+                out.extend(self._unknown(j.tasks, p))
+                continue
+            if group and seqs + len(j.tasks) > det.capacity:
+                out.extend(self._judge_backbone_group(group))
+                group, seqs = [], 0
+            group.append((j, p))
+            seqs += len(j.tasks)
+        if group:
+            out.extend(self._judge_backbone_group(group))
+        return out
+
+    def _judge_backbone_group(self, pairs: list) -> list[MetricVerdict]:
+        det = self._backbone
+        thr = float(self.config.anomaly.rule_for(None).threshold)
+        keys, hists, passing = [], [], []
+        for j, _ in pairs:
+            for f, t in enumerate(j.tasks):
+                key = ("backbone", t.app, t.alias, t.fit_key)
+                if t.fit_key is None:
+                    # unsettled history: a row for this judgment alone
+                    key = ("backbone", "__passing__", t.job_id, t.alias)
+                    passing.append(key)
+                keys.append(key)
+                hists.append(j.hist_v[f])
+        entries = det.ensure(keys, hists)
+        self._drop_evicted()
+        tc = bucket_length(max(len(j.cur_t) for j, _ in pairs))
+        cur = np.zeros((len(keys), tc), np.float32)
+        valid = np.zeros((len(keys), tc), bool)
+        at = 0
+        for j, _ in pairs:
+            f, n = j.cur_v.shape
+            cur[at : at + f, :n] = j.cur_v
+            valid[at : at + f, :n] = True
+            at += f
+        scales = np.array([e[0] for e in entries], np.float32)
+        scores = det.score(keys, scales, cur, valid)
+        det.arena.release(passing)
+        out: list[MetricVerdict] = []
+        at = 0
+        for j, pw in pairs:
+            f, n = j.cur_v.shape
+            flags = (scores[at : at + f, :n] > thr).any(axis=0)
+            doc_keys = self._joint_keys("backbone", j, tc)
+            if doc_keys is not None:
+                seq_keys = tuple(keys[at : at + f])
+                self._record_joint(
+                    "backbone", j, tc, entry=(seq_keys, scales[at : at + f])
+                )
+                for k in seq_keys:
+                    self._backbone_doc[k] = doc_keys[0]
+            out.extend(self._emit(j, flags, thr, pw))
+            at += f
+        return out
+
+    def _backbone_columnar(self, entries: list, cur, mask) -> np.ndarray:
+        """Warm judgment of admitted backbone docs: cur [S, F, tcb], mask
+        [S, tcb] -> flags [S, tcb], a timestamp flagged where any of the
+        doc's F sequences scores over the threshold."""
+        s0, f, tcb = cur.shape
+        thr = float(self.config.anomaly.rule_for(None).threshold)
+        with span("judge.joint_prep", stage="pack", rows=s0):
+            seq_keys = [k for e in entries for k in e[0]]
+            scales = np.concatenate([e[1] for e in entries])
+            valid = np.repeat(mask, f, axis=0)
+        scores = self.backbone.score(
+            seq_keys, scales, cur.reshape(s0 * f, tcb), valid
+        )
+        self.batch_rows_total += s0 * f
+        return (scores > thr).reshape(s0, f, tcb).any(axis=1) & mask
 
     # -- LSTM autoencoder ------------------------------------------------
 
@@ -1050,6 +1185,9 @@ class MultivariateJudge:
             # history via the entry's mvn[7]/mvn[8] check in
             # columnar_joint_peek
             key = ("bivariate", app, aliases, hkeys)
+        elif mode == "backbone":
+            # the history's identity is the prefix cache's key
+            key = ("backbone", app, aliases, hkeys)
         else:
             key = self._key(j, tc)
         return key, ("jmeta", mode, app, aliases, hkeys)
@@ -1117,6 +1255,16 @@ class MultivariateJudge:
             key = ("bivariate", app, aliases, hist_keys)
             entry = self.cache.peek(key)
             if entry is None:
+                return None
+        elif mode == "backbone":
+            if n_hist < max(min_pts, 2) or self._backbone is None:
+                return None
+            key = ("backbone", app, aliases, hist_keys)
+            entry = self.cache.peek(key)
+            # a warm entry is worth what its rows are: a restored or
+            # handed-over entry, or one whose row was recycled, prefills
+            rows = self._backbone.arena.rows
+            if entry is None or any(k not in rows for k in entry[0]):
                 return None
         else:
             # same 2-window floor as _judge_lstm's explicit min-history
@@ -1300,6 +1448,8 @@ class MultivariateJudge:
         Returns flags [S, tcb] bool (host numpy). The batch axis is
         pow2-padded (dup of row 0, mask all-False => flags all-False) so
         claim-size jitter cannot force recompiles."""
+        if mode == "backbone":
+            return self._backbone_columnar(entries, cur, mask)
         s0, f, tcb = cur.shape
         thr = float(self.config.anomaly.rule_for(None).threshold)
         # Stage spans, in order, siblings on the tick thread: joint_prep

@@ -480,6 +480,9 @@ class BrainWorker:
         # fits are cached (ISSUE 4 tentpole — previously every joint doc
         # fell onto the ~10x-slower per-task object path forever)
         self._mv = self.config.algorithm in MULTIVARIATE_ALGOS
+        # kind `backbone` takes every doc, a single alias too: each alias
+        # is one sequence of the shared model
+        self._mv_single = self.config.algorithm == "backbone"
         self._mvj = (
             self.judge if isinstance(self.judge, MultivariateJudge) else None
         )
@@ -523,6 +526,7 @@ class BrainWorker:
         # columnar program)
         self._fast_kinds = {
             "univariate": 0, "bivariate": 0, "lstm": 0, "baseline": 0,
+            "backbone": 0,
         }
         # per-document decoded config/endTime metadata (immutable per doc
         # id — see _doc_meta) and per-fit-key gap anchors (step, last
@@ -1234,7 +1238,11 @@ class BrainWorker:
             ("gaps", self._gap_meta),
             ("refine", self._refine_book),
         ]
-        if self._mvj is not None:
+        if self._mvj is not None and not self._mv_single:
+            # kind `backbone` has nothing to persist: its fitted state is
+            # the prefix cache's rows, tens of MB a sequence on the device,
+            # and a warm entry without its rows is worth nothing. A
+            # restarted worker prefills again.
             pairs += [
                 ("joint", self._mvj.cache),
                 ("jmeta", self._mvj.joint_meta),
@@ -1709,6 +1717,8 @@ class BrainWorker:
         )
         updated: list = []
         counts = {"univariate": 0, "bivariate": 0, "lstm": 0}
+        if self._mv_single:
+            counts["backbone"] = 0
         with span(
             "worker.pack_joint", stage="pack", docs=len(ok_joint)
         ) as sp:
@@ -1892,6 +1902,7 @@ class BrainWorker:
         n_joint = 0
         kind_counts = {
             "univariate": 0, "bivariate": 0, "lstm": 0, "baseline": 0,
+            "backbone": 0,
         }
         if ok_joint:
             j_updated, demoted, j_counts = self._judge_joint_fast(
@@ -1977,7 +1988,7 @@ class BrainWorker:
             if not aliases:
                 slow.append(doc)
                 continue
-            if self._mv and len(aliases) != 1:
+            if self._mv and (len(aliases) != 1 or self._mv_single):
                 item = self._admit_joint(
                     doc, aliases, end_epoch, now, jtoken
                 )
@@ -2832,6 +2843,7 @@ class BrainWorker:
             updated = list(res.joint_updated)
             kind_counts = {
                 "univariate": 0, "bivariate": 0, "lstm": 0, "baseline": 0,
+            "backbone": 0,
             }
             if res.joint_counts:
                 for kind, n in res.joint_counts.items():
@@ -3413,6 +3425,12 @@ class BrainWorker:
             dm = self._device_mesh_state()
             if dm is not None:
                 self.metrics.observe_device_mesh(dm)
+        if self._mv_single and self.metrics is not None and hasattr(
+            self.metrics, "observe_backbone"
+        ):
+            bb = self._mvj.backbone_counters() if self._mvj else None
+            if bb is not None:
+                self.metrics.observe_backbone(bb)
         self._last_tick = {
             "at": time.time(),
             "docs": n_docs,
@@ -3559,6 +3577,9 @@ class BrainWorker:
             # LSTM-AE params + residual-MVN state); None when the judge
             # has no joint dispatch
             "joint_arena": joint_arena,
+            "backbone": (
+                self._mvj.backbone_counters() if self._mvj is not None else None
+            ),
             # device mesh (ISSUE 13/19, FOREMAST_DEVICE_MESH): mesh
             # shape, padded-row fraction, arena layout + HBM accounting
             # (per-device bytes x device count = shard-sum when sharded,

@@ -301,10 +301,53 @@ class WorkerMetrics:
         self.fast_docs = Counter(
             "foremast_worker_fast_docs_total",
             "documents scored on the columnar fast path, by model kind "
-            "(univariate / bivariate / lstm)",
+            "(univariate / bivariate / lstm / backbone)",
             ["kind"],
             registry=reg,
         )
+        # the shared sequence backbone (ISSUE 27, engine/backbone.py):
+        # tokens prefilled and scored, its prefix cache, the load of the
+        # experts this process holds
+        self.backbone_prefill_tokens = Counter(
+            "foremast_backbone_prefill_tokens_total",
+            "history tokens prefilled into the backbone's prefix cache",
+            registry=reg,
+        )
+        self.backbone_window_tokens = Counter(
+            "foremast_backbone_window_tokens_total",
+            "current-window tokens the backbone's window program scored",
+            registry=reg,
+        )
+        self.backbone_cache_rows = Gauge(
+            "foremast_backbone_cache_rows_live",
+            "sequences whose prefix the backbone's cache holds",
+            registry=reg,
+        )
+        self.backbone_cache_hits = Counter(
+            "foremast_backbone_cache_hits_total",
+            "sequences that found their cached prefix (a followed job of "
+            "the same service is a hit)",
+            registry=reg,
+        )
+        self.backbone_cache_misses = Counter(
+            "foremast_backbone_cache_misses_total",
+            "sequences prefilled because the cache held no row for them",
+            registry=reg,
+        )
+        self.backbone_expert_tokens = Counter(
+            "foremast_backbone_expert_tokens_total",
+            "token assignments each held expert received from the window "
+            "program, all layers (max over mean is the straggler)",
+            ["expert"],
+            registry=reg,
+        )
+        self.backbone_dropped_tokens = Counter(
+            "foremast_backbone_dropped_tokens_total",
+            "assignments routed to a held expert and not computed: the "
+            "expert layer has no capacity factor, so this stays 0",
+            registry=reg,
+        )
+        self._backbone_last: dict = {}
         self._arena_last = {
             "hits": 0,
             "misses": 0,
@@ -538,6 +581,31 @@ class WorkerMetrics:
             if delta > 0:
                 self.arena.labels(event=event).inc(delta)
             self._arena_last[event] = cur
+
+
+    def observe_backbone(self, counters: dict) -> None:
+        """Feed cumulative `MultivariateJudge.backbone_counters()`;
+        deltas are exported, as in `observe_arena`."""
+        last = self._backbone_last
+        flat = {
+            "prefill_tokens": self.backbone_prefill_tokens,
+            "window_tokens": self.backbone_window_tokens,
+            "cache_hits": self.backbone_cache_hits,
+            "cache_misses": self.backbone_cache_misses,
+            "dropped_tokens": self.backbone_dropped_tokens,
+        }
+        for key, family in flat.items():
+            delta = counters.get(key, 0) - last.get(key, 0)
+            if delta > 0:
+                family.inc(delta)
+            last[key] = counters.get(key, 0)
+        self.backbone_cache_rows.set(counters.get("cache_rows_live", 0))
+        seen = last.get("expert_tokens") or []
+        for e, total in enumerate(counters.get("expert_tokens", [])):
+            delta = total - (seen[e] if e < len(seen) else 0)
+            if delta > 0:
+                self.backbone_expert_tokens.labels(expert=str(e)).inc(delta)
+        last["expert_tokens"] = list(counters.get("expert_tokens", []))
 
 
 def start_metrics_server(port: int = 8000, registry=None):

@@ -34,6 +34,15 @@ ALLOWED_LABELS: dict[str, frozenset[str]] = {
     "foremast_worker_tick_seconds": frozenset(),
     "foremast_worker_arena_events": frozenset({"event"}),
     "foremast_worker_fast_docs": frozenset({"kind"}),
+    # the shared sequence backbone (ISSUE 27, observe/gauges.py
+    # WorkerMetrics.observe_backbone)
+    "foremast_backbone_prefill_tokens": frozenset(),
+    "foremast_backbone_window_tokens": frozenset(),
+    "foremast_backbone_cache_rows_live": frozenset(),
+    "foremast_backbone_cache_hits": frozenset(),
+    "foremast_backbone_cache_misses": frozenset(),
+    "foremast_backbone_expert_tokens": frozenset({"expert"}),
+    "foremast_backbone_dropped_tokens": frozenset(),
     # chunk-pipeline occupancy (observe/gauges.py WorkerMetrics), by
     # path since ISSUE 15: "slow" = the cold chunk pipeline (PR 3),
     # "warm" = the sliced sweep's claim-pool pipeline
@@ -130,9 +139,35 @@ FAMILY_DOCS: dict[str, str] = {
     ),
     "foremast_worker_fast_docs": (
         "documents scored on the columnar fast path, by model kind "
-        "(univariate/bivariate/lstm, plus `baseline` — the canary "
-        "bucket: baseline-carrying univariate docs judged through the "
-        "pairwise-active columnar program)"
+        "(univariate/bivariate/lstm/backbone, plus `baseline` — the "
+        "canary bucket: baseline-carrying univariate docs judged through "
+        "the pairwise-active columnar program)"
+    ),
+    "foremast_backbone_prefill_tokens": (
+        "history tokens prefilled into the backbone's prefix cache "
+        "(`ML_ALGORITHM=backbone`; docs/backbone.md)"
+    ),
+    "foremast_backbone_window_tokens": (
+        "current-window tokens the backbone's window program scored"
+    ),
+    "foremast_backbone_cache_rows_live": (
+        "sequences whose prefix the backbone's cache holds "
+        "(capacity: `FOREMAST_BACKBONE_ROWS`)"
+    ),
+    "foremast_backbone_cache_hits": (
+        "sequences that found their cached prefix; a followed job of "
+        "the same service is a hit"
+    ),
+    "foremast_backbone_cache_misses": (
+        "sequences prefilled because the cache held no row for them"
+    ),
+    "foremast_backbone_expert_tokens": (
+        "token assignments each held expert received from the window "
+        "program, by expert (max over mean is the straggler)"
+    ),
+    "foremast_backbone_dropped_tokens": (
+        "assignments routed to a held expert and not computed; always 0 "
+        "(no capacity factor)"
     ),
     "foremast_worker_pipeline_idle_seconds": (
         "seconds the judge stage sat stalled waiting on a chunk's "
@@ -370,8 +405,13 @@ def default_registry_families():
     metrics.observe_doc("completed_health", 1)
     metrics.observe_arena({"hits": 1, "misses": 1, "evictions": 0, "fallbacks": 0})
     metrics.tick_seconds.observe(0.01)
-    for kind in ("univariate", "bivariate", "lstm"):
+    for kind in ("univariate", "bivariate", "lstm", "backbone"):
         metrics.fast_docs.labels(kind=kind).inc()
+    metrics.observe_backbone({
+        "prefill_tokens": 1, "window_tokens": 1, "cache_rows_live": 1,
+        "cache_hits": 1, "cache_misses": 1, "dropped_tokens": 0,
+        "expert_tokens": [1, 1],
+    })
     for path in ("micro", "sweep"):
         metrics.verdict_latency.labels(path=path, tenant="default").observe(
             0.1
