@@ -58,6 +58,7 @@ class BackboneDetector:
         self.expert_tokens = np.zeros(held, np.int64)  # window program only
         self.prefill_tokens = 0
         self.window_tokens = 0
+        self.fused_attn_tokens = 0  # of window_tokens: dispatches that took the fused kernel
         self.dropped_tokens = 0
 
     @property
@@ -72,6 +73,7 @@ class BackboneDetector:
         return {
             "prefill_tokens": self.prefill_tokens,
             "window_tokens": self.window_tokens,
+            "fused_attn_tokens": self.fused_attn_tokens,
             "cache_rows_live": c["rows_live"],
             "cache_hits": c["hits"],
             "cache_misses": c["misses"],
@@ -182,7 +184,10 @@ class BackboneDetector:
             )
         with span("judge.decode", stage="decode", rows=sb, device=True):
             scores = np.asarray(scores)[:s]
-        self.window_tokens += int(valid.sum())
+        tokens = int(valid.sum())
+        self.window_tokens += tokens
+        if model.fused_window_attention(cfg, self.ctx_cap, w):
+            self.fused_attn_tokens += tokens
         self.expert_tokens += np.asarray(counts)
         self.dropped_tokens += int(dropped)
         return scores

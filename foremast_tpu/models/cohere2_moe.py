@@ -37,6 +37,11 @@ cached: it is the window program's first input. Warm, `score_window` feeds
 [last id; the window's ids but the last] at positions n .. n + w - 1 and
 reads the rows IN PLACE by row index (no gathered copy of the batch's
 caches; rows are never written): score_t = -log p(id_t | history, id_<t).
+On a TPU, at widths its tiling meets, the window program's attention is
+one fused kernel a layer over the rows where they lie
+(`cohere2_attention.fused_attend_rows`; `fused_window_attention` decides,
+from the backend and the shapes alone); everywhere else, and in the
+prefill always, it is `attend`, a sequence at a time.
 
 Precision: weights, activations and cache in `compute_dtype` (bfloat16);
 accumulation, softmax, LN statistics, router and log-softmax in float32.
@@ -56,6 +61,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from foremast_tpu.models.cohere2_attention import (
+    MASKED,
+    cached_positions,
+    fused_applies,
+    fused_attend_rows,
+    visible,
+)
+
 SLIDING = "sliding_attention"
 FULL = "full_attention"
 DEFAULT_MODEL_FILE = os.path.join(
@@ -65,7 +78,6 @@ DEFAULT_MODEL_FILE = os.path.join(
 )
 PREFILL_CHUNK = 2048  # tokens a sequence a prefill dispatch, at most
 TOKEN_RANGE = 15.0  # a scaled value in [-15, 15) maps onto the vocabulary
-_MASKED = -1e30
 
 
 @dataclasses.dataclass(frozen=True)
@@ -274,6 +286,17 @@ def prefill_chunk_len(cfg: Cohere2MoeConfig, ctx_cap: int) -> int:
     return chunk
 
 
+def fused_window_attention(cfg: Cohere2MoeConfig, ctx_cap: int, tokens: int) -> bool:
+    """Whether `score_window` over windows of `tokens` against rows of
+    `ctx_cap` positions attends through the fused kernel
+    (`cohere2_attention.fused_attend_rows`) or through `attend`: decided by
+    the backend and the shapes alone, once for the whole program. The
+    detector counts `fused_attn_tokens` by the same call."""
+    return fused_applies(
+        cfg.head_dim, cfg.dtype.itemsize, tokens, (ctx_cap, ring_size(cfg, ctx_cap))
+    )
+
+
 def cache_template(cfg: Cohere2MoeConfig, ctx_cap: int) -> dict:
     """One arena row: a sequence's cached prefix, head-major so that one
     head's keys are contiguous."""
@@ -314,13 +337,6 @@ def rope(x, pos, theta: float):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _visible(pos_q, pos_k, valid_k, window: int | None):
-    """[Tq, Tk] bool: key j is seen by query i."""
-    gap = pos_q[:, None] - pos_k[None, :]
-    seen = valid_k[None, :] & (gap >= 0)
-    return seen & (gap < window) if window else seen
-
-
 def attend(q, pos_q, kn, vn, pos_n, valid_n, kc, vc, pos_c, valid_c, group: int,
            window: int | None):
     """One sequence, one layer: queries q [Tq, Hq, D] at pos_q [Tq] over
@@ -335,8 +351,10 @@ def attend(q, pos_q, kn, vn, pos_n, valid_n, kc, vc, pos_c, valid_c, group: int,
     sc = jnp.einsum("hqd,hkd->hqk", qh, kc, preferred_element_type=jnp.float32) * scale
     sn = jnp.einsum("hqd,khd->hqk", qh, kn, preferred_element_type=jnp.float32) * scale
     # rows of qh run (g, t): the masks repeat over g
-    sc = jnp.where(jnp.tile(_visible(pos_q, pos_c, valid_c, window), (group, 1)), sc, _MASKED)
-    sn = jnp.where(jnp.tile(_visible(pos_q, pos_n, valid_n, window), (group, 1)), sn, _MASKED)
+    seen_c = visible(pos_q[:, None], pos_c[None, :], valid_c[None, :], window)
+    seen_n = visible(pos_q[:, None], pos_n[None, :], valid_n[None, :], window)
+    sc = jnp.where(jnp.tile(seen_c, (group, 1)), sc, MASKED)
+    sn = jnp.where(jnp.tile(seen_n, (group, 1)), sn, MASKED)
     m = jnp.maximum(sc.max(axis=-1), sn.max(axis=-1))[..., None]
     ec, en = jnp.exp(sc - m), jnp.exp(sn - m)
     den = ec.sum(axis=-1) + en.sum(axis=-1)
@@ -445,14 +463,6 @@ def _project(cfg: Cohere2MoeConfig, lp: dict, xn, pos, sliding: bool):
     return q, k, v
 
 
-def _ring_positions(n, ring: int):
-    """Position held by each ring slot once n positions are cached (slot =
-    position mod ring): the largest p < n congruent to the slot; `valid`
-    where there is one."""
-    slot = jnp.arange(ring, dtype=jnp.int32)
-    return slot + ring * ((n - 1 - slot) // ring), slot < n
-
-
 def _layer_slots(cfg: Cohere2MoeConfig):
     """(layer type, its index among the layers of its own type) a layer."""
     seen = {SLIDING: 0, FULL: 0}
@@ -464,29 +474,35 @@ def _layer_slots(cfg: Cohere2MoeConfig):
 
 
 def _attend_rows(cfg: Cohere2MoeConfig, state, names, slot: int, rows, q, k, v, pos, valid,
-                 cached_n, sliding: bool, q_block: int | None, write_at):
+                 cached_n, sliding: bool, q_block: int | None, write_at, fused: bool):
     """One layer's attention for every sequence of the dispatch, each
     against its own arena row read where it lies. q [S, T, Hq, D], k, v
     [S, T, Hkv, D]. With `write_at` the sequence's new keys and values are
     then written into its row, head-major, at slot `write_at mod capacity`
     (a full layer's capacity is past every position, so that is the
     position itself); positions that are not `valid` keep what the row
-    held: in a ring they are still needed. -> (att [S, T, Hq * D], state)."""
+    held: in a ring they are still needed. `fused` (the window program on
+    a TPU: `fused_window_attention`): one kernel over the rows in place
+    instead of `attend` a sequence. -> (att [S, T, Hq * D], state)."""
     s, t = q.shape[:2]
     hkv, d = cfg.num_key_value_heads, cfg.head_dim
     cap = state[names[0]].shape[-2]
     window = cfg.sliding_window if sliding else None
+    # foremast: ignore[jit-hygiene] — decided from the backend and shapes while tracing
+    if fused:
+        att = fused_attend_rows(
+            state[names[0]], state[names[1]], rows, cached_n, q, k, v, pos, valid,
+            layer=slot, group=cfg.group, window=window,
+        )
+        return att, state
 
     def row_of(leaf, row):
         return lax.dynamic_slice(leaf, (row, slot, 0, 0, 0), (1, 1, hkv, cap, d))[0, 0]
 
     def one(kc, vc, qs, kn, vn, ps, ok, n):
-        # foremast: ignore[jit-hygiene] — the layer's type, static
-        if sliding:
-            pos_c, valid_c = _ring_positions(n, cap)
-        else:
-            pos_c = jnp.arange(cap, dtype=jnp.int32)
-            valid_c = pos_c < n
+        pos_c, valid_c = cached_positions(
+            jnp.arange(cap, dtype=jnp.int32), n, cap if sliding else None
+        )
 
         def block(a):
             return attend(a[0], a[1], kn, vn, ps, ok, kc, vc, pos_c, valid_c, cfg.group, window)
@@ -543,6 +559,8 @@ def _forward(cfg: Cohere2MoeConfig, params, state, rows, ids, pos, valid, cached
     counts = jnp.zeros(cfg.share.experts_held, jnp.int32)
     dropped = jnp.int32(0)
     slots = _layer_slots(cfg)
+    # the prefill writes rows through a loop's carry and keeps `attend`
+    fused = write_at is None and fused_window_attention(cfg, state["kf"].shape[-2], t)
     for li, (lp, (kind, slot)) in enumerate(zip(params["layers"], slots)):
         sliding = kind == SLIDING
         names = ("ks", "vs") if sliding else ("kf", "vf")
@@ -550,7 +568,7 @@ def _forward(cfg: Cohere2MoeConfig, params, state, rows, ids, pos, valid, cached
         q, k, v = _project(cfg, lp, xn, pos, sliding)
         with jax.named_scope("attn_sliding" if sliding else "attn_full"):
             att, state = _attend_rows(cfg, state, names, slot, rows, q, k, v, pos, valid,
-                                      cached_n, sliding, q_block, write_at)
+                                      cached_n, sliding, q_block, write_at, fused)
             a = jnp.dot(att.reshape(s * t, -1), lp["wo"], preferred_element_type=jnp.float32)
         # foremast: ignore[jit-hygiene] — `li` counts the Python loop
         if write_at is not None and li == len(slots) - 1:

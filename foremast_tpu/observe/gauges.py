@@ -318,6 +318,12 @@ class WorkerMetrics:
             "current-window tokens the backbone's window program scored",
             registry=reg,
         )
+        self.backbone_fused_attn_tokens = Counter(
+            "foremast_backbone_fused_attn_tokens_total",
+            "of the window tokens, those of dispatches whose attention took "
+            "the fused TPU kernel",
+            registry=reg,
+        )
         self.backbone_cache_rows = Gauge(
             "foremast_backbone_cache_rows_live",
             "sequences whose prefix the backbone's cache holds",
@@ -590,6 +596,7 @@ class WorkerMetrics:
         flat = {
             "prefill_tokens": self.backbone_prefill_tokens,
             "window_tokens": self.backbone_window_tokens,
+            "fused_attn_tokens": self.backbone_fused_attn_tokens,
             "cache_hits": self.backbone_cache_hits,
             "cache_misses": self.backbone_cache_misses,
             "dropped_tokens": self.backbone_dropped_tokens,

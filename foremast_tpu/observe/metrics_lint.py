@@ -38,6 +38,7 @@ ALLOWED_LABELS: dict[str, frozenset[str]] = {
     # WorkerMetrics.observe_backbone)
     "foremast_backbone_prefill_tokens": frozenset(),
     "foremast_backbone_window_tokens": frozenset(),
+    "foremast_backbone_fused_attn_tokens": frozenset(),
     "foremast_backbone_cache_rows_live": frozenset(),
     "foremast_backbone_cache_hits": frozenset(),
     "foremast_backbone_cache_misses": frozenset(),
@@ -149,6 +150,10 @@ FAMILY_DOCS: dict[str, str] = {
     ),
     "foremast_backbone_window_tokens": (
         "current-window tokens the backbone's window program scored"
+    ),
+    "foremast_backbone_fused_attn_tokens": (
+        "of those, tokens of dispatches whose attention took the fused "
+        "TPU kernel; 0 off a TPU or at widths the kernel does not tile"
     ),
     "foremast_backbone_cache_rows_live": (
         "sequences whose prefix the backbone's cache holds "
@@ -408,7 +413,8 @@ def default_registry_families():
     for kind in ("univariate", "bivariate", "lstm", "backbone"):
         metrics.fast_docs.labels(kind=kind).inc()
     metrics.observe_backbone({
-        "prefill_tokens": 1, "window_tokens": 1, "cache_rows_live": 1,
+        "prefill_tokens": 1, "window_tokens": 1, "fused_attn_tokens": 1,
+        "cache_rows_live": 1,
         "cache_hits": 1, "cache_misses": 1, "dropped_tokens": 0,
         "expert_tokens": [1, 1],
     })

@@ -150,6 +150,7 @@ def test_cold_tick_two_warm_ticks_and_a_followed_job_match_the_reference(model_f
             assert worker._fast_kinds["backbone"] == k * len(SERVICES)
         assert after["window_tokens"] - before["window_tokens"] == 7 * WINDOW
         assert after["dropped_tokens"] == 0 and after["cache_rows_live"] == 7
+        assert after["fused_attn_tokens"] == 0  # off a TPU every dispatch attends through `attend`
         for s, aliases in SERVICES.items():
             doc = fleet.store._docs[f"job-{s}-{fleet.gen[s]}"]
             over = np.stack([want[(k, s, a)] > thr for a in aliases]).any(axis=0)
