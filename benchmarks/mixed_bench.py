@@ -8,7 +8,7 @@ Four phases, one JSON line each:
   2. **canary** — the ISSUE 14 headline: a canary-HEAVY fleet (>= 50%
      baseline-carrying docs) judged twice on identical fleets — the
      columnar canary bucket (default) vs the object path
-     (FOREMAST_CANARY_COLUMNAR=0 semantics) — with IN-RUN asserts:
+     (`worker._canary_fast = False`) — with IN-RUN asserts:
      statuses byte-identical after every tick, warm throughput >= 3x
      the object arm, and >= 12.5k windows/s/chip (full shapes only;
      CPU-host proxy for the per-chip bar, like rounds 7-15).
@@ -71,7 +71,7 @@ def run_canary(
 
       * columnar   — the default: canary docs on the pairwise-active
         columnar bucket, baseline-less docs on the PAIRWISE_NONE one;
-      * canary_off — FOREMAST_CANARY_COLUMNAR=0 semantics (the pre-
+      * canary_off — `worker._canary_fast = False` (the pre-
         round-16 default: canary docs object, the rest columnar);
       * object     — the whole fleet on the per-task object path (the
         ~10k w/s path VERDICT r5 #9 pinned — the acceptance bar's
@@ -95,10 +95,8 @@ def run_canary(
             worker_id="canary-bench",
         )
         if arm == "canary_off":
-            # FOREMAST_CANARY_COLUMNAR=0 semantics (the knob itself is
-            # read at construction and pinned by tests/test_fast_tick;
-            # the bench flips the worker's resolved flag so one process
-            # measures all arms)
+            # the object path for canary docs: the bench clears the
+            # worker's flag so one process measures all arms
             worker._canary_fast = False
         elif arm == "object":
             worker._fast_tick = lambda docs, now: (0, docs)

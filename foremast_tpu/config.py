@@ -141,7 +141,9 @@ class PairwiseConfig:
 # Every value `ML_ALGORITHM` may take. The univariate names are the
 # engine's model registry (`engine.scoring.AI_MODEL` with what `models/`
 # registers on import; tests/test_config.py holds the two together), the
-# joint ones `engine.multivariate.MULTIVARIATE_ALGOS`. Anything else is
+# joint ones the selectors of the joint kinds (`engine.kinds.JOINT_KINDS`;
+# tests/test_joint_kinds.py holds the two together: config is the lowest
+# layer and imports nothing of the engine). Anything else is
 # refused when the configuration is loaded: `select_mode` used to hand
 # an unknown name to the univariate judge, so a mistyped algorithm judged
 # with another detector, silently.
@@ -481,16 +483,6 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
         "scores as one batched program",
     ),
     EnvKnob(
-        "FOREMAST_JOINT_COLUMNAR",
-        "1",
-        "bool",
-        "default `1`: warm joint (multi-alias bivariate / LSTM-hybrid) "
-        "docs ride the columnar fast tick from arena-resident model "
-        "state, the same path univariate re-checks use. `0` routes every "
-        "joint doc through the per-task object path (the pre-round-7 "
-        "behavior — ~10x slower per joint doc at fleet scale)",
-    ),
-    EnvKnob(
         "FOREMAST_BACKBONE_MODEL",
         None,
         "path",
@@ -516,19 +508,6 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
         "alias of a document), allocated once and never grown: a row is "
         "tens of MB (91.6 MB at the default model and context), so size it "
         "to the fleet and to the HBM the weights leave",
-    ),
-    EnvKnob(
-        "FOREMAST_CANARY_COLUMNAR",
-        "1",
-        "bool",
-        "default `1`: warm BASELINE-carrying univariate docs (the "
-        "canary/continuous strategies) ride the columnar fast tick as "
-        "their own bucket — baseline windows fill a second [B, Tc] "
-        "buffer judged by a pairwise-active compiled variant "
-        "(Mann-Whitney/Wilcoxon/Kruskal/Friedman batched over the "
-        "buffer). `0` routes every baseline-carrying doc through the "
-        "per-task object path (the pre-round-16 behavior — ~10k w/s "
-        "regardless of device)",
     ),
     EnvKnob(
         "FOREMAST_COLD_CHUNK_DOCS",

@@ -1,0 +1,295 @@
+"""What a joint detector kind is.
+
+A joint kind judges all of a job's metrics as one observation. Everything
+that differs between two kinds is a member of `JointKind`; the judge
+(`engine/multivariate.py`), the pack (`jobs/joint_pack.py`) and the worker
+(`jobs/worker.py`) ask the kind and never compare its name. The table that
+holds the kinds, and `select_mode` beside it, are in the package's
+`__init__`.
+
+`ArenaKind` is the warm path shared by the kinds whose fitted state is one
+`TreeArena` row a document (`bivariate`, `lstm`): assign rows, scatter the
+cold ones, pad the batch to its bucket, hand the operands over, dispatch
+one from-rows program. Such a kind supplies four small hooks and its two
+programs. A kind that keeps its state elsewhere (`backbone`: a prefix
+cache of its own) writes `judge_warm` itself.
+
+Nothing here imports `engine.multivariate`: a kind receives the judge and
+uses its shared helpers (`_joint`, `_pairwise`, `_unknown`, `_emit`,
+`_effective_thresholds`, `_record_joint`, the arena lifecycle).
+"""
+
+from __future__ import annotations
+
+import logging
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from foremast_tpu.engine.judge import bucket_length
+from foremast_tpu.observe.spans import note, span
+
+# the arena fallback's warning keeps the logger operators already filter on
+log = logging.getLogger("foremast_tpu.engine.multivariate")
+
+
+def pack_bf16_delta_rows(values: np.ndarray, mask: np.ndarray):
+    """Anchor-shifted bf16-delta pack of left-packed joint histories.
+
+    values [..., T] f32 with a valid-prefix mask [..., T] (broadcastable)
+    -> (anchor [...] f32, delta [..., T] bf16). Anchor is the first slot
+    (left-packed rows put the first valid value there; all-masked rows
+    anchor 0), the same shift `judge._pack_hist_bf16_host` uses, so cold
+    joint fits ship 2 B/point instead of 5."""
+    import ml_dtypes
+
+    anchor = (values[..., 0] * mask[..., 0]).astype(np.float32)
+    delta = (values - anchor[..., None]) * mask
+    return anchor, delta.astype(ml_dtypes.bfloat16)
+
+
+class JointKind:
+    """One joint detector kind. Subclass it in a module of its own and add
+    the instance to `JOINT_KINDS` (docs/backbone.md, "Adding a joint
+    kind")."""
+
+    # `jinfo[0]` of an admitted doc, the `fast_docs` label, the arena's
+    # key, the second field of the `("jmeta", name, …)` key
+    name: str
+    # `ML_ALGORITHM` value -> (fewest, most) metrics of a job that this
+    # kind takes under it; most None = no upper limit. A kind that takes
+    # 1-metric jobs takes single-alias documents off the univariate path.
+    selectors: dict[str, tuple[int, int | None]]
+    # the fits are journalled by `BrainWorker.enable_fit_persistence`
+    persisted = True
+    # the warm program wants each doc's whole steps between the fitted
+    # history's last stamp and the window's first (`JointGroup.fill`)
+    needs_gaps = False
+    # a doc whose window bucket drifted from the one it was fitted at
+    # (`meta[0]`) is demoted to the slow path, not scored (`pack_slice`)
+    pins_bucket = False
+    # the kind keeps cumulative counters of its own: `counters` feeds
+    # /debug/state under the kind's name and `observe` its gauge families
+    keeps_counters = False
+
+    def takes(self, algorithm: str, n_metrics: int) -> bool:
+        bounds = self.selectors.get(algorithm)
+        return (
+            bounds is not None
+            and bounds[0] <= n_metrics
+            and (bounds[1] is None or n_metrics <= bounds[1])
+        )
+
+    def cache_key(self, config, app, aliases, hist_keys, tc) -> tuple:
+        """The fit cache's key of one job's fitted state: the persisted
+        format, built here and nowhere else."""
+        raise NotImplementedError
+
+    def admissible(self, judge, entry, meta) -> bool:
+        """Whether a doc whose cached (entry, meta) these are may be
+        scored warm: the same measurability gates the cold path applies.
+        `entry` is None when nothing is cached. Lock-free: admission runs
+        per doc."""
+        raise NotImplementedError
+
+    def judge_cold(self, judge, jobs: list) -> list:
+        """The slow path: fit what is not cached, score, record the warm
+        state (`judge._record_joint`). jobs: one list of MetricTasks a
+        job -> MetricVerdicts."""
+        raise NotImplementedError
+
+    def judge_warm(self, judge, keys, entries, metas, cur, mask, gaps):
+        """Admitted docs, arrays in: cur [S, F, tcb], mask [S, tcb] ->
+        anomaly flags [S, tcb] bool (host numpy)."""
+        raise NotImplementedError
+
+    def counters(self, judge) -> dict | None:
+        return None
+
+    def observe(self, metrics, counters: dict) -> None:
+        """Feed `counters` to the kind's gauge families of a
+        `WorkerMetrics`."""
+
+
+class ArenaKind(JointKind):
+    """A kind whose fitted state is one `TreeArena` row a document."""
+
+    def template(self, f: int, m: int):
+        """The row's pytree of `jax.ShapeDtypeStruct`s at F metrics and a
+        season buffer m wide."""
+        raise NotImplementedError
+
+    def row_tree(self, entry, m: int):
+        """One arena row (host numpy pytree) from a cache entry."""
+        raise NotImplementedError
+
+    def season_need(self, entries: list) -> int:
+        """The season width the arena has to hold for these entries."""
+        return 1
+
+    def operands(self, entries, thr, f, sb, s0, cur, mask, gaps):
+        """(host, operands) of one dispatch of sb rows, s0 of them real:
+        `host` the batch buffers that go through the judge's placement
+        hook, `operands` the per-row arrays that do not."""
+        raise NotImplementedError
+
+    def program(self, state, rows, *args):
+        """The compiled from-rows program: (arena state, rows [sb],
+        *host, *operands) -> flags [sb, tcb]."""
+        raise NotImplementedError
+
+    def program_sharded(self, state, rows, *args, mesh):
+        """`program` against a row space block-partitioned over `mesh`'s
+        data axis, `rows` local to each block."""
+        raise NotImplementedError
+
+    # The warm joint gather stage: arrays in, jitted from-rows programs
+    # dispatched, flags gathered to host numpy out (the joint counterpart
+    # of the worker's _decode_uni).
+    # foremast: device-boundary
+    def judge_warm(self, judge, keys, entries, metas, cur, mask, gaps):
+        """The batch axis is pow2-padded (dup of row 0, mask all-False =>
+        flags all-False) so claim-size jitter cannot force recompiles."""
+        s0, f, tcb = cur.shape
+        thr = float(judge.config.anomaly.rule_for(None).threshold)
+        # Stage spans, in order, siblings on the tick thread: joint_prep
+        # (pack) -> arena_assemble -> joint_prep (pack) -> h2d -> score
+        # -> decode. Every host array is built BEFORE the h2d span and
+        # the score span holds the jitted call alone.
+        with span("judge.joint_prep", stage="pack", rows=s0):
+            m_need = self.season_need(entries)
+            arena = judge._joint_arena_for(self, f, m_need)
+            # batch target shape FIRST (pow2 bucket + data-axis rounding,
+            # same rule as judge_columnar) — a sharded arena's assign
+            # must see the PADDED position list, because row placement is
+            # a function of position // (B / shards)
+            sb = bucket_length(s0)
+            mult = judge._joint_multiple()
+            if mult > 1 and sb % mult:
+                sb += mult - sb % mult
+            if arena is not None:
+                re_ = arena.row_entry
+                force = [
+                    i
+                    for i, (k, e) in enumerate(zip(keys, entries))
+                    if re_.get(k) is not None and re_.get(k) is not e
+                ]
+                keys_a, entries_a = keys, entries
+                if arena.shards > 1 and sb != s0:
+                    # shard-qualified pad keys (ISSUE 19): one stable pad
+                    # row per data-axis block (same contract as the
+                    # univariate "__pad__col__@N" family — a single
+                    # shared key would migrate between blocks as s0
+                    # jitters); mask all-False keeps the pad rows' flags
+                    # inert
+                    per = sb // arena.shards
+                    keys_a = list(keys) + [
+                        f"__pad__joint__@{(s0 + j) // per}"
+                        for j in range(sb - s0)
+                    ]
+                    entries_a = list(entries) + [entries[0]] * (sb - s0)
+        rows = None
+        state = None
+        if arena is not None:
+            with span(
+                "judge.arena_assemble",
+                stage="arena_assemble",
+                rows=s0,
+                device=True,
+            ) as sp:
+                assigned = arena.assign(keys_a, force, s0)
+                if assigned is not None:
+                    rows_idx, scat = assigned
+                    if scat:
+                        trees = [None] * len(entries_a)
+                        for i in scat:
+                            trees[i] = self.row_tree(
+                                entries_a[i], arena.season_m
+                            )
+                            re_[keys_a[i]] = entries_a[i]
+                        arena.scatter(rows_idx, scat, trees)
+                    state = arena.state
+                    rows = rows_idx
+                    note(sp, scattered=len(scat))
+        with span("judge.joint_prep", stage="pack", rows=sb):
+            stacked = None
+            if rows is None:
+                # arena disabled or batch over the hard byte cap: one-off
+                # host stack + upload — counted, never silent (same
+                # contract as the univariate fallback)
+                if arena is not None:
+                    judge._joint_counters_base["fallbacks"] += 1
+                    log.warning(
+                        "joint arena fallback: %d %s rows exceed the hard "
+                        "cap — full state restack this tick; raise "
+                        "FOREMAST_ARENA_MAX_BYTES",
+                        s0,
+                        self.name,
+                    )
+                stacked = jax.tree.map(
+                    lambda *ls: np.stack(ls),
+                    *[self.row_tree(e, m_need) for e in entries],
+                )
+                rows = np.arange(s0, dtype=np.int64)
+            # data-axis rounding (ISSUE 13): same rule as judge_columnar
+            # — a sharded univariate judge means the joint programs
+            # partition over the same mesh, so S must divide by its data
+            # axis. A sharded arena assigned real pad rows above (rows is
+            # already sb-long); the replicated/stacked layouts pad by
+            # duplicating row 0 with an all-False mask: flags all-False,
+            # dropped on the [:s0] decode.
+            judge.batch_rows_total += sb
+            judge.pad_rows_total += sb - s0
+            if sb != s0:
+                pad = sb - s0
+                cur = np.concatenate(
+                    [cur, np.zeros((pad, f, tcb), np.float32)]
+                )
+                mask = np.concatenate([mask, np.zeros((pad, tcb), bool)])
+                if len(rows) != sb:
+                    rows = np.concatenate(
+                        [rows, np.full(pad, rows[0], rows.dtype)]
+                    )
+                if gaps is not None:
+                    gaps = np.concatenate([gaps, np.zeros(pad, np.int32)])
+            # sharded-arena dispatch (ISSUE 19): when the joint arena row
+            # space is block-partitioned over the data axis, ship LOCAL
+            # (per-shard) indices through the same placement hook as the
+            # batch buffers and run the shard_map from-rows programs —
+            # device-local gather, zero cross-chip transfer. The stacked
+            # fallback keeps global rows + the replicated programs.
+            sharded = (
+                arena is not None and arena.shards > 1 and stacked is None
+            )
+            if sharded:
+                rows = (rows % arena.cap_s).astype(np.int32)
+            host, operands = self.operands(
+                entries, thr, f, sb, s0, cur, mask, gaps
+            )
+        with span("judge.h2d", stage="h2d", rows=sb, device=True) as sp:
+            # everything the dispatch hands to the device: batch buffers
+            # and (sharded) local rows through the placement hook, the
+            # per-row operands, and the stacked fallback's whole state
+            handed = [rows, *host, *operands]
+            if stacked is not None:
+                handed += jax.tree.leaves(stacked)
+                state = jax.tree.map(jnp.asarray, stacked)
+            note(sp, bytes=sum(int(a.nbytes) for a in handed))
+            if sharded:
+                (rows,) = judge._place_joint(rows)
+            rows_j = jnp.asarray(rows)
+            placed = [jnp.asarray(a) for a in judge._place_joint(*host)]
+            operands = [jnp.asarray(a) for a in operands]
+        with span(
+            "judge.score", stage="score", rows=sb, device=True
+        ):
+            if sharded:
+                flags = self.program_sharded(
+                    state, rows_j, *placed, *operands,
+                    mesh=judge.univariate.mesh,
+                )
+            else:
+                flags = self.program(state, rows_j, *placed, *operands)
+        with span("judge.decode", stage="decode", rows=sb, device=True):
+            return np.asarray(flags)[:s0]

@@ -301,7 +301,7 @@ def _advance_gap(fc: Forecast, gap_steps: jax.Array | None) -> Forecast:
     the TRUE gap mod m (clamping would corrupt the phase — 10*m ≡ 0);
     only the trend extrapolation is bounded against runaway level drift
     (GAP_TREND_CAP_STEPS), mirroring the residual-MVN path
-    (multivariate._judge_lstm_group). Trendless, seasonless models (the
+    (engine/kinds/lstm.py, `LstmKind._judge_group`). Trendless, seasonless models (the
     deployed moving_average_all default) are bit-for-bit unaffected."""
     if gap_steps is None:
         return fc

@@ -32,6 +32,7 @@ from __future__ import annotations
 import numpy as np
 
 from foremast_tpu.engine.judge import bucket_length
+from foremast_tpu.engine.kinds import JOINT_KINDS
 from foremast_tpu.engine.multivariate import align_series
 
 
@@ -61,10 +62,10 @@ class JointGroup:
         self.classes = classes
 
     def fill(self):
-        """(cur, mask, gaps, keys, entries, metas). lstm pads to the
-        group's widest fitted window bucket, which IS the widest window's
-        bucket: a doc whose bucket drifted from its fitted one was demoted
-        in `pack_slice`."""
+        """(cur, mask, gaps, keys, entries, metas). A kind that pins its
+        docs' bucket pads to the group's widest fitted window bucket, which
+        IS the widest window's bucket: a doc whose bucket drifted from its
+        fitted one was demoted in `pack_slice`."""
         sub = self.sub
         s = len(sub)
         tcb = bucket_length(max(c[0] for c in self.classes))
@@ -79,7 +80,7 @@ class JointGroup:
         entries = [it[2][4] for it in sub]
         metas = [it[2][6] for it in sub]
         gaps = None
-        if self.mode == "lstm":
+        if JOINT_KINDS[self.mode].needs_gaps:
             # whole steps between the fitted history's last stamp and the
             # window's first
             gaps = np.array(
@@ -97,8 +98,9 @@ def pack_slice(ok_joint):
 
     Returns (groups, empty, demoted, bulk, aligned): the dispatch groups
     in the order of their first packed doc; the docs with no joint
-    observation, as (doc, end_epoch, jinfo, vals), and the lstm docs whose
-    window bucket drifted from the fitted one, both in `ok_joint` order;
+    observation, as (doc, end_epoch, jinfo, vals), and the docs whose
+    window bucket drifted from the fitted one (of the kinds that pin it),
+    both in `ok_joint` order;
     and how many rows were packed from the class stacks and how many
     after `align_series`."""
     by_kind: dict = {}
@@ -111,7 +113,7 @@ def pack_slice(ok_joint):
     demoted: list[int] = []
     bulk = aligned = 0
     for (mode, f), idx in by_kind.items():
-        lstm = mode == "lstm"
+        pins = JOINT_KINDS[mode].pins_bucket
         s = len(idx)
         docs = [ok_joint[i] for i in idx]
         ts = [pair[0] for _, series in docs for pair in series]
@@ -145,8 +147,8 @@ def pack_slice(ok_joint):
                 single += [j for j, o in zip(sel, fine) if not o]
                 sel = [j for j, o in zip(sel, fine) if o]
                 t = t[ok]
-            if lstm:
-                # window bucket drifted from the one the AE was fitted
+            if pins:
+                # window bucket drifted from the one the model was fitted
                 # at: the model no longer applies — refit on the slow
                 # path instead of scoring through the wrong program
                 tcb = bucket_length(n)
@@ -171,7 +173,7 @@ def pack_slice(ok_joint):
             if n == 0:
                 # no joint observation: decided UNKNOWN by the caller
                 empty.append(idx[j])
-            elif lstm and bucket_length(n) != item[2][6][0]:
+            elif pins and bucket_length(n) != item[2][6][0]:
                 demoted.append(idx[j])
             else:
                 slots[j] = item + (ct, cv, n)

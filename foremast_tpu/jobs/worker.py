@@ -43,6 +43,12 @@ from foremast_tpu.engine import (
     MetricVerdict,
     combine_verdicts,
 )
+from foremast_tpu.engine.kinds import (
+    JOINT_KINDS,
+    UNIVARIATE,
+    kinds_under,
+    select_mode,
+)
 from foremast_tpu.jobs.models import (
     STATUS_COMPLETED_HEALTH,
     STATUS_COMPLETED_UNHEALTH,
@@ -369,6 +375,15 @@ def _hist_end_epoch(url: str) -> float | None:
         return ts if ts > 0 else None
 
 
+def fast_kinds() -> tuple[str, ...]:
+    """The `kind` labels of `foremast_worker_fast_docs_total` and the keys
+    of `BrainWorker._fast_kinds`: the univariate judge's two buckets
+    ("baseline" is the canary bucket — single-alias docs judged WITH their
+    baseline windows through the pairwise-active columnar program) and
+    every joint kind in the table."""
+    return (UNIVARIATE, "baseline", *JOINT_KINDS)
+
+
 def infer_metric_type(alias: str, config: BrainConfig) -> str | None:
     """Map a metric alias onto a per-type threshold row by substring match
     (the reference keys its override matrix by metric *type* names like
@@ -469,20 +484,25 @@ class BrainWorker:
         self._eff_cfg = eff_cfg
         self._eff_algo = eff_cfg.algorithm
         self._eff_season = eff_cfg.season_steps
-        from foremast_tpu.engine.multivariate import (
-            MULTIVARIATE_ALGOS,
-            MultivariateJudge,
-        )
+        from foremast_tpu.engine.multivariate import MultivariateJudge
 
         # multivariate selectors route multi-alias jobs to joint models;
         # single-alias docs take the univariate columnar path, and
         # multi-alias docs take the JOINT columnar path below once their
         # fits are cached (ISSUE 4 tentpole — previously every joint doc
-        # fell onto the ~10x-slower per-task object path forever)
-        self._mv = self.config.algorithm in MULTIVARIATE_ALGOS
-        # kind `backbone` takes every doc, a single alias too: each alias
-        # is one sequence of the shared model
-        self._mv_single = self.config.algorithm == "backbone"
+        # fell onto the ~10x-slower per-task object path forever).
+        # Everything the tick asks of a joint kind is resolved here, once:
+        # admission runs per doc per tick.
+        kinds = kinds_under(self.config.algorithm)
+        self._mv = bool(kinds)
+        # a kind that takes 1-metric jobs takes single-alias docs too
+        self._mv_single = (
+            select_mode(self.config.algorithm, 1) != UNIVARIATE
+        )
+        self._mv_persisted = any(kind.persisted for kind in kinds)
+        self._counting_kinds = tuple(
+            kind for kind in JOINT_KINDS.values() if kind.keeps_counters
+        )
         self._mvj = (
             self.judge if isinstance(self.judge, MultivariateJudge) else None
         )
@@ -503,31 +523,19 @@ class BrainWorker:
         # pair, revalidated per entry by IDENTITY on a version bump —
         # same discipline as _admit/_revalidate above
         self._jadmit: dict = {}
-        import os as _os0
-
-        self._joint_fast = (
-            self._mv
-            and self._mvj is not None
-            and _os0.environ.get("FOREMAST_JOINT_COLUMNAR", "1") == "1"
-        )
+        # warm joint docs ride the columnar tick whenever the judge has a
+        # joint dispatch; the object path stays as what a demoted doc takes
+        self._joint_fast = self._mv and self._mvj is not None
         # canary columnar path (ISSUE 14): baseline-carrying univariate
         # docs ride the fast tick as their own bucket — a second
         # [B, tc] baseline buffer through a pairwise-active compiled
-        # variant. FOREMAST_CANARY_COLUMNAR=0 opts out (they demote to
-        # the object path, the pre-ISSUE-14 behavior).
-        self._canary_fast = (
-            _os0.environ.get("FOREMAST_CANARY_COLUMNAR", "1") == "1"
-        )
+        # variant. (Parity tests clear this, and `_joint_fast`, to obtain
+        # the object path as their reference.)
+        self._canary_fast = True
         # cumulative columnar-path doc counts per model kind — the
         # per-kind bucket counters /debug/state and WorkerMetrics expose
-        # (proof that joint docs actually ride the fast path;
-        # "baseline" is the canary bucket — single-alias docs judged
-        # WITH their baseline windows through the pairwise-active
-        # columnar program)
-        self._fast_kinds = {
-            "univariate": 0, "bivariate": 0, "lstm": 0, "baseline": 0,
-            "backbone": 0,
-        }
+        # (proof that joint docs actually ride the fast path)
+        self._fast_kinds = dict.fromkeys(fast_kinds(), 0)
         # per-document decoded config/endTime metadata (immutable per doc
         # id — see _doc_meta) and per-fit-key gap anchors (step, last
         # hist timestamp) for the history-free warm path
@@ -1238,11 +1246,9 @@ class BrainWorker:
             ("gaps", self._gap_meta),
             ("refine", self._refine_book),
         ]
-        if self._mvj is not None and not self._mv_single:
-            # kind `backbone` has nothing to persist: its fitted state is
-            # the prefix cache's rows, tens of MB a sequence on the device,
-            # and a warm entry without its rows is worth nothing. A
-            # restarted worker prefills again.
+        if self._mvj is not None and self._mv_persisted:
+            # (a kind whose fitted state lives on the device alone says
+            # `persisted = False`: a restarted worker fits again)
             pairs += [
                 ("joint", self._mvj.cache),
                 ("jmeta", self._mvj.joint_meta),
@@ -1610,13 +1616,12 @@ class BrainWorker:
             cached[2] == jtoken or self._revalidate_joint(cached, jtoken)
         ):
             return (doc, cached[0], cached[1])
-        from foremast_tpu.engine.multivariate import select_mode
-
         mode = select_mode(self.config.algorithm, len(aliases))
-        if mode == "univariate":
-            # metric-count misfit (e.g. 3 aliases under bivariate_normal):
-            # the object path scores these per alias with the univariate
-            # fallback — multi-task docs stay off the columnar paths
+        if mode == UNIVARIATE:
+            # no joint kind takes this job: a metric-count misfit (e.g.
+            # 3 aliases under bivariate_normal). The object path scores
+            # these per alias with the univariate fallback — multi-task
+            # docs stay off the columnar paths
             return None
         names = []
         urls = []
@@ -1716,9 +1721,7 @@ class BrainWorker:
             np.float32(judge.config.anomaly.rule_for(None).threshold)
         )
         updated: list = []
-        counts = {"univariate": 0, "bivariate": 0, "lstm": 0}
-        if self._mv_single:
-            counts["backbone"] = 0
+        counts = dict.fromkeys(self._fast_kinds, 0)
         with span(
             "worker.pack_joint", stage="pack", docs=len(ok_joint)
         ) as sp:
@@ -1874,8 +1877,7 @@ class BrainWorker:
         whose baseline windows fill a second [B, tc] buffer judged by
         the pairwise-active columnar program. Docs that don't qualify
         (unsettled or absent histories, cold fits, multi-alias docs
-        with baselines, canary docs under FOREMAST_CANARY_COLUMNAR=0)
-        are returned for the slow path. Returns (n_processed,
+        with baselines) are returned for the slow path. Returns (n_processed,
         slow_docs).
 
         Admission (which docs qualify, with their entry/gap references)
@@ -1900,10 +1902,7 @@ class BrainWorker:
             return len(failed) + len(released), slow
         updated_all: list = []
         n_joint = 0
-        kind_counts = {
-            "univariate": 0, "bivariate": 0, "lstm": 0, "baseline": 0,
-            "backbone": 0,
-        }
+        kind_counts = dict.fromkeys(self._fast_kinds, 0)
         if ok_joint:
             j_updated, demoted, j_counts = self._judge_joint_fast(
                 ok_joint, now
@@ -2011,10 +2010,8 @@ class BrainWorker:
             ) in aliases:
                 # baseline presence is a BUCKET dimension, not a
                 # slow-path demotion (ISSUE 14): a baseline-carrying
-                # alias routes its doc to the canary bucket below —
-                # unless the canary columnar path is opted out, in
-                # which case it keeps the pre-ISSUE-14 object-path
-                # routing. The fit gates (settled history, cached
+                # alias routes its doc to the canary bucket below.
+                # The fit gates (settled history, cached
                 # entry/gap) are identical for both buckets: the
                 # baseline window, like the current window, is fetched
                 # fresh every tick and never feeds the fit.
@@ -2841,10 +2838,7 @@ class BrainWorker:
                     prep.released, REASON_FETCH, led, prep.claim_mono
                 )
             updated = list(res.joint_updated)
-            kind_counts = {
-                "univariate": 0, "bivariate": 0, "lstm": 0, "baseline": 0,
-            "backbone": 0,
-            }
+            kind_counts = dict.fromkeys(self._fast_kinds, 0)
             if res.joint_counts:
                 for kind, n in res.joint_counts.items():
                     kind_counts[kind] += n
@@ -3425,12 +3419,11 @@ class BrainWorker:
             dm = self._device_mesh_state()
             if dm is not None:
                 self.metrics.observe_device_mesh(dm)
-        if self._mv_single and self.metrics is not None and hasattr(
-            self.metrics, "observe_backbone"
-        ):
-            bb = self._mvj.backbone_counters() if self._mvj else None
-            if bb is not None:
-                self.metrics.observe_backbone(bb)
+        if self.metrics is not None and self._mvj is not None:
+            for kind in self._counting_kinds:
+                kc = kind.counters(self._mvj)
+                if kc is not None:
+                    kind.observe(self.metrics, kc)
         self._last_tick = {
             "at": time.time(),
             "docs": n_docs,
@@ -3577,9 +3570,15 @@ class BrainWorker:
             # LSTM-AE params + residual-MVN state); None when the judge
             # has no joint dispatch
             "joint_arena": joint_arena,
-            "backbone": (
-                self._mvj.backbone_counters() if self._mvj is not None else None
-            ),
+            # a joint kind's own counters, under its name (`backbone`:
+            # tokens prefilled and scored, cache rows, expert load); None
+            # until the kind has built its state
+            **{
+                kind.name: (
+                    kind.counters(self._mvj) if self._mvj is not None else None
+                )
+                for kind in self._counting_kinds
+            },
             # device mesh (ISSUE 13/19, FOREMAST_DEVICE_MESH): mesh
             # shape, padded-row fraction, arena layout + HBM accounting
             # (per-device bytes x device count = shard-sum when sharded,

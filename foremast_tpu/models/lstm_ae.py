@@ -177,7 +177,7 @@ def fit_many(
 
     Short-history admission (ISSUE 10) feeds this the same way a full
     history does, but the caller MUST hold the PR-7 min-history gate
-    (`multivariate._judge_lstm`: >= 2 training windows of the job's own
+    (`engine/kinds/lstm.py`, `LstmKind.judge_cold`: >= 2 training windows of the job's own
     bucket) — a single-window "distribution" degenerates its cutoff
     calibration and flags clean noise. Jobs under the gate stay
     UNKNOWN until refinement grows their coverage past it.

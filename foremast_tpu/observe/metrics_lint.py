@@ -400,6 +400,7 @@ def default_registry_families():
     so every label combination appears in the exposition."""
     from prometheus_client import CollectorRegistry
 
+    from foremast_tpu.jobs.worker import fast_kinds
     from foremast_tpu.observe.gauges import BrainGauges, WorkerMetrics
     from foremast_tpu.observe.spans import Tracer, counter
 
@@ -410,7 +411,7 @@ def default_registry_families():
     metrics.observe_doc("completed_health", 1)
     metrics.observe_arena({"hits": 1, "misses": 1, "evictions": 0, "fallbacks": 0})
     metrics.tick_seconds.observe(0.01)
-    for kind in ("univariate", "bivariate", "lstm", "backbone"):
+    for kind in fast_kinds():
         metrics.fast_docs.labels(kind=kind).inc()
     metrics.observe_backbone({
         "prefill_tokens": 1, "window_tokens": 1, "fused_attn_tokens": 1,

@@ -311,26 +311,6 @@ def test_canary_fast_path_engages_and_matches_object_path(algorithm, season):
     assert all(r[6] < 0.05 for r in differs)
 
 
-def test_canary_columnar_opt_out(monkeypatch):
-    """FOREMAST_CANARY_COLUMNAR=0 keeps baseline-carrying docs on the
-    object path (the pre-round-16 routing) with identical judgments."""
-    monkeypatch.setenv("FOREMAST_CANARY_COLUMNAR", "0")
-    off_w, off_store, _ = _mk_worker(
-        4, "moving_average_all", 24, baseline_frac=1.0
-    )
-    assert not off_w._canary_fast
-    monkeypatch.delenv("FOREMAST_CANARY_COLUMNAR")
-    on_w, on_store, _ = _mk_worker(
-        4, "moving_average_all", 24, baseline_frac=1.0
-    )
-    for w in (off_w, on_w):
-        assert w.tick(now=NOW + 150) == 4
-        assert w.tick(now=NOW + 200) == 4
-    assert off_w._fast_kinds["baseline"] == 0
-    assert on_w._fast_kinds["baseline"] == 4
-    assert _statuses(off_store) == _statuses(on_store)
-
-
 def test_canary_doc_with_partial_baseline_aliases():
     """A canary doc where only SOME aliases carry baselines: the
     baseline-less aliases judge with the hardwired (p=1, False) inside

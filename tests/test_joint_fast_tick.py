@@ -227,16 +227,6 @@ def test_joint_window_bucket_drift_demotes_to_slow_path():
     )
 
 
-def test_joint_fast_disabled_by_env(monkeypatch):
-    """FOREMAST_JOINT_COLUMNAR=0 restores the object-path routing."""
-    monkeypatch.setenv("FOREMAST_JOINT_COLUMNAR", "0")
-    a, _, _, _ = _mk_worker(True)
-    assert not a._joint_fast
-    a.tick(now=NOW + 150)
-    a.tick(now=NOW + 200)
-    assert a._fast_kinds["bivariate"] == 0 and a._fast_kinds["lstm"] == 0
-
-
 def test_debug_state_carries_joint_counters():
     a, _, _, _ = _mk_worker(True)
     a.tick(now=NOW + 150)
@@ -677,7 +667,10 @@ def test_bulk_pack_matches_the_per_doc_loop(case):
             assert a.tobytes() == b.tobytes()
     assert got["updated"] == want["updated"]  # statuses, payloads, ORDER
     assert got["demoted"] == want["demoted"]
-    assert got["counts"] == want["counts"]
+    # the worker's dict holds every `fast_kinds()` key, the oracle's three
+    assert {k: n for k, n in got["counts"].items() if n} == {
+        k: n for k, n in want["counts"].items() if n
+    }
     assert [d for d, _ in got["hook"]] == [d for d, _ in want["hook"]]
     for (_, va), (_, vb) in zip(got["hook"], want["hook"]):
         assert len(va) == len(vb)

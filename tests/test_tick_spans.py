@@ -32,7 +32,7 @@ from prometheus_client import CollectorRegistry
 
 from benchmarks.worker_bench import build_mixed_fleet
 from foremast_tpu.config import BrainConfig
-from foremast_tpu.engine import multivariate
+from foremast_tpu.engine.kinds import lstm as lstm_kind
 from foremast_tpu.jobs import BrainWorker, joint_pack
 from foremast_tpu.jobs.pipeline import PipelineStats
 from foremast_tpu.observe import spans
@@ -129,7 +129,7 @@ def swept(tmp_path_factory):
     ):
         mp.setattr(w, name, rec.wrap(name, getattr(w, name)))
     mp.setattr(w._mvj, "_place_joint", rec.wrap("_place_joint", w._mvj._place_joint))
-    mp.setattr(multivariate, "ae_cutoff", rec.wrap("ae_cutoff", multivariate.ae_cutoff))
+    mp.setattr(lstm_kind, "ae_cutoff", rec.wrap("ae_cutoff", lstm_kind.ae_cutoff))
     mp.setattr(
         joint_pack, "align_series",
         rec.wrap("align_series", joint_pack.align_series),
