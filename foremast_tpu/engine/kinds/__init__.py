@@ -9,7 +9,7 @@ the judge, the pack and the worker need no edit.
 from __future__ import annotations
 
 from foremast_tpu.engine.kinds.backbone import BackboneKind
-from foremast_tpu.engine.kinds.base import ArenaKind, JointKind
+from foremast_tpu.engine.kinds.base import ArenaKind, JointKind, JointPending
 from foremast_tpu.engine.kinds.bivariate import BivariateKind
 from foremast_tpu.engine.kinds.lstm import LstmKind
 
@@ -50,6 +50,7 @@ __all__ = [
     "ArenaKind",
     "JOINT_KINDS",
     "JointKind",
+    "JointPending",
     "UNIVARIATE",
     "kinds_under",
     "select_mode",

@@ -14,6 +14,12 @@ one from-rows program. Such a kind supplies four small hooks and its two
 programs. A kind that keeps its state elsewhere (`backbone`: a prefix
 cache of its own) writes `judge_warm` itself.
 
+A warm judgment has two phases (ISSUE 30): `issue_warm` does the host work
+and issues the device program, `JointPending.wait` blocks for the flags.
+The sliced sweep issues every program of a slice before it waits for any,
+and lets the next slice's prefetch run under the waits. A kind that writes
+`judge_warm` alone is issued by running it to the end.
+
 Nothing here imports `engine.multivariate`: a kind receives the judge and
 uses its shared helpers (`_joint`, `_pairwise`, `_unknown`, `_emit`,
 `_effective_thresholds`, `_record_joint`, the arena lifecycle).
@@ -47,6 +53,43 @@ def pack_bf16_delta_rows(values: np.ndarray, mask: np.ndarray):
     anchor = (values[..., 0] * mask[..., 0]).astype(np.float32)
     delta = (values - anchor[..., None]) * mask
     return anchor, delta.astype(ml_dtypes.bfloat16)
+
+
+class JointPending:
+    """A warm joint judgment that has been issued: `wait()` gives the
+    anomaly flags [S, tcb] bool (host numpy), once. This base holds flags
+    that are on the host already (a kind that does not split its warm
+    path); `ArenaKind` returns one whose flags are still on the device.
+    Issued on the tick thread; `wait()` on any one thread (the joint
+    counterpart of `engine.judge.ColumnarPending`)."""
+
+    __slots__ = ("flags",)
+
+    def __init__(self, flags):
+        self.flags = flags
+
+    def wait(self) -> np.ndarray:
+        return self.flags
+
+
+class _ArenaPending(JointPending):
+    """The flags of one from-rows dispatch of `sb` rows, `s0` of them
+    real, not yet gathered."""
+
+    __slots__ = ("s0", "sb")
+
+    def __init__(self, flags, s0: int, sb: int):
+        super().__init__(flags)
+        self.s0 = s0
+        self.sb = sb
+
+    # The warm joint gather stage: blocks until the program has run and
+    # brings its flags to host numpy (the joint counterpart of
+    # `HealthJudge._columnar_wait`).
+    # foremast: device-boundary
+    def wait(self) -> np.ndarray:
+        with span("judge.decode", stage="decode", rows=self.sb, device=True):
+            return np.asarray(self.flags)[: self.s0]
 
 
 class JointKind:
@@ -104,6 +147,17 @@ class JointKind:
         anomaly flags [S, tcb] bool (host numpy)."""
         raise NotImplementedError
 
+    def issue_warm(
+        self, judge, keys, entries, metas, cur, mask, gaps
+    ) -> JointPending:
+        """`judge_warm` in two phases: everything up to the device
+        program's (asynchronous) issue here, the blocking gather in the
+        returned pending's `wait()`. Optional: this default runs
+        `judge_warm` to its end and hands back the finished flags."""
+        return JointPending(
+            self.judge_warm(judge, keys, entries, metas, cur, mask, gaps)
+        )
+
     def counters(self, judge) -> dict | None:
         return None
 
@@ -144,19 +198,23 @@ class ArenaKind(JointKind):
         data axis, `rows` local to each block."""
         raise NotImplementedError
 
-    # The warm joint gather stage: arrays in, jitted from-rows programs
-    # dispatched, flags gathered to host numpy out (the joint counterpart
-    # of the worker's _decode_uni).
-    # foremast: device-boundary
     def judge_warm(self, judge, keys, entries, metas, cur, mask, gaps):
-        """The batch axis is pow2-padded (dup of row 0, mask all-False =>
-        flags all-False) so claim-size jitter cannot force recompiles."""
+        return self.issue_warm(
+            judge, keys, entries, metas, cur, mask, gaps
+        ).wait()
+
+    def issue_warm(self, judge, keys, entries, metas, cur, mask, gaps):
+        """Arrays in, the jitted from-rows program dispatched, its flags
+        left on the device for `_ArenaPending.wait`. The batch axis is
+        pow2-padded (dup of row 0, mask all-False => flags all-False) so
+        claim-size jitter cannot force recompiles."""
         s0, f, tcb = cur.shape
         thr = float(judge.config.anomaly.rule_for(None).threshold)
         # Stage spans, in order, siblings on the tick thread: joint_prep
         # (pack) -> arena_assemble -> joint_prep (pack) -> h2d -> score
-        # -> decode. Every host array is built BEFORE the h2d span and
-        # the score span holds the jitted call alone.
+        # here, decode in the pending's wait(). Every host array is built
+        # BEFORE the h2d span and the score span holds the jitted call
+        # alone.
         with span("judge.joint_prep", stage="pack", rows=s0):
             m_need = self.season_need(entries)
             arena = judge._joint_arena_for(self, f, m_need)
@@ -276,20 +334,19 @@ class ArenaKind(JointKind):
                 handed += jax.tree.leaves(stacked)
                 state = jax.tree.map(jnp.asarray, stacked)
             note(sp, bytes=sum(int(a.nbytes) for a in handed))
-            if sharded:
-                (rows,) = judge._place_joint(rows)
-            rows_j = jnp.asarray(rows)
-            placed = [jnp.asarray(a) for a in judge._place_joint(*host)]
-            operands = [jnp.asarray(a) for a in operands]
+            rows_j = jnp.asarray(
+                judge._place_joint(rows)[0] if sharded else rows
+            )
+            placed = jax.tree.map(jnp.asarray, judge._place_joint(*host))
+            operands_j = jax.tree.map(jnp.asarray, operands)
         with span(
             "judge.score", stage="score", rows=sb, device=True
         ):
             if sharded:
                 flags = self.program_sharded(
-                    state, rows_j, *placed, *operands,
+                    state, rows_j, *placed, *operands_j,
                     mesh=judge.univariate.mesh,
                 )
             else:
-                flags = self.program(state, rows_j, *placed, *operands)
-        with span("judge.decode", stage="decode", rows=sb, device=True):
-            return np.asarray(flags)[:s0]
+                flags = self.program(state, rows_j, *placed, *operands_j)
+        return _ArenaPending(flags, s0, sb)

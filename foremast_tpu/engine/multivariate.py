@@ -56,6 +56,7 @@ from foremast_tpu.engine.kinds import (
     JOINT_KINDS,
     UNIVARIATE,
     JointKind,
+    JointPending,
     kinds_under,
     select_mode,
 )
@@ -564,6 +565,7 @@ class MultivariateJudge:
         cur: np.ndarray,
         mask: np.ndarray,
         gaps: np.ndarray | None = None,
+        issued: JointPending | None = None,
     ) -> np.ndarray:
         """Batched warm judgment of admitted joint docs — arrays in,
         anomaly flags out (the joint counterpart of `judge_columnar`).
@@ -571,7 +573,32 @@ class MultivariateJudge:
         cur [S, F, tcb] aligned current windows (caller-packed), mask
         [S, tcb] real points, keys/entries/metas per doc from
         `columnar_joint_peek`, gaps [S] int32 hist->cur steps (the kinds
-        with `needs_gaps`). Returns flags [S, tcb] bool (host numpy)."""
-        return JOINT_KINDS[mode].judge_warm(
+        with `needs_gaps`). Returns flags [S, tcb] bool (host numpy).
+
+        `issued`: what `joint_columnar_issue` returned for these same
+        arrays. The call is then the gather half alone — every warm
+        joint judgment's flags reach the host through this return."""
+        if issued is None:
+            issued = self.joint_columnar_issue(
+                mode, keys, entries, metas, cur, mask, gaps
+            )
+        return issued.wait()
+
+    def joint_columnar_issue(
+        self,
+        mode: str,
+        keys: list,
+        entries: list,
+        metas: list,
+        cur: np.ndarray,
+        mask: np.ndarray,
+        gaps: np.ndarray | None = None,
+    ) -> JointPending:
+        """`joint_columnar` up to the device program's issue (tick thread:
+        arena assignment and issue order are load-bearing). Hand the
+        returned pending back to `joint_columnar(..., issued=)` for the
+        flags; the sliced sweep issues a slice's every group before it
+        gathers one (the joint counterpart of `judge_columnar_async`)."""
+        return JOINT_KINDS[mode].issue_warm(
             self, keys, entries, metas, cur, mask, gaps
         )

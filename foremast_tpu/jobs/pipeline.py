@@ -62,6 +62,27 @@ ISSUE 15 extensions (the warm-path sliced sweep rides the same class):
   * ``on_drained(chunk, payload)`` — abort-path callback for chunks
     whose fetch completed but whose judgment never ran, so a fetch
     stage with side effects (claimed documents) can give them back.
+
+ISSUE 30 — a judge that owns the release (``judge_releases=True``):
+
+  The lookahead above is refilled BEFORE chunk N's judgment starts, so
+  chunk N+1's fetch runs beside the whole of it. That is the right
+  order where the fetch waits on I/O (the cold path). Where fetch and
+  judge are both Python (the warm sliced sweep: admission and packing)
+  they share one GIL, and the only part of a judgment a second Python
+  thread can hide under is the part spent blocked on the device. Such
+  a judge is called ``judge(chunk, payload, release)`` and calls
+  ``release()`` — the refill, idempotent, tick thread only — once its
+  device programs are issued and before it gathers any: "up to
+  ``depth - 1`` chunks ahead" then means ahead of the judge's GATHER,
+  not of its start. Between the previous chunk's hand-over and the
+  release the prefetch pool is idle and the judge's host work runs
+  alone. A judge that returns without releasing (nothing to dispatch)
+  has the refill made for it; one that raises before releasing leaves
+  nothing further submitted. `PipelineStats.released_at_issue` counts
+  the chunks whose successor was submitted from inside the judge, and
+  the `pipeline.wait_prefetch` span of a chunk submitted that way
+  carries ``at_issue=1``.
 """
 
 from __future__ import annotations
@@ -79,6 +100,10 @@ log = logging.getLogger("foremast_tpu.pipeline")
 DEFAULT_DEPTH = 2
 
 _DONE = object()
+
+
+def _nothing_to_release() -> None:
+    """The `release` a judge gets where no chunk is fetched ahead."""
 
 # Lazy-source exhaustion sentinel (ISSUE 15): a fetch stage backed by a
 # claim pool (the sliced sweep) signals "no more work" by RETURNING
@@ -125,6 +150,7 @@ class PipelineStats:
         "write_wait_seconds",
         "drain_seconds",
         "write_queue_peak",
+        "released_at_issue",
         "wall_seconds",
     )
 
@@ -148,6 +174,9 @@ class PipelineStats:
         self.write_wait_seconds = 0.0
         self.drain_seconds = 0.0
         self.write_queue_peak = 0
+        # chunks whose successor's fetch was submitted from inside the
+        # judge stage (`judge_releases`), not before or after it
+        self.released_at_issue = 0
         self.wall_seconds = 0.0
 
     def overlap_ratio(self) -> float:
@@ -173,6 +202,7 @@ class PipelineStats:
             "write_wait_seconds": round(self.write_wait_seconds, 4),
             "drain_seconds": round(self.drain_seconds, 4),
             "write_queue_peak": self.write_queue_peak,
+            "released_at_issue": self.released_at_issue,
             "wall_seconds": round(self.wall_seconds, 4),
             "overlap_ratio": round(self.overlap_ratio(), 4),
         }
@@ -198,10 +228,16 @@ class ChunkPipeline:
         prefetch_pool=None,
         boundary=None,
         on_drained=None,
+        judge_releases: bool = False,
     ):
         self.fetch = fetch
         self.judge = judge
         self.write = write
+        # `judge_releases` (ISSUE 30): the judge is called with a third
+        # argument `release` and decides when the next chunk's fetch is
+        # submitted (module docstring). Set by the caller in code for a
+        # judge whose fetch stage competes with it for the GIL.
+        self.judge_releases = bool(judge_releases)
         self.depth = max(1, int(depth))
         self.prefetch_pool = prefetch_pool
         # `boundary` (ISSUE 15): a tick-thread hook invoked between
@@ -248,6 +284,8 @@ class ChunkPipeline:
 
     def _run_serial(self, chunks, stats: PipelineStats) -> None:
         sized = hasattr(chunks, "__len__")
+        # nothing is fetched ahead here, so a judge's release does nothing
+        judge_args = (_nothing_to_release,) if self.judge_releases else ()
         for chunk in chunks:
             t0 = time.perf_counter()
             payload = self.fetch(chunk)
@@ -261,7 +299,7 @@ class ChunkPipeline:
                 stats.chunks += 1
                 stats.docs += len(chunk) if hasattr(chunk, "__len__") else 1
             try:
-                result = self.judge(chunk, payload)
+                result = self.judge(chunk, payload, *judge_args)
             except StageError as se:
                 t2 = time.perf_counter()
                 stats.judge_seconds += t2 - t1
@@ -325,17 +363,36 @@ class ChunkPipeline:
         pending: collections.deque = collections.deque()
         exhausted = [False]
 
-        def submit_next():
+        def submit_next(at_issue: int = 0) -> bool:
             if exhausted[0]:
-                return
+                return False
             try:
                 chunk = next(it)
             except StopIteration:
                 exhausted[0] = True
-                return
+                return False
             pending.append(
-                (chunk, self.prefetch_pool.submit(timed_fetch, chunk))
+                (
+                    chunk,
+                    self.prefetch_pool.submit(timed_fetch, chunk),
+                    at_issue,
+                )
             )
+            return True
+
+        # one refill of the lookahead window is owed per chunk judged:
+        # made before the judgment (the default), by the judge through
+        # `release`, or after a judge that released nothing
+        refill_owed = [False]
+
+        def refill(at_issue: int) -> None:
+            if not refill_owed[0]:
+                return
+            refill_owed[0] = False
+            if submit_next(at_issue) and at_issue:
+                stats.released_at_issue += 1
+
+        judge_args = (lambda: refill(1),) if self.judge_releases else ()
 
         def put_judged(item, i: int) -> None:
             # blocks while the writer is `depth` chunks behind
@@ -352,10 +409,15 @@ class ChunkPipeline:
                 if write_errors:
                     break  # writer failed; don't burn device time on
                     # a judgment whose result could never be written
-                chunk, fut = pending.popleft()
+                chunk, fut, at_issue = pending.popleft()
                 i += 1
                 t0 = time.perf_counter()
-                with span("pipeline.wait_prefetch", stage="wait", slice=i):
+                with span(
+                    "pipeline.wait_prefetch",
+                    stage="wait",
+                    slice=i,
+                    at_issue=at_issue,
+                ):
                     fetch_s, payload = fut.result()
                 stats.judge_stall_seconds += time.perf_counter() - t0
                 stats.fetch_seconds += fetch_s
@@ -373,10 +435,12 @@ class ChunkPipeline:
                     stats.docs += (
                         len(chunk) if hasattr(chunk, "__len__") else 1
                     )
-                submit_next()  # keep the lookahead window full
+                refill_owed[0] = True
+                if not self.judge_releases:
+                    refill(0)  # keep the lookahead window full
                 t1 = time.perf_counter()
                 try:
-                    result = self.judge(chunk, payload)
+                    result = self.judge(chunk, payload, *judge_args)
                 except StageError as se:
                     # stop feeding NOW (no further chunk touches the
                     # broken judge), but the partial result still rides
@@ -389,6 +453,7 @@ class ChunkPipeline:
                 stats.judge_seconds += time.perf_counter() - t1
                 if write_errors:
                     break  # writer failed mid-judgment; stop feeding
+                refill(0)  # a judge that issued and released nothing
                 put_judged((chunk, result), i)
                 # measured after the put: the peak reflects queued
                 # chunks only, so it never exceeds the documented
@@ -411,7 +476,7 @@ class ChunkPipeline:
                 wt.join()
             stats.drain_seconds += time.perf_counter() - t0
             stats.write_seconds += write_seconds[0]
-            for chunk, fut in pending:
+            for chunk, fut, _ in pending:
                 if fut.cancel():
                     continue
                 try:

@@ -205,3 +205,43 @@ def test_pack_bulk_layer_reads_bulk_from_the_slices_first_pack_span():
     for sp in rec["spans"]:
         sp["args"].pop("bulk", None)
     assert span_attr_per_kwin.read(rec, spec["params"]) is None
+
+
+def test_prefetch_at_issue_layer_counts_the_waits_released_from_a_judge():
+    """ISSUE 30's layer file through the reader it names: a sweep of four
+    slices waits for five prefetches, four of them submitted from inside
+    a judge (`at_issue` 1); a program that records no such attr (the
+    parent) leaves the metric out."""
+    import json
+    import os
+
+    from chipbench import readers
+
+    path = os.path.join(
+        os.path.dirname(readers.__file__), os.pardir, "layers",
+        "prefetch_at_issue_per_mwin.sweep.json",
+    )
+    with open(path) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "span_attr_per_kwin"
+    waits = [
+        _span("pipeline.wait_prefetch", 1000 + 10 * i, 2, stage="wait",
+              slice=i, at_issue=int(i > 0))
+        for i in range(5)
+    ]
+    rec = _record(
+        [
+            _root(0, 500, trace="setup"),
+            _span("pipeline.wait_prefetch", 10, 2, trace="setup",
+                  stage="wait", slice=1, at_issue=1),
+            _root(1000, 100, trace="t1"),
+            *waits,
+        ],
+        windows=131_072,
+    )
+    assert span_attr_per_kwin.read(rec, spec["params"]) == pytest.approx(
+        4 / 0.131072
+    )
+    for sp in rec["spans"]:
+        sp["args"].pop("at_issue", None)
+    assert span_attr_per_kwin.read(rec, spec["params"]) is None

@@ -433,9 +433,20 @@ class _StubJudge:
         )
         self.calls = []
 
-    def joint_columnar(self, mode, keys, entries, metas, cur, mask, gaps=None):
+    def joint_columnar(self, mode, keys, entries, metas, cur, mask,
+                       gaps=None, issued=None):
+        if issued is None:
+            issued = self.joint_columnar_issue(
+                mode, keys, entries, metas, cur, mask, gaps
+            )
+        return issued.wait()
+
+    def joint_columnar_issue(self, mode, keys, entries, metas, cur, mask,
+                             gaps=None):
+        from foremast_tpu.engine.kinds import JointPending
+
         self.calls.append((mode, keys, entries, metas, cur, mask, gaps))
-        return mask & (cur.max(axis=1) > 2.0)
+        return JointPending(mask & (cur.max(axis=1) > 2.0))
 
 
 T0 = int(NOW) - 30 * 60
@@ -616,6 +627,14 @@ def _slice_of(specs):
     return ok_joint
 
 
+def _judge_joint_fast(worker, ok_joint, now):
+    """The worker's warm joint judgment, both halves, as `_fast_tick`
+    runs them."""
+    return worker._decide_joint_fast(
+        worker._judge_joint_fast(ok_joint, now), now
+    )
+
+
 def _run_pack(judge_fn, specs):
     from benchmarks.worker_bench import ArraySource
     from foremast_tpu.jobs.store import InMemoryStore
@@ -650,7 +669,7 @@ def test_bulk_pack_matches_the_per_doc_loop(case):
 
     build, bulk, aligned, empty, demoted, dispatches = _PACK_CASES[case]
     want = _run_pack(_oracle_judge_joint_fast, build())
-    got = _run_pack(BrainWorker._judge_joint_fast, build())
+    got = _run_pack(_judge_joint_fast, build())
 
     assert len(got["calls"]) == len(want["calls"]) == dispatches
     for g, w in zip(got["calls"], want["calls"]):

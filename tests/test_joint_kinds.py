@@ -158,6 +158,81 @@ def test_a_toy_kind_is_judged_cold_then_warm_through_the_worker(monkeypatch):
     worker.close()
 
 
+# -- (a') a warm judgment in two phases (ISSUE 30) ----------------------------
+
+
+def test_a_kind_that_only_judges_warm_is_issued_finished():
+    """The base class's `issue_warm` runs `judge_warm` to its end: the toy
+    kind above needs no edit to be issued by the sliced sweep."""
+    from foremast_tpu.engine.kinds import JointPending
+
+    assert "issue_warm" not in vars(ToyKind)
+    cur = np.array([[[1.0, 9.0], [1.0, 1.0]]], np.float32)  # [S, F, tcb]
+    mask = np.ones((1, 2), bool)
+    pending = ToyKind().issue_warm(None, ["k"], [(5.0,)], [None], cur, mask, None)
+    assert type(pending) is JointPending
+    np.testing.assert_array_equal(pending.wait(), [[False, True]])
+
+
+@pytest.fixture(scope="module")
+def warm_arena_groups():
+    """What a warm tick hands each arena kind, and the judge that took
+    it: a cold-fitted worker over one bivariate and one LSTM-hybrid doc
+    (and two single-alias ones)."""
+    import dataclasses
+
+    from benchmarks.worker_bench import build_mixed_fleet
+
+    store, source, _ = build_mixed_fleet(4, 256, 30, NOW, joint_frac=0.5)
+    cfg = BrainConfig(algorithm="auto", season_steps=24, max_cache_size=80)
+    cfg = dataclasses.replace(
+        cfg, anomaly=dataclasses.replace(cfg.anomaly, threshold=4.0)
+    )
+    worker = BrainWorker(store, source, config=cfg, worker_id="issue-w")
+    worker.judge.lstm_steps = 10  # CI speed
+    assert worker.tick(now=NOW + 150) == 4  # cold: fits
+    seen = {}
+    orig = worker._mvj.joint_columnar_issue
+
+    def recording(mode, *args):
+        seen[mode] = args
+        return orig(mode, *args)
+
+    worker._mvj.joint_columnar_issue = recording
+    assert worker.tick(now=NOW + 200) == 4  # warm: one group a kind
+    worker._mvj.joint_columnar_issue = orig
+    yield worker._mvj, seen
+    worker.close()
+
+
+@pytest.mark.parametrize("name", ["bivariate", "lstm"])
+def test_issue_then_wait_is_judge_warm(warm_arena_groups, name):
+    """`ArenaKind.issue_warm` leaves the flags on the device; its
+    `wait()` gives what `judge_warm` (and `joint_columnar`) give for the
+    same arrays."""
+    import jax
+
+    from foremast_tpu.engine.kinds import ArenaKind, JointPending
+
+    judge, seen = warm_arena_groups
+    kind, args = JOINT_KINDS[name], seen[name]
+    assert isinstance(kind, ArenaKind)
+    pending = kind.issue_warm(judge, *args)
+    assert isinstance(pending, JointPending)
+    assert isinstance(pending.flags, jax.Array)  # issued, not gathered
+    got = pending.wait()
+    cur = args[3]
+    assert isinstance(got, np.ndarray) and got.dtype == bool
+    assert got.shape == (cur.shape[0], cur.shape[2])
+    np.testing.assert_array_equal(got, kind.judge_warm(judge, *args))
+    # the judge's two entries: one call, or issue then gather
+    np.testing.assert_array_equal(got, judge.joint_columnar(name, *args))
+    issued = judge.joint_columnar_issue(name, *args)
+    np.testing.assert_array_equal(
+        got, judge.joint_columnar(name, *args, issued=issued)
+    )
+
+
 # -- (b) the persisted keys and the warm gates of the three real kinds --------
 
 APP, ALIASES, HKEYS = "app7", ("latency", "tps"), ("hk-latency", "hk-tps")

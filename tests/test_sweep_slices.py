@@ -502,3 +502,251 @@ def test_sweep_abort_releases_pooled_and_prepared_docs():
     # and the next sweep judges everything again, cleanly
     assert w.tick(now=now + 120) == 32
     w.close()
+
+
+# -- ISSUE 30: a slice's programs are issued, then the prefetch is released --
+#
+# From the recorded spans and the store, never from timings: a fleet of
+# all four kinds (bivariate, LSTM-hybrid, univariate, canary: one of each
+# in every four docs, so every slice holds both joint dispatch groups and
+# both columnar buckets), swept sliced and, on a twin fleet, by the
+# monolithic `_fast_tick`.
+
+FLEET_NOW = 1_760_000_000.0
+FOUR_KINDS = 16  # docs; slices of 8
+
+
+def _four_kind_worker(slice_docs, trace_dir=None):
+    import dataclasses
+
+    from benchmarks.worker_bench import ArraySource, _add_joint_service
+    from foremast_tpu.config import BrainConfig
+    from foremast_tpu.jobs import BrainWorker
+    from foremast_tpu.jobs.store import InMemoryStore
+    from prometheus_client import CollectorRegistry
+
+    from foremast_tpu.observe.spans import Tracer
+
+    rng = np.random.default_rng(31)  # a draw on which no clean doc goes terminal
+    store, source = InMemoryStore(), ArraySource()
+    t_now = int(FLEET_NOW)
+    ht = t_now - 86_400 * 7 + 60 * np.arange(256, dtype=np.int64)
+    ct = ht[-1] + 60 + 60 * np.arange(30, dtype=np.int64)
+    end_time = time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime(t_now + 3600))
+    for s in range(FOUR_KINDS):
+        f = (2, 4, 1, 1)[s % 4]
+        doc_id = _add_joint_service(
+            store, source, str(s), ht, ct, f, end_time, rng
+        )
+        if s % 4 == 3:
+            # a canary: the same window again as its baseline pods'
+            doc = store.get(doc_id)
+            base_url = f"http://prom/base?q=m0:app{s}&step=60"
+            _, cv = source.data[f"http://prom/cur?q=m0:app{s}&step=60"]
+            source.data[base_url] = (ct - 3600, cv.copy())
+            doc.baseline_config = f"m0== {base_url}"
+            doc.strategy = "canary"
+    cfg = BrainConfig(
+        algorithm="auto", season_steps=24, max_cache_size=8 * FOUR_KINDS
+    )
+    cfg = dataclasses.replace(
+        cfg, anomaly=dataclasses.replace(cfg.anomaly, threshold=4.0)
+    )
+    tracer = None
+    if trace_dir is not None:
+        tracer = Tracer(
+            service="test", registry=CollectorRegistry(),
+            trace_dir=str(trace_dir), buffer_size=1 << 14,
+        )
+    w = BrainWorker(
+        store, source, config=cfg, claim_limit=2 * FOUR_KINDS,
+        worker_id="issue30-w", tracer=tracer,
+    )
+    w.judge.lstm_steps = 10  # CI speed; identical on both workers
+    w.sweep_slice_docs = slice_docs
+    w.pipeline_depth = 2
+    assert w.tick(now=FLEET_NOW + 150) == FOUR_KINDS  # cold: fits
+    assert w.tick(now=FLEET_NOW + 200) == FOUR_KINDS  # warm: compiles
+    return w, store, source, tracer
+
+
+def _spike(source, sid, f):
+    """The last 3 points of every metric moved by 0.6; a 2-metric doc's
+    metrics move APART (the bivariate kind follows a common excursion)."""
+    for m in range(f):
+        url = f"http://prom/cur?q=m{m}:app{sid}&step=60"
+        ct, cv = source.data[url]
+        spiked = cv.copy()
+        spiked[-3:] += -0.6 if (f == 2 and m == 1) else 0.6
+        source.data[url] = (ct, spiked)
+
+
+def _written(store):
+    return {
+        d.id: (d.status, d.status_code, d.reason, d.anomaly_info)
+        for d in store._docs.values()
+    }
+
+
+@pytest.fixture(scope="module")
+def four_kind_sweeps(tmp_path_factory):
+    """The sweep under test, sliced and traced (A) and monolithic (B), on
+    twin fleets with one doc of each kind spiked in each slice."""
+    a, a_store, a_src, tracer = _four_kind_worker(
+        8, tmp_path_factory.mktemp("issue30")
+    )
+    b, b_store, b_src, _ = _four_kind_worker(0)
+    assert a._sweep_sliceable() and not b._sweep_sliceable()
+    for src in (a_src, b_src):
+        for sid, f in ((0, 2), (1, 4), (2, 1), (3, 1), (9, 4), (12, 2)):
+            _spike(src, sid, f)
+    before = tracer.ring.total
+    assert a.tick(now=FLEET_NOW + 260) == FOUR_KINDS
+    events = tracer.ring.snapshot()[-(tracer.ring.total - before):]
+    assert b.tick(now=FLEET_NOW + 260) == FOUR_KINDS
+    out = {
+        "events": sorted(events, key=lambda e: e["ts"]),
+        "sliced": _written(a_store),
+        "monolithic": _written(b_store),
+        "last_sweep": dict(a._last_sweep),
+        "fast_kinds": (dict(a._fast_kinds), dict(b._fast_kinds)),
+    }
+    a.close()
+    b.close()
+    return out
+
+
+def _by_slice(events):
+    """The tick thread's `judge.score` and `judge.decode` events of each
+    slice: those between its `pipeline.wait_prefetch` and the next."""
+    waits = [e for e in events if e["name"] == "pipeline.wait_prefetch"]
+    tick_tid = waits[0]["tid"]
+    out = {}
+    for e in events:
+        if e["tid"] != tick_tid or e["name"] not in (
+            "judge.score", "judge.decode"
+        ):
+            continue
+        k = max(w["args"]["slice"] for w in waits if w["ts"] <= e["ts"])
+        out.setdefault(k, {"judge.score": [], "judge.decode": []})[
+            e["name"]
+        ].append(e)
+    return out
+
+
+def _end(e):
+    return e["ts"] + e["dur"]
+
+
+def test_every_program_of_a_slice_is_issued_before_one_is_gathered(
+    four_kind_sweeps,
+):
+    slices = _by_slice(four_kind_sweeps["events"])
+    assert sorted(slices) == [0, 1]
+    for k, spans in slices.items():
+        # bivariate, LSTM, univariate, canary: four issues; the two joint
+        # groups are gathered on this thread (the buckets': the writer)
+        assert len(spans["judge.score"]) == 4, k
+        assert len(spans["judge.decode"]) == 2, k
+        last_issue = max(_end(e) for e in spans["judge.score"])
+        assert last_issue <= min(e["ts"] for e in spans["judge.decode"]), k
+    # the writer's gathers of a slice's buckets come after its issues too
+    tick_tid = slices[0]["judge.score"][0]["tid"]
+    writer = [
+        e for e in four_kind_sweeps["events"]
+        if e["name"] == "judge.decode" and e["tid"] != tick_tid
+    ]
+    assert len(writer) == 4
+    for k in (0, 1):
+        last_issue = max(_end(e) for e in slices[k]["judge.score"])
+        assert all(last_issue <= e["ts"] for e in writer[2 * k: 2 * k + 2])
+
+
+def test_next_slices_admission_opens_after_the_last_issue(four_kind_sweeps):
+    """The prefetch of slice k+1 is submitted from inside slice k's
+    judgment, once its programs are in flight: `worker.admit` of k+1
+    opens after slice k's last `judge.score` closes (and the pack,
+    `joint_prep` and `h2d` before it have run alone)."""
+    events = four_kind_sweeps["events"]
+    slices = _by_slice(events)
+    admits = [e for e in events if e["name"] == "worker.admit"]
+    assert len(admits) == 2  # the END probe admits nothing
+    last_issue_0 = max(_end(e) for e in slices[0]["judge.score"])
+    assert admits[1]["ts"] >= last_issue_0
+    host_alone = [
+        e for e in events
+        if e["name"] in ("worker.pack_joint", "judge.joint_prep", "judge.h2d")
+        and e["ts"] < last_issue_0
+    ]
+    assert host_alone and all(_end(e) <= admits[1]["ts"] for e in host_alone)
+    waits = [
+        (e["args"]["slice"], e["args"]["at_issue"])
+        for e in events if e["name"] == "pipeline.wait_prefetch"
+    ]
+    assert waits == [(0, 0), (1, 1), (2, 1)]
+    pipe = four_kind_sweeps["last_sweep"]["pipeline"]
+    assert pipe["completed"] and pipe["released_at_issue"] == 2
+    assert four_kind_sweeps["last_sweep"]["slices"] == 2
+
+
+def test_released_sweep_writes_what_the_monolithic_tick_writes(
+    four_kind_sweeps,
+):
+    """Status, code, reason and anomaly payload of every doc, byte for
+    byte, against `_fast_tick` on the twin fleet."""
+    import json
+
+    a, b = four_kind_sweeps["sliced"], four_kind_sweeps["monolithic"]
+    assert json.dumps(a, sort_keys=True) == json.dumps(b, sort_keys=True)
+    unhealthy = {
+        k for k, v in a.items() if v[0] == STATUS_COMPLETED_UNHEALTH
+    }
+    # both joint kinds in both slices went through a payload
+    assert {"job-0", "job-1", "job-9", "job-12"} <= unhealthy
+    assert set(a["job-1"][3]["values"]) == {"m0", "m1", "m2", "m3"}
+    fa, fb = four_kind_sweeps["fast_kinds"]
+    assert fa == fb
+    assert all(fa[k] for k in ("bivariate", "lstm", "univariate", "baseline"))
+
+
+def test_issue_that_raises_on_the_second_group_writes_no_joint_doc():
+    """The first slice's second joint group dies at issue: nothing of
+    either group is decided or written, the slice's every doc and the
+    rest of the sweep are released un-judged, and the next slice's
+    prefetch was never submitted."""
+    from foremast_tpu.chaos.degrade import REASON_ABORT
+
+    w, store, source, _ = _four_kind_worker(8)
+    _spike(source, 0, 2)  # would go terminal if its group were decided
+    before = _written(store)
+    orig = w._mvj.joint_columnar_issue
+    calls = []
+
+    def dying(mode, *a, **kw):
+        calls.append(mode)
+        if len(calls) == 2:
+            raise RuntimeError("device died")
+        return orig(mode, *a, **kw)
+
+    w._mvj.joint_columnar_issue = dying
+    admitted = []
+    orig_admit = w._admit_fast
+    w._admit_fast = lambda docs, now: (
+        admitted.append(len(docs)) or orig_admit(docs, now)
+    )
+    aborted = w._degrade.stats.docs_snapshot().get(REASON_ABORT, 0)
+    with pytest.raises(RuntimeError, match="device died"):
+        w.tick(now=FLEET_NOW + 260)
+    w._mvj.joint_columnar_issue = orig
+    assert len(calls) == 2 and set(calls) == {"bivariate", "lstm"}
+    assert admitted == [8]  # slice 1 was never prepared
+    assert _written(store) == before  # all released as they were claimed
+    assert (
+        w._degrade.stats.docs_snapshot()[REASON_ABORT] - aborted
+        == FOUR_KINDS
+    )
+    assert w._last_sweep["pipeline"]["released_at_issue"] == 0
+    # and the next sweep judges everything, the spiked doc included
+    assert w.tick(now=FLEET_NOW + 320) == FOUR_KINDS
+    assert _written(store)["job-0"][0] == STATUS_COMPLETED_UNHEALTH
+    w.close()

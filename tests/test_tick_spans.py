@@ -265,13 +265,17 @@ def test_first_pack_joint_span_counts_bulk_and_aligned(swept):
 def test_pack_joint_spans_one_a_slice_and_one_a_dispatch_group(
     case, dispatch_groups, tmp_path
 ):
-    from tests.test_joint_fast_tick import _PACK_CASES, _run_pack
+    from tests.test_joint_fast_tick import (
+        _PACK_CASES,
+        _judge_joint_fast,
+        _run_pack,
+    )
 
     tracer = Tracer(
         service="test", registry=CollectorRegistry(), trace_dir=str(tmp_path)
     )
     with tracer.span("worker.tick"):
-        _run_pack(BrainWorker._judge_joint_fast, _PACK_CASES[case][0]())
+        _run_pack(_judge_joint_fast, _PACK_CASES[case][0]())
     packs = [
         e["args"]
         for e in tracer.ring.snapshot()

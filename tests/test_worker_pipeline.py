@@ -351,3 +351,269 @@ def test_pipeline_stats_account_stages():
     # serial fallback: single chunk
     stats1 = pipe.run([1])
     assert stats1.pipelined is False
+
+
+# -- a judge that owns the release (ISSUE 30) ---------------------------
+#
+# Built with `judge_releases=True`; the cases above, built without it,
+# pin the cold path's order (the successor is submitted BEFORE the
+# judgment starts).
+
+
+class _RecordingPool:
+    """A prefetch pool that notes, on the submitting thread, which chunk
+    each submit carries: what has been SUBMITTED is then a fact of the
+    tick thread's own order, not of thread scheduling."""
+
+    def __init__(self, workers=1):
+        from concurrent.futures import ThreadPoolExecutor
+
+        self.pool = ThreadPoolExecutor(max_workers=workers)
+        self.submitted = []
+
+    def submit(self, fn, chunk):
+        self.submitted.append(chunk)
+        return self.pool.submit(fn, chunk)
+
+    def shutdown(self):
+        self.pool.shutdown(wait=True)
+
+
+def _releasing_pipe(fetch, judge, write, depth=2, **kw):
+    from foremast_tpu.jobs.pipeline import ChunkPipeline
+
+    pool = _RecordingPool(max(1, depth - 1))
+    pipe = ChunkPipeline(
+        fetch, judge, write, depth=depth, prefetch_pool=pool,
+        judge_releases=True, **kw,
+    )
+    return pipe, pool
+
+
+def _wait_prefetch_spans(run, trace_dir):
+    """Run `run()` under a tracer; the `pipeline.wait_prefetch` events'
+    (slice, at_issue), in order."""
+    from prometheus_client import CollectorRegistry
+
+    from foremast_tpu.observe.spans import Tracer
+
+    tracer = Tracer(
+        service="pipe-test", registry=CollectorRegistry(),
+        trace_dir=str(trace_dir),
+    )
+    with tracer.span("root"):
+        run()
+    return [
+        (e["args"]["slice"], e["args"]["at_issue"])
+        for e in tracer.ring.snapshot()
+        if e["name"] == "pipeline.wait_prefetch"
+    ]
+
+
+def test_release_is_what_submits_the_successor(tmp_path):
+    """Inside the judge of chunk k, chunk k+1 has not been submitted, so
+    its fetch has not started, until `release()` is called; after the
+    call it has been, and it starts."""
+    started = {c: threading.Event() for c in (1, 2, 3)}
+    seen = []
+
+    def fetch(chunk):
+        started[chunk].set()
+        return chunk
+
+    def judge(chunk, payload, release):
+        nxt = chunk + 1
+        before = list(pool.submitted)
+        began = nxt in started and started[nxt].is_set()
+        release()
+        if nxt in started:
+            assert started[nxt].wait(10)  # it runs while we still judge
+        seen.append((chunk, before, began, list(pool.submitted)))
+        return payload
+
+    written = []
+    pipe, pool = _releasing_pipe(fetch, judge, lambda c, r: written.append(r))
+    spans = _wait_prefetch_spans(lambda: pipe.run([1, 2, 3]), tmp_path)
+    pool.shutdown()
+    assert seen == [
+        (1, [1], False, [1, 2]),
+        (2, [1, 2], False, [1, 2, 3]),
+        (3, [1, 2, 3], False, [1, 2, 3]),  # nothing left to submit
+    ]
+    assert written == [1, 2, 3]
+    stats = pipe.last_stats
+    assert stats.completed and stats.released_at_issue == 2
+    assert stats.as_dict()["released_at_issue"] == 2
+    # the first chunk was submitted before any judgment, the others from
+    # inside one
+    assert spans == [(0, 0), (1, 1), (2, 1)]
+
+
+@pytest.mark.parametrize("depth", [2, 3])
+def test_a_judge_that_never_releases_still_gets_every_chunk(depth, tmp_path):
+    """The pipeline makes the refill itself once such a judge returns:
+    every chunk is fetched, judged and written, in order; none counts as
+    released at issue."""
+    judged, written, at_return = [], [], []
+
+    def judge(chunk, payload, release):
+        judged.append(chunk)
+        at_return.append(list(pool.submitted))
+        return payload * 10
+
+    pipe, pool = _releasing_pipe(
+        lambda c: c, judge, lambda c, r: written.append((c, r)), depth=depth
+    )
+    spans = _wait_prefetch_spans(
+        lambda: pipe.run([1, 2, 3, 4, 5]), tmp_path
+    )
+    pool.shutdown()
+    assert judged == [1, 2, 3, 4, 5]
+    assert written == [(c, 10 * c) for c in judged]
+    assert pool.submitted == [1, 2, 3, 4, 5]
+    # while chunk k is judged, only the depth - 1 before it run ahead
+    assert at_return[0] == list(range(1, depth))
+    assert pipe.last_stats.released_at_issue == 0
+    assert spans == [(i, 0) for i in range(5)]
+
+
+def test_release_twice_submits_once():
+    def judge(chunk, payload, release):
+        release()
+        release()
+        return payload
+
+    written = []
+    pipe, pool = _releasing_pipe(
+        lambda c: c, judge, lambda c, r: written.append(r)
+    )
+    stats = pipe.run([1, 2, 3, 4])
+    pool.shutdown()
+    assert pool.submitted == [1, 2, 3, 4]  # each once
+    assert written == [1, 2, 3, 4]
+    assert stats.released_at_issue == 3
+
+
+@pytest.mark.parametrize("released_first", [False, True])
+def test_stage_error_submits_nothing_further(released_first):
+    """A judge that dies submits no further chunk: one that had not yet
+    released leaves its successor unsubmitted; one that had leaves it to
+    the drain (`on_drained`), and nothing after it. The partial result is
+    written either way."""
+    from foremast_tpu.jobs.pipeline import StageError
+
+    written, drained = [], []
+    fetched_3 = threading.Event()
+
+    def fetch(chunk):
+        if chunk == 3:
+            fetched_3.set()
+        return f"prep-{chunk}"
+
+    def judge(chunk, payload, release):
+        if chunk == 2:
+            if released_first:
+                release()
+                # one not yet started would be cancelled by the drain
+                assert fetched_3.wait(10)
+            raise StageError(RuntimeError("dead"), ("partial", chunk))
+        release()
+        return payload
+
+    pipe, pool = _releasing_pipe(
+        fetch, judge, lambda c, r: written.append(r),
+        on_drained=lambda c, p: drained.append((c, p)),
+    )
+    with pytest.raises(RuntimeError, match="dead"):
+        pipe.run([1, 2, 3, 4])
+    pool.shutdown()
+    assert written == ["prep-1", ("partial", 2)]
+    if released_first:
+        assert pool.submitted == [1, 2, 3]
+        assert drained == [(3, "prep-3")]
+    else:
+        assert pool.submitted == [1, 2]
+        assert drained == []
+    assert not pipe.last_stats.completed
+
+
+def test_plain_judge_exception_before_release_submits_nothing_further():
+    def judge(chunk, payload, release):
+        if chunk == 2:
+            raise RuntimeError("boom")
+        release()
+        return payload
+
+    written = []
+    pipe, pool = _releasing_pipe(
+        lambda c: c, judge, lambda c, r: written.append(r)
+    )
+    with pytest.raises(RuntimeError, match="boom"):
+        pipe.run([1, 2, 3, 4])
+    pool.shutdown()
+    assert pool.submitted == [1, 2]
+    assert written == [1]  # everything judged before the failure
+
+
+def test_released_lazy_source_ends_on_END_and_counts_its_probe(tmp_path):
+    """The sliced sweep's shape: an unbounded iterator whose fetch says
+    END. Four real chunks and the END probe: the probe too is submitted
+    from inside a judge (4 of 5 prefetches), END is never judged, and
+    the boundary hook runs after each real chunk."""
+    import itertools
+
+    from foremast_tpu.jobs.pipeline import END
+
+    judged, written, boundaries = [], [], []
+
+    def judge(i, payload, release):
+        release()
+        judged.append(i)
+        return payload
+
+    pipe, pool = _releasing_pipe(
+        lambda i: f"prep-{i}" if i < 4 else END,
+        judge,
+        lambda i, r: written.append(r),
+        boundary=lambda: boundaries.append(len(judged)),
+    )
+    spans = _wait_prefetch_spans(
+        lambda: pipe.run(itertools.count()), tmp_path
+    )
+    pool.shutdown()
+    assert judged == [0, 1, 2, 3]
+    assert written == [f"prep-{i}" for i in range(4)]
+    assert boundaries == [1, 2, 3, 4]
+    assert pool.submitted == [0, 1, 2, 3, 4]  # nothing past the END probe
+    stats = pipe.last_stats
+    assert stats.completed and stats.chunks == 4
+    assert stats.released_at_issue == 4
+    assert spans == [(0, 0), (1, 1), (2, 1), (3, 1), (4, 1)]
+
+
+def test_serial_loop_hands_the_judge_a_release_that_does_nothing():
+    """No pool (a `concurrent_fetch = False` source) or depth 1: the
+    judge still takes three arguments, and nothing runs ahead."""
+    from foremast_tpu.jobs.pipeline import ChunkPipeline
+
+    order = []
+
+    def fetch(chunk):
+        order.append(("fetch", chunk))
+        return chunk
+
+    def judge(chunk, payload, release):
+        release()
+        order.append(("judge", chunk))
+        return payload
+
+    pipe = ChunkPipeline(
+        fetch, judge, lambda c, r: order.append(("write", c)),
+        depth=2, prefetch_pool=None, judge_releases=True,
+    )
+    stats = pipe.run([1, 2])
+    assert not stats.pipelined and stats.released_at_issue == 0
+    assert order == [
+        ("fetch", 1), ("judge", 1), ("write", 1),
+        ("fetch", 2), ("judge", 2), ("write", 2),
+    ]
