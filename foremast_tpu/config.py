@@ -164,7 +164,7 @@ UNIVARIATE_ALGORITHMS = frozenset(
     }
 )
 JOINT_ALGORITHMS = frozenset(
-    {"auto", "bivariate_normal", "lstm_autoencoder", "backbone"}
+    {"auto", "bivariate_normal", "lstm_autoencoder", "backbone", "backbone_kda"}
 )
 KNOWN_ALGORITHMS = UNIVARIATE_ALGORITHMS | JOINT_ALGORITHMS
 
@@ -338,8 +338,9 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
         "(per-series structure screen over {mean, HW or phase_means by "
         "season length, Fourier seasonal} — recommended for unknown "
         "metric mixes), `auto`, `bivariate_normal`, `lstm_autoencoder` "
-        "(hybrid: AE + seasonal-residual Gaussian), `backbone` (the shared "
-        "sequence backbone, docs/backbone.md: `ML_THRESHOLD` is then a "
+        "(hybrid: AE + seasonal-residual Gaussian), `backbone` / "
+        "`backbone_kda` (a shared sequence backbone, softmax-attention "
+        "or linear-attention, docs/backbone.md: `ML_THRESHOLD` is then a "
         "score in nats). An unknown name is an error at load",
         "engine",
     ),
@@ -486,10 +487,12 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
         "FOREMAST_BACKBONE_MODEL",
         None,
         "path",
-        "model file of `ML_ALGORITHM=backbone`: the sequence model's "
-        "config.json keys plus the `share` of it this process holds and "
-        "the seed of its weights (default: the packaged "
-        "`foremast_tpu/models/configs/command-a-plus-05-2026.json`; "
+        "model file of `ML_ALGORITHM=backbone` / `backbone_kda`: the "
+        "sequence model's config.json keys plus the `share` of it this "
+        "process holds and the seed of its weights; its `model_type` has "
+        "to be one the kind takes (default: the packaged "
+        "`foremast_tpu/models/configs/command-a-plus-05-2026.json`, for "
+        "`backbone_kda` `kimi-linear-48b-a3b-instruct.json`; "
         "docs/backbone.md)",
     ),
     EnvKnob(

@@ -1,44 +1,82 @@
-"""The `backbone` detector kind: ONE set of sequence-model weights shared
-by the fleet (`models/cohere2_moe.py`), a prefix cache row per sequence in
-a fixed-capacity `TreeArena`, and the two dispatches that use them — the
+"""The model-backed detector kinds (`backbone`, `backbone_kda`): ONE set of
+sequence-model weights shared by the fleet, a cache row per sequence in a
+fixed-capacity `TreeArena`, and the two dispatches that use them — the
 chunked prefill of a cold history ("fit") and the warm window program.
 
-`MultivariateJudge` owns one `BackboneDetector` under
-`ML_ALGORITHM=backbone` and calls `ensure` from its slow path and `score`
-from both. Each alias of a document is one sequence, keyed by its history's
-fit key, so a followed job of the same service finds its rows and prefills
-nothing. A row is device state only: it is never journalled, and a
-restarted worker prefills again (docs/backbone.md).
+The model is a module of `foremast_tpu/models/`, chosen by the model file's
+`model_type` (`MODELS`), and the detector reaches it through these names
+alone (docs/backbone.md, "The model interface"):
+
+    MODEL_TYPE, DEFAULT_MODEL_FILE, Config.from_dict(model file's dict)
+    prefill_seqs(cfg, ctx_cap), prefill_chunk_len(cfg, ctx_cap)
+    cache_template(cfg, ctx_cap)         one arena row's pytree of shapes
+    init_params(cfg)                     the share's weights, from the seed
+    series_scale(history), tokenize(values, scale, vocab)
+    prefill_chunk(cfg, params, state, rows, ids, start, n) -> (state, counts)
+    finish_rows(state, rows, n, last, scale) -> state
+    score_window(cfg, params, state, rows, ids, valid)
+        -> (scores, counts, dropped, *what the program itself counted a sequence)
+    WINDOW_COUNTERS, window_counters(cfg, ctx_cap, valid, *counted)
+        -> the names of the model's own counters, and what a dispatch adds to them
+
+`MultivariateJudge` owns one `BackboneDetector` a kind and calls `ensure`
+from its slow path and `score` from both. Each alias of a document is one
+sequence, keyed by its history's fit key, so a followed job of the same
+service finds its rows and prefills nothing. A row is device state only: it
+is never journalled, and a restarted worker prefills again
+(docs/backbone.md).
 """
 
 from __future__ import annotations
 
+import importlib
+import json
 import os
 
 import jax.numpy as jnp
 import numpy as np
 
 from foremast_tpu.engine.arena import TreeArena
-from foremast_tpu.models import cohere2_moe as model
 from foremast_tpu.observe.spans import note, span
 
-
-# sequences a prefill dispatch holds (each up to 2,048 tokens a chunk): what
-# the prefill's activations leave room for beside 13.9 GB of weights and cache
-PREFILL_SEQS = 2
+# model file's `model_type` -> the module that runs it
+MODELS = {
+    "cohere2_moe": "foremast_tpu.models.cohere2_moe",
+    "kimi_linear": "foremast_tpu.models.kimi_linear",
+}
 
 
 def _round_up(n: int, to: int) -> int:
     return -(-n // to) * to
 
 
+def load_model(model_file: str | None, model_types: tuple):
+    """(the model's module, its config) for `model_file`, or for the packaged
+    file of the first of `model_types` where none is named. A file whose
+    `model_type` is not one of `model_types` (what the kind takes) is an
+    error here, at load."""
+    if not model_file:
+        model_file = importlib.import_module(MODELS[model_types[0]]).DEFAULT_MODEL_FILE
+    with open(model_file, encoding="utf-8") as fh:
+        d = json.load(fh)
+    found = d.get("model_type")
+    if found not in model_types:
+        raise ValueError(
+            f"model file {model_file}: model_type {found!r} is not one this detector "
+            f"kind takes ({', '.join(model_types)})"
+        )
+    model = importlib.import_module(MODELS[found])
+    return model, model.Config.from_dict(d)
+
+
 class BackboneDetector:
     def __init__(self, model_file: str | None = None, context: int | None = None,
-                 rows: int | None = None):
+                 rows: int | None = None, model_types: tuple = ("cohere2_moe",)):
         env = os.environ.get
-        self.cfg = model.Cohere2MoeConfig.from_file(
-            model_file or env("FOREMAST_BACKBONE_MODEL") or None
+        self.model, self.cfg = load_model(
+            model_file or env("FOREMAST_BACKBONE_MODEL") or None, model_types
         )
+        model = self.model
         # history points a sequence keeps (the newest); all but the last
         # are cached, and the leaves are sized to that rounded up to a
         # multiple of 128 (8 for a toy context), never to a power of two
@@ -46,7 +84,7 @@ class BackboneDetector:
         cached = self.context - 1
         self.ctx_cap = _round_up(cached, 128 if cached > 128 else 8)
         self.capacity = int(rows or env("FOREMAST_BACKBONE_ROWS", "64"))
-        self.prefill_seqs = PREFILL_SEQS
+        self.prefill_seqs = model.prefill_seqs(self.cfg, self.ctx_cap)
         self.chunk = model.prefill_chunk_len(self.cfg, self.ctx_cap)
         self.arena = TreeArena(
             model.cache_template(self.cfg, self.ctx_cap), fixed_rows=self.capacity
@@ -58,14 +96,16 @@ class BackboneDetector:
         self.expert_tokens = np.zeros(held, np.int64)  # window program only
         self.prefill_tokens = 0
         self.window_tokens = 0
-        self.fused_attn_tokens = 0  # of window_tokens: dispatches that took the fused kernel
         self.dropped_tokens = 0
+        # the model's own counters of its window dispatches (Cohere2:
+        # `fused_attn_tokens`; Kimi-Linear: `latent_positions`, `state_bytes_read`)
+        self.model_counters = dict.fromkeys(model.WINDOW_COUNTERS, 0)
 
     @property
     def params(self):
         if self._params is None:
             with span("backbone.init_weights", device=True):
-                self._params = model.init_params(self.cfg)
+                self._params = self.model.init_params(self.cfg)
         return self._params
 
     def counters(self) -> dict:
@@ -73,7 +113,7 @@ class BackboneDetector:
         return {
             "prefill_tokens": self.prefill_tokens,
             "window_tokens": self.window_tokens,
-            "fused_attn_tokens": self.fused_attn_tokens,
+            **self.model_counters,
             "cache_rows_live": c["rows_live"],
             "cache_hits": c["hits"],
             "cache_misses": c["misses"],
@@ -89,7 +129,7 @@ class BackboneDetector:
         float32; those that have no row are tokenised and prefilled. At
         most `capacity` sequences a call. -> per sequence (scale, cached
         positions, last history id)."""
-        cfg, arena = self.cfg, self.arena
+        cfg, arena, model = self.cfg, self.arena, self.model
         hists = [np.asarray(h, np.float32)[-self.context:] for h in histories]
         assigned = arena.assign(keys, [])
         if assigned is None:
@@ -154,7 +194,7 @@ class BackboneDetector:
         points) of the sequences `keys`, whose rows have to be live. The
         batch is padded to a power of two, or to the cache's capacity, so
         that a claim's size cannot force a compile. -> [S, W] float32."""
-        cfg, arena = self.cfg, self.arena
+        cfg, arena, model = self.cfg, self.arena, self.model
         s, w = windows.shape
         with span("judge.tokenize", stage="pack", seqs=s, tokens=int(valid.sum())):
             ids = model.tokenize(windows, scales, cfg.share.vocab_rows_held)
@@ -179,15 +219,15 @@ class BackboneDetector:
             handed = (jnp.asarray(rows), jnp.asarray(ids), jnp.asarray(valid))
         with span("judge.score", stage="score", rows=sb, seqs=s,
                   tokens=int(valid.sum()), device=True):
-            scores, counts, dropped = model.score_window(
+            scores, counts, dropped, *counted = model.score_window(
                 cfg, self.params, arena.state, *handed
             )
         with span("judge.decode", stage="decode", rows=sb, device=True):
             scores = np.asarray(scores)[:s]
-        tokens = int(valid.sum())
-        self.window_tokens += tokens
-        if model.fused_window_attention(cfg, self.ctx_cap, w):
-            self.fused_attn_tokens += tokens
+        self.window_tokens += int(valid.sum())
+        counted = [np.asarray(c)[:s] for c in counted]
+        for name, n in model.window_counters(cfg, self.ctx_cap, valid[:s], *counted).items():
+            self.model_counters[name] += n
         self.expert_tokens += np.asarray(counts)
         self.dropped_tokens += int(dropped)
         return scores

@@ -19,7 +19,13 @@ UNIVARIATE = "univariate"
 
 # In the order `MultivariateJudge.judge` emits their verdicts.
 JOINT_KINDS: dict[str, JointKind] = {
-    kind.name: kind for kind in (BivariateKind(), LstmKind(), BackboneKind())
+    kind.name: kind
+    for kind in (
+        BivariateKind(),
+        LstmKind(),
+        BackboneKind("backbone", ("cohere2_moe",)),
+        BackboneKind("backbone_kda", ("kimi_linear",)),
+    )
 }
 
 
@@ -30,7 +36,8 @@ def select_mode(algorithm: str, n_metrics: int) -> str:
     The reference's metric-count rule (`docs/guides/design.md:57-93`) is
     the kinds' own `selectors`: `auto` -> bivariate at 2 metrics, lstm at
     3+; `bivariate_normal` -> bivariate at 2; `lstm_autoencoder` -> lstm
-    at 2+; `backbone` -> backbone at 1+. A count that fits no kind under
+    at 2+; `backbone` -> backbone at 1+; `backbone_kda` -> backbone_kda at
+    1+. A count that fits no kind under
     an explicit selector falls to the univariate judge."""
     for kind in JOINT_KINDS.values():
         if kind.takes(algorithm, n_metrics):

@@ -1,9 +1,12 @@
-"""Kind `backbone`: every alias of every job is one sequence of ONE shared
-sequence model (`engine/backbone.py`'s `BackboneDetector`: the weights, a
-prefix cache row a sequence, the chunked prefill and the window program;
-docs/backbone.md). The kind is what the judge, the pack and the worker see
-of it: which jobs it takes, its warm entry and gates, and the two paths
-around the detector."""
+"""The model-backed kinds, `backbone` and `backbone_kda`: every alias of
+every job is one sequence of ONE shared sequence model
+(`engine/backbone.py`'s `BackboneDetector`: the weights, a cache row a
+sequence, the chunked prefill and the window program; docs/backbone.md).
+The two are one class instantiated twice: a kind's name is its
+`ML_ALGORITHM` value, and `model_types` the model files it takes (`backbone`:
+`cohere2_moe`; `backbone_kda`: `kimi_linear`). The kind is what the judge,
+the pack and the worker see of it: which jobs it takes, its warm entry and
+gates, and the two paths around the detector."""
 
 from __future__ import annotations
 
@@ -18,28 +21,33 @@ class _State:
     """What one judge keeps of the kind: the detector, and for each of its
     sequence keys the joint cache key of the document that holds it."""
 
-    def __init__(self):
+    def __init__(self, model_types: tuple):
         from foremast_tpu.engine.backbone import BackboneDetector
 
-        self.detector = BackboneDetector()
+        self.detector = BackboneDetector(model_types=model_types)
         self.doc_of: dict = {}
 
 
 class BackboneKind(JointKind):
-    name = "backbone"
-    selectors = {"backbone": (1, None)}
     # nothing to persist: the fitted state is the prefix cache's rows, tens
     # of MB a sequence on the device, and a warm entry without its rows is
     # worth nothing. A restarted worker prefills again.
     persisted = False
     keeps_counters = True
 
+    def __init__(self, name: str, model_types: tuple):
+        self.name = name
+        self.selectors = {name: (1, None)}
+        # the model files' `model_type`s this kind takes; the first one's
+        # packaged file is the default
+        self.model_types = tuple(model_types)
+
     def state(self, judge, build: bool = True) -> _State | None:
         """The judge's `_State`, built at its first use: the weights are
         gigabytes. Its cache is counted with the joint arenas."""
         st = judge.kind_state.get(self.name)
         if st is None and build:
-            st = judge.kind_state[self.name] = _State()
+            st = judge.kind_state[self.name] = _State(self.model_types)
             judge._joint_arenas[(self.name, 0)] = st.detector.arena
         return st
 
@@ -49,7 +57,7 @@ class BackboneKind(JointKind):
 
     def observe(self, metrics, counters: dict) -> None:
         if hasattr(metrics, "observe_backbone"):
-            metrics.observe_backbone(counters)
+            metrics.observe_backbone(self.name, counters)
 
     def cache_key(self, config, app, aliases, hist_keys, tc) -> tuple:
         # the history's identity is the prefix cache's key

@@ -384,13 +384,23 @@ class MultivariateJudge:
 
     # -- what the benchmark and the tests reach by name --------------------
 
+    def _backbone_kind(self):
+        """The model-backed kind `ML_ALGORITHM` selects (`backbone`,
+        `backbone_kda`): the one kind under it that keeps a detector."""
+        for kind in kinds_under(self.config.algorithm):
+            if kind.keeps_counters:
+                return kind
+        raise ValueError(
+            f"ML_ALGORITHM={self.config.algorithm!r} selects no model-backed kind"
+        )
+
     @property
     def backbone(self):
-        """The process's one `BackboneDetector` (kind `backbone`)."""
-        return JOINT_KINDS["backbone"].state(self).detector
+        """The process's one `BackboneDetector`, of the kind selected."""
+        return self._backbone_kind().state(self).detector
 
     def backbone_counters(self) -> dict | None:
-        return JOINT_KINDS["backbone"].counters(self)
+        return self._backbone_kind().counters(self)
 
     def _bi_template(self):
         return JOINT_KINDS["bivariate"].template()

@@ -76,7 +76,11 @@ DEFAULT_MODEL_FILE = os.path.join(
     "configs",
     "command-a-plus-05-2026.json",
 )
+MODEL_TYPE = "cohere2_moe"  # the model files this module runs (`engine/backbone.py`)
 PREFILL_CHUNK = 2048  # tokens a sequence a prefill dispatch, at most
+# sequences a prefill dispatch holds (each up to PREFILL_CHUNK tokens): what
+# the prefill's activations leave room for beside 13.9 GB of weights and cache
+PREFILL_SEQS = 2
 TOKEN_RANGE = 15.0  # a scaled value in [-15, 15) maps onto the vocabulary
 
 
@@ -197,6 +201,9 @@ class Cohere2MoeConfig:
         return jnp.dtype(self.compute_dtype)
 
 
+Config = Cohere2MoeConfig  # the name `engine/backbone.py` loads a model file through
+
+
 # -- weights -----------------------------------------------------------------
 
 
@@ -286,6 +293,11 @@ def prefill_chunk_len(cfg: Cohere2MoeConfig, ctx_cap: int) -> int:
     return chunk
 
 
+def prefill_seqs(cfg: Cohere2MoeConfig, ctx_cap: int) -> int:
+    """Sequences a prefill dispatch holds."""
+    return PREFILL_SEQS
+
+
 def fused_window_attention(cfg: Cohere2MoeConfig, ctx_cap: int, tokens: int) -> bool:
     """Whether `score_window` over windows of `tokens` against rows of
     `ctx_cap` positions attends through the fused kernel
@@ -295,6 +307,17 @@ def fused_window_attention(cfg: Cohere2MoeConfig, ctx_cap: int, tokens: int) -> 
     return fused_applies(
         cfg.head_dim, cfg.dtype.itemsize, tokens, (ctx_cap, ring_size(cfg, ctx_cap))
     )
+
+
+WINDOW_COUNTERS = ("fused_attn_tokens",)
+
+
+def window_counters(cfg: Cohere2MoeConfig, ctx_cap: int, valid) -> dict:
+    """What one window dispatch adds to the detector's counters beside the
+    tokens it scored (`valid` [S, W]): the tokens whose attention took the
+    fused kernel."""
+    fused = fused_window_attention(cfg, ctx_cap, valid.shape[1])
+    return {"fused_attn_tokens": int(valid.sum()) if fused else 0}
 
 
 def cache_template(cfg: Cohere2MoeConfig, ctx_cap: int) -> dict:
@@ -364,17 +387,22 @@ def attend(q, pos_q, kn, vn, pos_n, valid_n, kc, vc, pos_c, valid_c, group: int,
     return out.reshape(hkv, group, tq, d).transpose(2, 0, 1, 3).reshape(tq, hq * d).astype(q.dtype)
 
 
-def route(cfg: Cohere2MoeConfig, router, xn):
-    """Sigmoid selection over ALL experts: (top-k expert ids [T, k], their
-    weights s_e / sum_E s [T, k]) in float32."""
+def router_scores(router, xn):
+    """sigmoid(xn W_r) over ALL experts [T, experts], float32."""
     logits = jnp.dot(
         xn.astype(jnp.float32), router.astype(jnp.float32), precision=lax.Precision.HIGHEST
     )
-    top_s, top_i = lax.top_k(jax.nn.sigmoid(logits), cfg.num_experts_per_tok)
+    return jax.nn.sigmoid(logits)
+
+
+def route(cfg: Cohere2MoeConfig, lp: dict, xn):
+    """Sigmoid selection over ALL experts: (top-k expert ids [T, k], their
+    weights s_e / sum_E s [T, k]) in float32."""
+    top_s, top_i = lax.top_k(router_scores(lp["router"], xn), cfg.num_experts_per_tok)
     return top_i, top_s / top_s.sum(axis=-1, keepdims=True)
 
 
-def _block_rows(cfg: Cohere2MoeConfig, tokens: int) -> int:
+def _block_rows(cfg, tokens: int) -> int:
     """Rows of one block of the grouped product: about what one expert is
     assigned when routing is even, a power of two in [8, 512]."""
     even = tokens * cfg.num_experts_per_tok // cfg.num_experts
@@ -384,10 +412,14 @@ def _block_rows(cfg: Cohere2MoeConfig, tokens: int) -> int:
     return rows
 
 
-def routed_experts(cfg: Cohere2MoeConfig, lp: dict, xn, valid):
+def routed_experts(cfg, lp: dict, xn, valid, route=route):
     """The held experts' part of the FFN for tokens xn [T, h] (`valid` [T]:
     padding is routed nowhere) -> (y [T, h] float32, assignments a held
     expert received [experts_held] int32, assignments multiplied () int32).
+    `cfg` is any model's config that says how many experts a token takes,
+    which are held here and of how many (`num_experts_per_tok`,
+    `num_experts`, `share.experts_held`, `expert_offset`); `route(cfg, lp,
+    xn)` is the model's router: expert ids and weights [T, k].
 
     The T x k assignments are sorted by expert (those of experts held
     elsewhere, and padding, last), and the sorted run is cut into blocks of
@@ -400,7 +432,7 @@ def routed_experts(cfg: Cohere2MoeConfig, lp: dict, xn, valid):
     k, held = cfg.num_experts_per_tok, cfg.share.experts_held
     rows = _block_rows(cfg, t)
     with jax.named_scope("moe_route"):
-        top_i, top_w = route(cfg, lp["router"], xn)
+        top_i, top_w = route(cfg, lp, xn)
         local = top_i - cfg.expert_offset
         mine = (local >= 0) & (local < held) & valid[:, None]
         key = jnp.where(mine, local, held).reshape(t * k)

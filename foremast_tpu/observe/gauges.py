@@ -301,56 +301,78 @@ class WorkerMetrics:
         self.fast_docs = Counter(
             "foremast_worker_fast_docs_total",
             "documents scored on the columnar fast path, by model kind "
-            "(univariate / bivariate / lstm / backbone)",
+            "(univariate / bivariate / lstm / backbone / backbone_kda)",
             ["kind"],
             registry=reg,
         )
-        # the shared sequence backbone (ISSUE 27, engine/backbone.py):
-        # tokens prefilled and scored, its prefix cache, the load of the
+        # the model-backed kinds (`backbone`, `backbone_kda`;
+        # engine/backbone.py), by kind: tokens prefilled and scored, the
+        # cache, what the window dispatches read of it, the load of the
         # experts this process holds
         self.backbone_prefill_tokens = Counter(
             "foremast_backbone_prefill_tokens_total",
-            "history tokens prefilled into the backbone's prefix cache",
+            "history tokens prefilled into the backbone's cache",
+            ["kind"],
             registry=reg,
         )
         self.backbone_window_tokens = Counter(
             "foremast_backbone_window_tokens_total",
             "current-window tokens the backbone's window program scored",
+            ["kind"],
             registry=reg,
         )
         self.backbone_fused_attn_tokens = Counter(
             "foremast_backbone_fused_attn_tokens_total",
             "of the window tokens, those of dispatches whose attention took "
             "the fused TPU kernel",
+            ["kind"],
+            registry=reg,
+        )
+        self.backbone_latent_positions = Counter(
+            "foremast_backbone_latent_positions_total",
+            "positions the window tokens' latent attention attended to "
+            "(a linear-attention backbone's full-attention layers)",
+            ["kind"],
+            registry=reg,
+        )
+        self.backbone_state_bytes_read = Counter(
+            "foremast_backbone_state_bytes_read_total",
+            "bytes of recurrent state and convolution tails the window "
+            "dispatches read from the cache rows",
+            ["kind"],
             registry=reg,
         )
         self.backbone_cache_rows = Gauge(
             "foremast_backbone_cache_rows_live",
             "sequences whose prefix the backbone's cache holds",
+            ["kind"],
             registry=reg,
         )
         self.backbone_cache_hits = Counter(
             "foremast_backbone_cache_hits_total",
             "sequences that found their cached prefix (a followed job of "
             "the same service is a hit)",
+            ["kind"],
             registry=reg,
         )
         self.backbone_cache_misses = Counter(
             "foremast_backbone_cache_misses_total",
             "sequences prefilled because the cache held no row for them",
+            ["kind"],
             registry=reg,
         )
         self.backbone_expert_tokens = Counter(
             "foremast_backbone_expert_tokens_total",
             "token assignments each held expert received from the window "
             "program, all layers (max over mean is the straggler)",
-            ["expert"],
+            ["kind", "expert"],
             registry=reg,
         )
         self.backbone_dropped_tokens = Counter(
             "foremast_backbone_dropped_tokens_total",
             "assignments routed to a held expert and not computed: the "
             "expert layer has no capacity factor, so this stays 0",
+            ["kind"],
             registry=reg,
         )
         self._backbone_last: dict = {}
@@ -589,29 +611,36 @@ class WorkerMetrics:
             self._arena_last[event] = cur
 
 
-    def observe_backbone(self, counters: dict) -> None:
-        """Feed cumulative `MultivariateJudge.backbone_counters()`;
-        deltas are exported, as in `observe_arena`."""
-        last = self._backbone_last
+    def observe_backbone(self, kind: str, counters: dict) -> None:
+        """Feed a model-backed kind's cumulative counters
+        (`BackboneDetector.counters()`); deltas are exported, as in
+        `observe_arena`. A counter the kind's model does not keep
+        (`fused_attn_tokens`, `latent_positions`, `state_bytes_read`)
+        exports nothing."""
+        last = self._backbone_last.setdefault(kind, {})
         flat = {
             "prefill_tokens": self.backbone_prefill_tokens,
             "window_tokens": self.backbone_window_tokens,
             "fused_attn_tokens": self.backbone_fused_attn_tokens,
+            "latent_positions": self.backbone_latent_positions,
+            "state_bytes_read": self.backbone_state_bytes_read,
             "cache_hits": self.backbone_cache_hits,
             "cache_misses": self.backbone_cache_misses,
             "dropped_tokens": self.backbone_dropped_tokens,
         }
         for key, family in flat.items():
-            delta = counters.get(key, 0) - last.get(key, 0)
+            if key not in counters:
+                continue
+            delta = counters[key] - last.get(key, 0)
             if delta > 0:
-                family.inc(delta)
-            last[key] = counters.get(key, 0)
-        self.backbone_cache_rows.set(counters.get("cache_rows_live", 0))
+                family.labels(kind=kind).inc(delta)
+            last[key] = counters[key]
+        self.backbone_cache_rows.labels(kind=kind).set(counters.get("cache_rows_live", 0))
         seen = last.get("expert_tokens") or []
         for e, total in enumerate(counters.get("expert_tokens", [])):
             delta = total - (seen[e] if e < len(seen) else 0)
             if delta > 0:
-                self.backbone_expert_tokens.labels(expert=str(e)).inc(delta)
+                self.backbone_expert_tokens.labels(kind=kind, expert=str(e)).inc(delta)
         last["expert_tokens"] = list(counters.get("expert_tokens", []))
 
 

@@ -36,14 +36,16 @@ ALLOWED_LABELS: dict[str, frozenset[str]] = {
     "foremast_worker_fast_docs": frozenset({"kind"}),
     # the shared sequence backbone (ISSUE 27, observe/gauges.py
     # WorkerMetrics.observe_backbone)
-    "foremast_backbone_prefill_tokens": frozenset(),
-    "foremast_backbone_window_tokens": frozenset(),
-    "foremast_backbone_fused_attn_tokens": frozenset(),
-    "foremast_backbone_cache_rows_live": frozenset(),
-    "foremast_backbone_cache_hits": frozenset(),
-    "foremast_backbone_cache_misses": frozenset(),
-    "foremast_backbone_expert_tokens": frozenset({"expert"}),
-    "foremast_backbone_dropped_tokens": frozenset(),
+    "foremast_backbone_prefill_tokens": frozenset({"kind"}),
+    "foremast_backbone_window_tokens": frozenset({"kind"}),
+    "foremast_backbone_fused_attn_tokens": frozenset({"kind"}),
+    "foremast_backbone_latent_positions": frozenset({"kind"}),
+    "foremast_backbone_state_bytes_read": frozenset({"kind"}),
+    "foremast_backbone_cache_rows_live": frozenset({"kind"}),
+    "foremast_backbone_cache_hits": frozenset({"kind"}),
+    "foremast_backbone_cache_misses": frozenset({"kind"}),
+    "foremast_backbone_expert_tokens": frozenset({"kind", "expert"}),
+    "foremast_backbone_dropped_tokens": frozenset({"kind"}),
     # chunk-pipeline occupancy (observe/gauges.py WorkerMetrics), by
     # path since ISSUE 15: "slow" = the cold chunk pipeline (PR 3),
     # "warm" = the sliced sweep's claim-pool pipeline
@@ -140,20 +142,31 @@ FAMILY_DOCS: dict[str, str] = {
     ),
     "foremast_worker_fast_docs": (
         "documents scored on the columnar fast path, by model kind "
-        "(univariate/bivariate/lstm/backbone, plus `baseline` — the "
+        "(univariate/bivariate/lstm/backbone/backbone_kda, plus `baseline` — the "
         "canary bucket: baseline-carrying univariate docs judged through "
         "the pairwise-active columnar program)"
     ),
     "foremast_backbone_prefill_tokens": (
-        "history tokens prefilled into the backbone's prefix cache "
-        "(`ML_ALGORITHM=backbone`; docs/backbone.md)"
+        "history tokens prefilled into the backbone's cache, by model-backed "
+        "kind (`ML_ALGORITHM=backbone` / `backbone_kda`; docs/backbone.md)"
     ),
     "foremast_backbone_window_tokens": (
         "current-window tokens the backbone's window program scored"
     ),
     "foremast_backbone_fused_attn_tokens": (
-        "of those, tokens of dispatches whose attention took the fused "
-        "TPU kernel; 0 off a TPU or at widths the kernel does not tile"
+        "kind `backbone` only: of those, tokens of dispatches whose "
+        "attention took the fused TPU kernel; 0 off a TPU or at widths the "
+        "kernel does not tile"
+    ),
+    "foremast_backbone_latent_positions": (
+        "positions the window tokens' latent attention attended to, cached "
+        "and the window's own, as the window program counts them (kind "
+        "`backbone_kda`); over the window tokens it is the context a token "
+        "really saw"
+    ),
+    "foremast_backbone_state_bytes_read": (
+        "bytes of recurrent state and convolution tails the window "
+        "dispatches read from the cache rows (kind `backbone_kda`)"
     ),
     "foremast_backbone_cache_rows_live": (
         "sequences whose prefix the backbone's cache holds "
@@ -413,12 +426,15 @@ def default_registry_families():
     metrics.tick_seconds.observe(0.01)
     for kind in fast_kinds():
         metrics.fast_docs.labels(kind=kind).inc()
-    metrics.observe_backbone({
-        "prefill_tokens": 1, "window_tokens": 1, "fused_attn_tokens": 1,
-        "cache_rows_live": 1,
+    shared = {
+        "prefill_tokens": 1, "window_tokens": 1, "cache_rows_live": 1,
         "cache_hits": 1, "cache_misses": 1, "dropped_tokens": 0,
         "expert_tokens": [1, 1],
-    })
+    }
+    metrics.observe_backbone("backbone", {**shared, "fused_attn_tokens": 1})
+    metrics.observe_backbone(
+        "backbone_kda", {**shared, "latent_positions": 1, "state_bytes_read": 1}
+    )
     for path in ("micro", "sweep"):
         metrics.verdict_latency.labels(path=path, tenant="default").observe(
             0.1

@@ -1,8 +1,11 @@
-"""Kind `backbone` through `BrainWorker.tick()` against the plain reference
-(ISSUE 27, Tentpole section 3, case (a)): claim, admit, fetch, pack, cold
-prefill, warm window, decide, write-back, with no side script. A cold tick,
-two warm ticks and a followed job, on the small model of
-tests/test_backbone_model.py (context 20 > sliding window 8).
+"""The model-backed kinds through `BrainWorker.tick()` against their plain
+references (ISSUE 27, Tentpole section 3, case (a); ISSUE 31): claim, admit,
+fetch, pack, cold prefill, warm window, decide, write-back, with no side
+script. A cold tick, two warm ticks and a followed job, on the small models
+of tests/test_backbone_model.py (kind `backbone`: Cohere2-MoE, context 20 >
+sliding window 8) and tests/test_kimi_linear_model.py (kind `backbone_kda`:
+Kimi-Linear). The cases here are the detector's, so they run over both
+models; what only one model has is in that model's own files.
 
 Tolerance. The model computes in float32 here, so the program's scores and
 the reference's differ by the order of sums alone (2e-5 nats, as in
@@ -27,8 +30,15 @@ from foremast_tpu.jobs import (
 from foremast_tpu.jobs.models import Document
 from foremast_tpu.jobs.store import InMemoryStore
 from foremast_tpu.metrics.source import MetricSource
-from foremast_tpu.models import cohere2_moe_reference as ref
+from foremast_tpu.models import cohere2_moe_reference, kimi_linear_reference
 from tests.test_backbone_model import tiny
+from tests.test_kimi_linear_model import tiny as tiny_kda
+
+# kind -> (the small model file's dict, the plain reference)
+MODELS = {
+    "backbone": (tiny, cohere2_moe_reference),
+    "backbone_kda": (tiny_kda, kimi_linear_reference),
+}
 
 NOW = 1_760_000_000.0
 CONTEXT, WINDOW, TICKS = 20, 6, 3
@@ -96,18 +106,20 @@ class Fleet:
         return followed
 
 
-@pytest.fixture
-def model_file(tmp_path, monkeypatch):
-    d = tiny("float32")
-    path = tmp_path / "tiny-backbone.json"
+@pytest.fixture(params=sorted(MODELS))
+def model_file(request, tmp_path, monkeypatch):
+    """(kind, the model file's dict, its reference), the file in place."""
+    kind = request.param
+    d = MODELS[kind][0]("float32")
+    path = tmp_path / f"tiny-{kind}.json"
     path.write_text(json.dumps(d))
     monkeypatch.setenv("FOREMAST_BACKBONE_MODEL", str(path))
     monkeypatch.setenv("FOREMAST_BACKBONE_CONTEXT", str(CONTEXT))
     monkeypatch.setenv("FOREMAST_BACKBONE_ROWS", "8")
-    return d
+    return kind, d, MODELS[kind][1]
 
 
-def reference_scores(d, fleet):
+def reference_scores(d, fleet, ref):
     """{(tick, service, alias): scores [WINDOW]} by one full forward each."""
     return {
         (k, s, a): np.asarray(ref.window_scores(d, d["share"], fleet.hist[(s, a)], w)[0])
@@ -124,11 +136,11 @@ def gap_threshold(scores: dict) -> float:
 
 
 def test_cold_tick_two_warm_ticks_and_a_followed_job_match_the_reference(model_file):
-    d = model_file
+    kind, d, ref = model_file
     fleet = Fleet()
-    want = reference_scores(d, fleet)
+    want = reference_scores(d, fleet, ref)
     thr = gap_threshold(want)
-    cfg = BrainConfig(algorithm="backbone", max_cache_size=64)
+    cfg = BrainConfig(algorithm=kind, max_cache_size=64)
     cfg = dataclasses.replace(cfg, anomaly=dataclasses.replace(cfg.anomaly, threshold=thr))
     worker = BrainWorker(fleet.store, fleet.source, config=cfg, claim_limit=16, worker_id="bb")
     det = worker._mvj.backbone
@@ -141,16 +153,21 @@ def test_cold_tick_two_warm_ticks_and_a_followed_job_match_the_reference(model_f
         if k == 0:
             # cold: every sequence prefilled once, all but its last point
             assert after["prefill_tokens"] == 7 * (CONTEXT - 1)
-            assert after["cache_misses"] == 7 and worker._fast_kinds["backbone"] == 0
+            assert after["cache_misses"] == 7 and worker._fast_kinds[kind] == 0
         else:
             # warm, a followed job included: nothing prefilled, every row found
             assert after["prefill_tokens"] == before["prefill_tokens"]
             assert after["cache_misses"] == before["cache_misses"]
             assert after["cache_hits"] - before["cache_hits"] == 7
-            assert worker._fast_kinds["backbone"] == k * len(SERVICES)
+            assert worker._fast_kinds[kind] == k * len(SERVICES)
         assert after["window_tokens"] - before["window_tokens"] == 7 * WINDOW
         assert after["dropped_tokens"] == 0 and after["cache_rows_live"] == 7
-        assert after["fused_attn_tokens"] == 0  # off a TPU every dispatch attends through `attend`
+        if kind == "backbone":
+            assert after["fused_attn_tokens"] == 0  # off a TPU every dispatch attends through `attend`
+        else:
+            # every window token saw the 19 cached positions and its own window up to itself
+            seen = after["latent_positions"] - before["latent_positions"]
+            assert seen == 7 * (WINDOW * (CONTEXT - 1) + WINDOW * (WINDOW + 1) // 2)
         for s, aliases in SERVICES.items():
             doc = fleet.store._docs[f"job-{s}-{fleet.gen[s]}"]
             over = np.stack([want[(k, s, a)] > thr for a in aliases]).any(axis=0)
@@ -175,13 +192,13 @@ def test_cold_tick_two_warm_ticks_and_a_followed_job_match_the_reference(model_f
 def test_program_scores_match_the_reference_through_the_judge(model_file):
     """The scores themselves, not only the flags they give: the detector's
     prefill and window dispatches on the fleet's own sequences."""
-    d = model_file
+    kind, d, ref = model_file
     fleet = Fleet()
-    want = reference_scores(d, fleet)
+    want = reference_scores(d, fleet, ref)
     from foremast_tpu.engine.backbone import BackboneDetector
 
-    det = BackboneDetector()
-    keys = [("backbone", s, a, "h") for s, al in SERVICES.items() for a in al]
+    det = BackboneDetector(model_types=(d["model_type"],))
+    keys = [(kind, s, a, "h") for s, al in SERVICES.items() for a in al]
     hists = [fleet.hist[k[1:3]] for k in keys]
     entries = det.ensure(keys, hists)
     scales = np.array([e[0] for e in entries], np.float32)
@@ -200,7 +217,7 @@ def test_a_recycled_row_sends_its_document_back_to_the_prefill(model_file, monke
     went finds no warm entry and is prefilled again: slower, never wrong."""
     monkeypatch.setenv("FOREMAST_BACKBONE_ROWS", "4")
     fleet = Fleet()
-    cfg = BrainConfig(algorithm="backbone", max_cache_size=64)
+    cfg = BrainConfig(algorithm=model_file[0], max_cache_size=64)
     cfg = dataclasses.replace(cfg, anomaly=dataclasses.replace(cfg.anomaly, threshold=1e9))
     worker = BrainWorker(fleet.store, fleet.source, config=cfg, claim_limit=16, worker_id="bb")
     det = worker._mvj.backbone
@@ -213,6 +230,7 @@ def test_a_recycled_row_sends_its_document_back_to_the_prefill(model_file, monke
 
 def test_an_unknown_algorithm_is_an_error_at_load():
     assert BrainConfig.from_env({"ML_ALGORITHM": "backbone"}).algorithm == "backbone"
+    assert BrainConfig.from_env({"ML_ALGORITHM": "backbone_kda"}).algorithm == "backbone_kda"
     assert BrainConfig.from_env({"ML_ALGORITHM": "ewma"}).algorithm == "ewma"
     with pytest.raises(ValueError, match="unknown ML_ALGORITHM 'backbon'"):
         BrainConfig.from_env({"ML_ALGORITHM": "backbon"})

@@ -363,6 +363,7 @@ def test_nothing_cached_is_not_admissible():
         ("bivariate_normal", ("univariate", "bivariate", "univariate")),
         ("lstm_autoencoder", ("univariate", "lstm", "lstm")),
         ("backbone", ("backbone", "backbone", "backbone")),
+        ("backbone_kda", ("backbone_kda", "backbone_kda", "backbone_kda")),
         ("moving_average_all", ("univariate", "univariate", "univariate")),
     ],
 )
@@ -378,7 +379,7 @@ def test_fast_kinds_are_what_the_lint_exercises_and_the_docs_list():
     from foremast_tpu.observe.metrics_lint import default_registry_families
 
     assert fast_kinds() == (
-        "univariate", "baseline", "bivariate", "lstm", "backbone",
+        "univariate", "baseline", "bivariate", "lstm", "backbone", "backbone_kda",
     )
     worker = BrainWorker(InMemoryStore(), _Source(), config=BrainConfig())
     assert tuple(worker._fast_kinds) == fast_kinds()
@@ -401,7 +402,7 @@ def test_fast_kinds_are_what_the_lint_exercises_and_the_docs_list():
     ]
     assert len(rows) == 2  # the generated index and the operator's table
     for row in rows:
-        words = set(re.findall(r"[a-z]+", row.split("|")[3]))
+        words = set(re.findall(r"[a-z_]+", row.split("|")[3]))
         assert set(fast_kinds()) <= words, row
 
 
@@ -419,5 +420,5 @@ def test_config_lists_every_selector_the_table_answers_to():
     assert [k.name for k in JOINT_KINDS.values() if k.needs_gaps] == ["lstm"]
     assert [k.name for k in JOINT_KINDS.values() if k.pins_bucket] == ["lstm"]
     assert [k.name for k in JOINT_KINDS.values() if not k.persisted] == [
-        "backbone"
+        "backbone", "backbone_kda"
     ]
