@@ -26,6 +26,15 @@ eps `rms_norm_eps`, statistics in float32; sequential residuals):
     a pair (t, i) of different sub-chunks goes through the later one's
     anchor a (the sum up to its first token), e^(G_t - a) e^(a - G_i), both
     exponents <= 0; a pair inside a sub-chunk takes the difference itself.
+    On a TPU the WINDOW program runs the same algebra as one Pallas kernel
+    a layer (`models/kimi_kda.py:fused_kda_rows`): a grid step is a sequence
+    and its heads, each head's S_0 is read from the arena leaf where it
+    lies, everything between the inputs and O stays in VMEM, and S_C, which
+    the window program throws away, is not computed. `fused_window_kda` is
+    the test (the backend, no `write_at`, the tokens fit one chunk, d a
+    whole lane tile); no option selects it. `kda_chunks` runs everywhere
+    else: every CPU run, narrower heads, and the prefill always (chunks
+    under a scan, the state carried and written back).
   * MLA, latent attention (`full_attn_layers`; `q_lora_rank` null): q = x
     W_q -> per head [q_n; q_r]; [c; k_r] = x W_kva, c <- RMSNorm(c); [k_n,h;
     v_h] = c W_kvb,h; score (q_n.k_n,h + q_r.k_r) / sqrt(nope + rope), causal
@@ -59,9 +68,10 @@ program also returns the positions its queries attended to, counted from
 the masks the softmax was taken under (`latent_positions`).
 
 Precision: weights, activations, conv tails and latents in `compute_dtype`
-(bfloat16); the KDA state, its chunk algebra (float32 operands at `highest`:
-half a percent of a token's operations), accumulation, softmax, norms,
-router, decay sums and log-softmax in float32. The plain reference is
+(bfloat16); the KDA state, its chunk algebra (float32 operands at `highest`,
+in `kda_chunks` and in the kernel alike: half a percent of a token's
+operations; the two agree to 2e-6), accumulation, softmax, norms, router,
+decay sums and log-softmax in float32. The plain reference is
 `models/kimi_linear_reference.py`.
 """
 
@@ -87,6 +97,7 @@ from foremast_tpu.models.cohere2_moe import (  # the code both backbones share
     tensor,
     tokenize,
 )
+from foremast_tpu.models.kimi_kda import KDA_CHUNK, KDA_SUB, fused_applies, fused_kda_rows
 
 __all__ = [
     "Config", "MODEL_TYPE", "cache_template", "finish_rows", "init_params", "prefill_chunk",
@@ -101,8 +112,6 @@ DEFAULT_MODEL_FILE = os.path.join(
 )
 PREFILL_CHUNK = 2560  # tokens a sequence a prefill dispatch, at most
 PREFILL_SEQS = 2  # sequences a prefill dispatch: ~5,000 tokens, ~160 assignments a held expert
-KDA_CHUNK = 64  # tokens a chunk of the chunkwise recurrence
-KDA_SUB = 16  # tokens a sub-chunk: pairs inside one take their difference itself
 Q_BLOCK = 32  # query tokens a block of a prefill chunk's latent attention
 HEAD_BLOCK = 512  # tokens a block of the head: a block's logits are reduced to scores
 MASKED = -1e30
@@ -378,12 +387,24 @@ def state_bytes(cfg: KimiLinearConfig) -> int:
     return cfg.n_kda * (cfg.kda_heads * d * d * 4 + tail)
 
 
-WINDOW_COUNTERS = ("latent_positions", "state_bytes_read")
+def fused_window_kda(cfg: KimiLinearConfig, tokens: int, write_at=None) -> bool:
+    """Whether a dispatch of `tokens` a sequence runs its KDA layers' chunk
+    algebra through the fused kernel (`kimi_kda.fused_kda_rows`) or through
+    `kda_chunks`: decided by the backend, the program's kind (`write_at`: a
+    prefill chunk's, which writes the state back) and the shapes alone,
+    once for the whole program. The detector counts `fused_kda_tokens` by
+    the same call."""
+    return fused_applies(cfg.kda_head_dim, tokens, write_at)
+
+
+WINDOW_COUNTERS = ("latent_positions", "state_bytes_read", "fused_kda_tokens")
 
 
 def window_counters(cfg: KimiLinearConfig, ctx_cap: int, valid, attended) -> dict:
     """What one window dispatch adds to the detector's counters beside the
-    tokens it scored (`valid` [S, W] real points): `latent_positions`, the
+    tokens it scored (`valid` [S, W] real points): `fused_kda_tokens`, the
+    tokens of a dispatch whose KDA layers took the fused kernel;
+    `latent_positions`, the
     positions a MLA layer's queries attended to (every cached one, and the
     window's own up to the token), summed over tokens: `attended` [S], what
     `score_window` itself counted under its softmax's masks over all its
@@ -391,10 +412,11 @@ def window_counters(cfg: KimiLinearConfig, ctx_cap: int, valid, attended) -> dic
     `state_bytes_read`, the float32 state and convolution tails the
     dispatch's real sequences own: the request's size, not read off the
     program."""
-    real = int(np.asarray(valid, bool).any(axis=1).sum())
+    valid = np.asarray(valid, bool)
     return {
         "latent_positions": int(np.asarray(attended, np.int64).sum()) // max(cfg.n_mla, 1),
-        "state_bytes_read": real * state_bytes(cfg),
+        "state_bytes_read": int(valid.any(axis=1).sum()) * state_bytes(cfg),
+        "fused_kda_tokens": int(valid.sum()) if fused_window_kda(cfg, valid.shape[1]) else 0,
     }
 
 
@@ -527,11 +549,14 @@ def kda_chunks(q, k, v, g, beta, s0, chunk: int = KDA_CHUNK, sub: int = KDA_SUB)
     return o, s_end
 
 
-def kda_mix(cfg: KimiLinearConfig, lp: dict, xn, valid, tail, s0):
+def kda_mix(cfg: KimiLinearConfig, lp: dict, xn, valid, tail, s0, in_rows=None):
     """One KDA layer's mixer over xn [B, T, h] (`valid` [B, T]) as the
     continuation of the carried convolution tail [B, taps - 1, 3 H d] and
     state s0 [B, H, d, d] -> (Mix [B, T, h] float32, the state after the
-    valid tokens, [tail; the chunk's projected inputs] [B, taps - 1 + T, 3 H d])."""
+    valid tokens, [tail; the chunk's projected inputs] [B, taps - 1 + T, 3 H d]).
+    With `in_rows` (the arena leaf `S`, rows [B], the layer's slot: a window
+    dispatch where `fused_window_kda` holds) there is no s0: the fused
+    kernel reads each state in its row, and no state comes back."""
     b, t, _ = xn.shape
     heads, d = cfg.kda_heads, cfg.kda_head_dim
     hd = heads * d
@@ -542,18 +567,44 @@ def kda_mix(cfg: KimiLinearConfig, lp: dict, xn, valid, tail, s0):
         conv = sum(
             taps[j] * carried[:, j : j + t].astype(jnp.float32) for j in range(cfg.conv_taps)
         )
-        act = jax.nn.silu(conv).reshape(b, t, 3, heads, d)
+        act = jax.nn.silu(conv)
+
+    def gates(split_heads: bool):
+        """(g, beta, gate); g [B, T, H, d] or, for the kernel, [B, T, H d]."""
+        dt = _dot(_dot(xn, lp["f_down"]).astype(xn.dtype), lp["f_up"]) + lp["dt_bias"]
+        # foremast: ignore[jit-hygiene] — the program's path, a Python value
+        if split_heads:
+            g = -jnp.exp(lp["a_log"])[:, None] * jax.nn.softplus(dt).reshape(b, t, heads, d)
+            g = jnp.where(valid[..., None, None], g, 0.0)
+        else:
+            g = -jnp.repeat(jnp.exp(lp["a_log"]), d) * jax.nn.softplus(dt)
+            g = jnp.where(valid[..., None], g, 0.0)
+        beta = jnp.where(valid[..., None], jax.nn.sigmoid(_dot(xn, lp["beta"])), 0.0)
+        gate = jax.nn.sigmoid(_dot(_dot(xn, lp["g_down"]).astype(xn.dtype), lp["g_up"]))
+        return g, beta, gate
+
+    # foremast: ignore[jit-hygiene] — the program's path, a Python value
+    if in_rows is not None:
+        # the kernel's way: no axis of heads is ever split off (on the chip
+        # [B, T, H d] -> [B, T, H, d] is a copy into another tiling), and the
+        # per-head norms of q, k and o are taken inside the kernel
+        leaf, rows, slot = in_rows
+        with jax.named_scope("kda_gates"):
+            g, beta, gate = gates(split_heads=False)
+        with jax.named_scope("kda_chunk"):
+            o = fused_kda_rows(leaf, rows, act, g, beta, slot=slot, heads=heads, qk_norm=True,
+                               o_eps=cfg.rms_norm_eps)
+            o = o * jnp.tile(lp["o_norm"], heads) * gate
+        return _dot(o.astype(xn.dtype), lp["wo"]), None, carried
+    with jax.named_scope("kda_conv"):
+        act = act.reshape(b, t, 3, heads, d)
 
         def l2(x):
             return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
         q, k, v = l2(act[:, :, 0]) * d ** -0.5, l2(act[:, :, 1]), act[:, :, 2]
     with jax.named_scope("kda_gates"):
-        dt = _dot(_dot(xn, lp["f_down"]).astype(xn.dtype), lp["f_up"]) + lp["dt_bias"]
-        g = -jnp.exp(lp["a_log"])[:, None] * jax.nn.softplus(dt).reshape(b, t, heads, d)
-        g = jnp.where(valid[..., None, None], g, 0.0)
-        beta = jnp.where(valid[..., None], jax.nn.sigmoid(_dot(xn, lp["beta"])), 0.0)
-        gate = jax.nn.sigmoid(_dot(_dot(xn, lp["g_down"]).astype(xn.dtype), lp["g_up"]))
+        g, beta, gate = gates(split_heads=True)
     with jax.named_scope("kda_chunk"):
         o, s_end = kda_chunks(q, k, v, g, beta, s0)
         o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_norm_eps)
@@ -732,11 +783,18 @@ def _forward(cfg: KimiLinearConfig, params, state, rows, ids, valid, cached_n, w
         xn = rms_norm(x, lp["ln1"], cfg.rms_norm_eps)
         # foremast: ignore[jit-hygiene] — the layer's kind, read from the static config
         if kind == KDA:
-            s0, tail = state["S"][rows, slot], state["conv"][rows, slot]
-            # foremast: ignore[jit-hygiene] — the program's kind, a Python value
-            if carried is not None:
-                s0, tail = jnp.where(carried, s0, 0.0), jnp.where(carried, tail, 0)
-            mix, s_end, seen = kda_mix(cfg, lp, xn, valid, tail, s0)
+            # foremast: ignore[jit-hygiene] — backend, program kind and shapes: Python values
+            if fused_window_kda(cfg, t, write_at):
+                # the kernel reads each row's state where it lies: no batch of it is formed
+                mix, s_end, seen = kda_mix(
+                    cfg, lp, xn, valid, state["conv"][rows, slot], None,
+                    in_rows=(state["S"], rows, slot))
+            else:
+                s0, tail = state["S"][rows, slot], state["conv"][rows, slot]
+                # foremast: ignore[jit-hygiene] — the program's kind, a Python value
+                if carried is not None:
+                    s0, tail = jnp.where(carried, s0, 0.0), jnp.where(carried, tail, 0)
+                mix, s_end, seen = kda_mix(cfg, lp, xn, valid, tail, s0)
             # foremast: ignore[jit-hygiene]
             if write_at is not None:
                 # the tail after the chunk: the last taps - 1 valid positions

@@ -328,6 +328,13 @@ class WorkerMetrics:
             ["kind"],
             registry=reg,
         )
+        self.backbone_fused_kda_tokens = Counter(
+            "foremast_backbone_fused_kda_tokens_total",
+            "of the window tokens, those of dispatches whose KDA chunk "
+            "algebra took the fused TPU kernel",
+            ["kind"],
+            registry=reg,
+        )
         self.backbone_latent_positions = Counter(
             "foremast_backbone_latent_positions_total",
             "positions the window tokens' latent attention attended to "
@@ -615,13 +622,14 @@ class WorkerMetrics:
         """Feed a model-backed kind's cumulative counters
         (`BackboneDetector.counters()`); deltas are exported, as in
         `observe_arena`. A counter the kind's model does not keep
-        (`fused_attn_tokens`, `latent_positions`, `state_bytes_read`)
-        exports nothing."""
+        (`fused_attn_tokens`, `fused_kda_tokens`, `latent_positions`,
+        `state_bytes_read`) exports nothing."""
         last = self._backbone_last.setdefault(kind, {})
         flat = {
             "prefill_tokens": self.backbone_prefill_tokens,
             "window_tokens": self.backbone_window_tokens,
             "fused_attn_tokens": self.backbone_fused_attn_tokens,
+            "fused_kda_tokens": self.backbone_fused_kda_tokens,
             "latent_positions": self.backbone_latent_positions,
             "state_bytes_read": self.backbone_state_bytes_read,
             "cache_hits": self.backbone_cache_hits,

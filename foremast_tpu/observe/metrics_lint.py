@@ -39,6 +39,7 @@ ALLOWED_LABELS: dict[str, frozenset[str]] = {
     "foremast_backbone_prefill_tokens": frozenset({"kind"}),
     "foremast_backbone_window_tokens": frozenset({"kind"}),
     "foremast_backbone_fused_attn_tokens": frozenset({"kind"}),
+    "foremast_backbone_fused_kda_tokens": frozenset({"kind"}),
     "foremast_backbone_latent_positions": frozenset({"kind"}),
     "foremast_backbone_state_bytes_read": frozenset({"kind"}),
     "foremast_backbone_cache_rows_live": frozenset({"kind"}),
@@ -157,6 +158,11 @@ FAMILY_DOCS: dict[str, str] = {
         "kind `backbone` only: of those, tokens of dispatches whose "
         "attention took the fused TPU kernel; 0 off a TPU or at widths the "
         "kernel does not tile"
+    ),
+    "foremast_backbone_fused_kda_tokens": (
+        "kind `backbone_kda` only: of those, tokens of dispatches whose KDA "
+        "chunk algebra took the fused TPU kernel; 0 off a TPU or with heads "
+        "that are no lane tile"
     ),
     "foremast_backbone_latent_positions": (
         "positions the window tokens' latent attention attended to, cached "
@@ -433,7 +439,8 @@ def default_registry_families():
     }
     metrics.observe_backbone("backbone", {**shared, "fused_attn_tokens": 1})
     metrics.observe_backbone(
-        "backbone_kda", {**shared, "latent_positions": 1, "state_bytes_read": 1}
+        "backbone_kda",
+        {**shared, "latent_positions": 1, "state_bytes_read": 1, "fused_kda_tokens": 1},
     )
     for path in ("micro", "sweep"):
         metrics.verdict_latency.labels(path=path, tenant="default").observe(

@@ -193,3 +193,26 @@ def test_the_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, sliding
     assert "tpu_custom_call" in compiled.as_text()
     # nothing of the rows' size is gathered or held beside the leaves
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 48 * t * cfg.num_attention_heads * d * 2 + 1
+
+
+def test_the_kda_chunk_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip):
+    """Kind `backbone_kda`'s kernel (`models/kimi_kda.py`, ISSUE 32) at its
+    cell's shapes: 192 sequences of a 32-token window, 32 heads of 128, the
+    state read from a [192, 4, 32, 128, 128] leaf. Here, because this is the
+    one tier-1 file that loads libtpu."""
+    from foremast_tpu.models import kimi_kda, kimi_linear
+
+    cfg = kimi_linear.Config.from_file()
+    s, t, heads, d = 192, 32, cfg.kda_heads, cfg.kda_head_dim
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = kimi_kda.fused_kda_rows.lower(
+        sd((s, cfg.n_kda, heads, d, d)), sd((s,), jnp.int32), sd((s, t, 3 * heads * d)),
+        sd((s, t, heads * d)), sd((s, t, heads)), slot=cfg.n_kda - 1, heads=heads,
+        qk_norm=True, o_eps=cfg.rms_norm_eps,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # no batch of the states is gathered: nothing is held beside the leaf
+    assert compiled.memory_analysis().temp_size_in_bytes < s * t * heads * 4 + 1

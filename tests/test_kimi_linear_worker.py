@@ -95,7 +95,8 @@ def test_cold_tick_two_warm_ticks_and_a_followed_job_leave_the_rows_as_they_were
     assert c["window_tokens"] == TICKS * 7 * WINDOW and c["dropped_tokens"] == 0
     assert c["state_bytes_read"] == TICKS * 7 * det.model.state_bytes(det.cfg)
     assert c["latent_positions"] == TICKS * 7 * (WINDOW * (CONTEXT - 1) + WINDOW * (WINDOW + 1) // 2)
-    assert "fused_attn_tokens" not in c and len(c["expert_tokens"]) == 8  # this model has no kernel
+    assert "fused_attn_tokens" not in c and len(c["expert_tokens"]) == 8  # Cohere2's kernel's counter
+    assert c["fused_kda_tokens"] == 0  # off a TPU every dispatch runs `kda_chunks`
     assert worker._mvj.backbone_counters() == c
     assert worker._fast_kinds[KIND] == (TICKS - 1) * len(SERVICES) and worker._fast_kinds["backbone"] == 0
     # the gauge families carry the kind, and only the counters its model keeps
