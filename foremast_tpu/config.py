@@ -164,7 +164,8 @@ UNIVARIATE_ALGORITHMS = frozenset(
     }
 )
 JOINT_ALGORITHMS = frozenset(
-    {"auto", "bivariate_normal", "lstm_autoencoder", "backbone", "backbone_kda"}
+    {"auto", "bivariate_normal", "lstm_autoencoder", "backbone", "backbone_kda",
+     "backbone_diffusion"}
 )
 KNOWN_ALGORITHMS = UNIVARIATE_ALGORITHMS | JOINT_ALGORITHMS
 
@@ -339,8 +340,9 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
         "season length, Fourier seasonal} — recommended for unknown "
         "metric mixes), `auto`, `bivariate_normal`, `lstm_autoencoder` "
         "(hybrid: AE + seasonal-residual Gaussian), `backbone` / "
-        "`backbone_kda` (a shared sequence backbone, softmax-attention "
-        "or linear-attention, docs/backbone.md: `ML_THRESHOLD` is then a "
+        "`backbone_kda` / `backbone_diffusion` (a shared sequence "
+        "backbone, softmax-attention, linear-attention or block-diffusion, "
+        "docs/backbone.md: `ML_THRESHOLD` is then a "
         "score in nats). An unknown name is an error at load",
         "engine",
     ),
@@ -487,12 +489,14 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
         "FOREMAST_BACKBONE_MODEL",
         None,
         "path",
-        "model file of `ML_ALGORITHM=backbone` / `backbone_kda`: the "
+        "model file of `ML_ALGORITHM=backbone` / `backbone_kda` / "
+        "`backbone_diffusion`: the "
         "sequence model's config.json keys plus the `share` of it this "
         "process holds and the seed of its weights; its `model_type` has "
         "to be one the kind takes (default: the packaged "
         "`foremast_tpu/models/configs/command-a-plus-05-2026.json`, for "
-        "`backbone_kda` `kimi-linear-48b-a3b-instruct.json`; "
+        "`backbone_kda` `kimi-linear-48b-a3b-instruct.json`, for "
+        "`backbone_diffusion` `sdar-30b-a3b-chat.json`; "
         "docs/backbone.md)",
     ),
     EnvKnob(
@@ -500,8 +504,9 @@ ENV_KNOBS: tuple[EnvKnob, ...] = (
         "10080",
         "int",
         "history points a backbone sequence keeps (the newest): all but "
-        "the last are prefilled into its cache row, whose leaves are sized "
-        "to that rounded up to a multiple of 128",
+        "the last are prefilled into its cache row (`backbone_diffusion`: "
+        "its newest whole blocks), whose leaves are sized to that rounded "
+        "up to a multiple of 128",
     ),
     EnvKnob(
         "FOREMAST_BACKBONE_ROWS",

@@ -1,13 +1,15 @@
-"""The model-backed detector kinds (`backbone`, `backbone_kda`): ONE set of
-sequence-model weights shared by the fleet, a cache row per sequence in a
-fixed-capacity `TreeArena`, and the two dispatches that use them — the
-chunked prefill of a cold history ("fit") and the warm window program.
+"""The model-backed detector kinds (`backbone`, `backbone_kda`,
+`backbone_diffusion`): ONE set of sequence-model weights shared by the
+fleet, a cache row per sequence in a fixed-capacity `TreeArena`, and the two
+dispatches that use them — the chunked prefill of a cold history ("fit")
+and the warm window program.
 
 The model is a module of `foremast_tpu/models/`, chosen by the model file's
 `model_type` (`MODELS`), and the detector reaches it through these names
 alone (docs/backbone.md, "The model interface"):
 
     MODEL_TYPE, DEFAULT_MODEL_FILE, Config.from_dict(model file's dict)
+    cached_span(cfg, n)                  the history points [first, stop) of n a row caches
     prefill_seqs(cfg, ctx_cap), prefill_chunk_len(cfg, ctx_cap)
     cache_template(cfg, ctx_cap)         one arena row's pytree of shapes
     init_params(cfg)                     the share's weights, from the seed
@@ -43,6 +45,7 @@ from foremast_tpu.observe.spans import note, span
 MODELS = {
     "cohere2_moe": "foremast_tpu.models.cohere2_moe",
     "kimi_linear": "foremast_tpu.models.kimi_linear",
+    "sdar_moe": "foremast_tpu.models.sdar_moe",
 }
 
 
@@ -77,11 +80,13 @@ class BackboneDetector:
             model_file or env("FOREMAST_BACKBONE_MODEL") or None, model_types
         )
         model = self.model
-        # history points a sequence keeps (the newest); all but the last
-        # are cached, and the leaves are sized to that rounded up to a
-        # multiple of 128 (8 for a toy context), never to a power of two
+        # history points a sequence keeps (the newest); the model says
+        # which of them its row caches (`cached_span`), and the leaves are
+        # sized to that rounded up to a multiple of 128 (8 for a toy
+        # context), never to a power of two
         self.context = int(context or env("FOREMAST_BACKBONE_CONTEXT", "10080"))
-        cached = self.context - 1
+        first, stop = model.cached_span(self.cfg, self.context)
+        cached = stop - first
         self.ctx_cap = _round_up(cached, 128 if cached > 128 else 8)
         self.capacity = int(rows or env("FOREMAST_BACKBONE_ROWS", "64"))
         self.prefill_seqs = model.prefill_seqs(self.cfg, self.ctx_cap)
@@ -98,7 +103,8 @@ class BackboneDetector:
         self.window_tokens = 0
         self.dropped_tokens = 0
         # the model's own counters of its window dispatches (Cohere2:
-        # `fused_attn_tokens`; Kimi-Linear: `latent_positions`, `state_bytes_read`)
+        # `fused_attn_tokens`; Kimi-Linear: `latent_positions`,
+        # `state_bytes_read`; SDAR: `denoise_tokens`, `clean_tokens`)
         self.model_counters = dict.fromkeys(model.WINDOW_COUNTERS, 0)
 
     @property
@@ -126,9 +132,10 @@ class BackboneDetector:
     # foremast: device-boundary
     def ensure(self, keys: list, histories: list) -> list:
         """Rows for the sequences `keys`, each with its history [n]
-        float32; those that have no row are tokenised and prefilled. At
-        most `capacity` sequences a call. -> per sequence (scale, cached
-        positions, last history id)."""
+        float32; those that have no row are tokenised and the span of it
+        the model caches (`cached_span`) is prefilled. At most `capacity`
+        sequences a call. -> per sequence (scale, cached positions, last
+        history id)."""
         cfg, arena, model = self.cfg, self.arena, self.model
         hists = [np.asarray(h, np.float32)[-self.context:] for h in histories]
         assigned = arena.assign(keys, [])
@@ -162,8 +169,9 @@ class BackboneDetector:
                 for b, i in enumerate(members):
                     scale[b] = model.series_scale(hists[i])
                     tok = model.tokenize(hists[i], scale[b], vocab)
-                    ids[b, : len(tok)] = tok
-                    n[b], last[b], row[b] = len(tok) - 1, tok[-1], rows[i]
+                    first, stop = model.cached_span(cfg, len(tok))
+                    ids[b, : stop - first] = tok[first:stop]
+                    n[b], last[b], row[b] = stop - first, tok[-1], rows[i]
                 r, nj = jnp.asarray(row), jnp.asarray(n)
                 for start in range(0, int(n.max()), self.chunk):
                     stop = min(start + self.chunk, self.ctx_cap)

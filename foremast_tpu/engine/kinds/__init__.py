@@ -25,6 +25,7 @@ JOINT_KINDS: dict[str, JointKind] = {
         LstmKind(),
         BackboneKind("backbone", ("cohere2_moe",)),
         BackboneKind("backbone_kda", ("kimi_linear",)),
+        BackboneKind("backbone_diffusion", ("sdar_moe",)),
     )
 }
 
@@ -37,7 +38,8 @@ def select_mode(algorithm: str, n_metrics: int) -> str:
     the kinds' own `selectors`: `auto` -> bivariate at 2 metrics, lstm at
     3+; `bivariate_normal` -> bivariate at 2; `lstm_autoencoder` -> lstm
     at 2+; `backbone` -> backbone at 1+; `backbone_kda` -> backbone_kda at
-    1+. A count that fits no kind under
+    1+; `backbone_diffusion` -> backbone_diffusion at 1+. A count that fits
+    no kind under
     an explicit selector falls to the univariate judge."""
     for kind in JOINT_KINDS.values():
         if kind.takes(algorithm, n_metrics):

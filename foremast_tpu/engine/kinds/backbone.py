@@ -1,12 +1,13 @@
-"""The model-backed kinds, `backbone` and `backbone_kda`: every alias of
-every job is one sequence of ONE shared sequence model
-(`engine/backbone.py`'s `BackboneDetector`: the weights, a cache row a
-sequence, the chunked prefill and the window program; docs/backbone.md).
-The two are one class instantiated twice: a kind's name is its
-`ML_ALGORITHM` value, and `model_types` the model files it takes (`backbone`:
-`cohere2_moe`; `backbone_kda`: `kimi_linear`). The kind is what the judge,
-the pack and the worker see of it: which jobs it takes, its warm entry and
-gates, and the two paths around the detector."""
+"""The model-backed kinds, `backbone`, `backbone_kda` and
+`backbone_diffusion`: every alias of every job is one sequence of ONE shared
+sequence model (`engine/backbone.py`'s `BackboneDetector`: the weights, a
+cache row a sequence, the chunked prefill and the window program;
+docs/backbone.md). The three are one class instantiated three times: a
+kind's name is its `ML_ALGORITHM` value, and `model_types` the model files it
+takes (`backbone`: `cohere2_moe`; `backbone_kda`: `kimi_linear`;
+`backbone_diffusion`: `sdar_moe`). The kind is what the judge, the pack and
+the worker see of it: which jobs it takes, its warm entry and gates, and the
+two paths around the detector."""
 
 from __future__ import annotations
 

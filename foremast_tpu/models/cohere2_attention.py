@@ -4,6 +4,8 @@ the fused pass the window program takes on a TPU.
 `visible` and `cached_positions` are the mask arithmetic of BOTH paths:
 `cohere2_moe.attend` (every CPU run, the prefill) builds its [Tq, Tk] masks
 from them, and the kernel here evaluates them on a block of keys at a time.
+A caller whose dispatch's own keys are not seen by position hands the
+kernel its own rule for them (`own_visible`).
 
 `fused_attend_rows` is one layer's attention of a window dispatch as ONE
 Pallas TPU kernel. A grid step is one (sequence, key-value head): the
@@ -77,7 +79,8 @@ def fused_applies(head_dim: int, itemsize: int, tokens: int, capacities) -> bool
 
 
 def _kernel(rows_ref, n_ref, q_ref, pq_ref, kn_ref, vn_ref, pn_ref, ok_ref, kc_ref, vc_ref,
-            o_ref, m_ref, l_ref, acc_ref, *, group: int, window, ring, key_block: int):
+            o_ref, m_ref, l_ref, acc_ref, *, group: int, window, ring, key_block: int,
+            own_visible=None):
     del rows_ref  # read by the index maps
     n = n_ref[pl.program_id(0)]
     gt, d = q_ref.shape
@@ -115,30 +118,44 @@ def _kernel(rows_ref, n_ref, q_ref, pq_ref, kn_ref, vn_ref, pn_ref, ok_ref, kc_r
         start = pl.multiple_of(jnp.minimum(first, cap - key_block), math.gcd(key_block, cap))
         slot = start + lax.broadcasted_iota(jnp.int32, (1, key_block), 1)
         pos_k, held = cached_positions(slot, n, ring)
-        seen = visible(pos_q, pos_k, held & (slot >= first), window)
+        # foremast: ignore[jit-hygiene] — a static argument
+        if own_visible is not None:
+            # every query lies past the row: it sees every cached key
+            seen = held & (slot >= first)
+        else:
+            seen = visible(pos_q, pos_k, held & (slot >= first), window)
         take(kc_ref[pl.ds(start, key_block), :], vc_ref[pl.ds(start, key_block), :], seen)
         return carry
 
     # the dispatch's own keys start the sums: no state to zero and rescale.
     # A padding row sees none of them and sums them under MASKED; its first
     # visible cached key rescales that to nothing, as in `attend`
-    take(kn_ref[...], vn_ref[...], visible(pos_q, pn_ref[...], ok_ref[...] != 0, window), True)
+    take(kn_ref[...], vn_ref[...],
+         # foremast: ignore[jit-hygiene] — a static argument
+         own_visible(pos_q, pn_ref[...], ok_ref[...] != 0) if own_visible is not None
+         else visible(pos_q, pn_ref[...], ok_ref[...] != 0, window), True)
     lax.fori_loop(0, pl.cdiv(jnp.minimum(n, cap), key_block), cached, 0)
     o_ref[...] = (acc_ref[...] / l_ref[...]).reshape(gt, d).astype(o_ref.dtype)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("layer", "group", "window", "key_block", "interpret")
+    jax.jit,
+    static_argnames=("layer", "group", "window", "key_block", "interpret", "own_visible", "name"),
 )
 def fused_attend_rows(kleaf, vleaf, rows, cached_n, q, kn, vn, pos, valid, *, layer: int,
                       group: int, window: int | None, key_block: int = KEY_BLOCK,
-                      interpret: bool = False):
+                      interpret: bool = False, own_visible=None, name: str | None = None):
     """One layer's attention for every sequence of a window dispatch, each
     against arena row rows[s] of the leaves kleaf / vleaf [rows, layers of
     the type, Hkv, capacity, D] (slot `layer`), which holds cached_n[s]
     positions. q [S, T, Hq, D] at pos [S, T]; kn, vn [S, T, Hkv, D] the
     dispatch's own keys and values (`valid` [S, T]). With a `window` (a
-    sliding layer) the leaf is a ring and the window bounds the gap.
+    sliding layer) the leaf is a ring and the window bounds the gap. With
+    `own_visible` (no window) `pos` holds whatever that rule reads of a
+    token instead of its position: own_visible(pos_q [T, 1], pos_k, valid_k
+    [1, T]) -> [T, T] bool says which of the dispatch's own keys a query
+    sees, and every query sees every cached key. `name`: the device op's
+    name (`backbone_attn_full` / `_sliding` by default).
     -> [S, T, Hq * D]."""
     s, t, hq, d = q.shape
     hkv = hq // group
@@ -151,11 +168,14 @@ def fused_attend_rows(kleaf, vleaf, rows, cached_n, q, kn, vn, pos, valid, *, la
     cache = pl.BlockSpec(
         (None, None, None, cap, d), lambda i, h, rows, n: (rows[i], layer, h, 0, 0)
     )
+    kernel = functools.partial(
+        _kernel, group=group, window=window, ring=cap if window else None, key_block=key_block,
+    )
+    # foremast: ignore[jit-hygiene] — a static argument
+    if own_visible is not None:
+        kernel = functools.partial(kernel, own_visible=own_visible)
     out = pl.pallas_call(
-        functools.partial(
-            _kernel, group=group, window=window, ring=cap if window else None,
-            key_block=key_block,
-        ),
+        kernel,
         out_shape=jax.ShapeDtypeStruct((s, hkv, gt, d), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -185,7 +205,7 @@ def fused_attend_rows(kleaf, vleaf, rows, cached_n, q, kn, vn, pos, valid, *, la
             transcendentals=s * hkv * gt * (cap + t),
             bytes_accessed=2 * s * hkv * (cap + t + gt) * d * q.dtype.itemsize,
         ),
-        name="backbone_attn_sliding" if window else "backbone_attn_full",
+        name=name or ("backbone_attn_sliding" if window else "backbone_attn_full"),
         interpret=interpret,
     )(
         rows, cached_n, qh, pos[:, :, None],

@@ -279,6 +279,12 @@ def tokenize(values: np.ndarray, scale: np.ndarray, vocab: int) -> np.ndarray:
 # -- the cache row -----------------------------------------------------------
 
 
+def cached_span(cfg, n: int) -> tuple:
+    """The history points [first, stop) of n that a row caches: all but the
+    last, which is the window program's first input (`last`)."""
+    return 0, n - 1
+
+
 def ring_size(cfg: Cohere2MoeConfig, ctx_cap: int) -> int:
     return min(cfg.sliding_window, ctx_cap)
 
@@ -361,12 +367,14 @@ def rope(x, pos, theta: float):
 
 
 def attend(q, pos_q, kn, vn, pos_n, valid_n, kc, vc, pos_c, valid_c, group: int,
-           window: int | None):
+           window: int | None, seen_n=None):
     """One sequence, one layer: queries q [Tq, Hq, D] at pos_q [Tq] over
     the cached keys kc/vc [Hkv, Ck, D] (positions pos_c, `valid_c`) and the
     dispatch's own kn/vn [Tn, Hkv, D] (positions pos_n, `valid_n`), under
     ONE softmax taken in two parts, so that neither the keys nor the scores
-    are concatenated. -> [Tq, Hq * D]."""
+    are concatenated. `seen_n` [Tq, Tn], where given, says which of the
+    dispatch's own keys a query sees in place of their positions (a
+    block-diffusion dispatch: `sdar_moe`). -> [Tq, Hq * D]."""
     tq, hq, d = q.shape
     hkv = hq // group
     qh = q.reshape(tq, hkv, group, d).transpose(1, 2, 0, 3).reshape(hkv, group * tq, d)
@@ -375,7 +383,9 @@ def attend(q, pos_q, kn, vn, pos_n, valid_n, kc, vc, pos_c, valid_c, group: int,
     sn = jnp.einsum("hqd,khd->hqk", qh, kn, preferred_element_type=jnp.float32) * scale
     # rows of qh run (g, t): the masks repeat over g
     seen_c = visible(pos_q[:, None], pos_c[None, :], valid_c[None, :], window)
-    seen_n = visible(pos_q[:, None], pos_n[None, :], valid_n[None, :], window)
+    # foremast: ignore[jit-hygiene] — None or an array: decided while tracing
+    if seen_n is None:
+        seen_n = visible(pos_q[:, None], pos_n[None, :], valid_n[None, :], window)
     sc = jnp.where(jnp.tile(seen_c, (group, 1)), sc, MASKED)
     sn = jnp.where(jnp.tile(seen_n, (group, 1)), sn, MASKED)
     m = jnp.maximum(sc.max(axis=-1), sn.max(axis=-1))[..., None]

@@ -90,6 +90,7 @@ from jax import lax
 
 from foremast_tpu.models.cohere2_moe import (  # the code both backbones share
     Share,
+    cached_span,
     finish_rows,
     routed_experts,
     router_scores,
@@ -100,9 +101,9 @@ from foremast_tpu.models.cohere2_moe import (  # the code both backbones share
 from foremast_tpu.models.kimi_kda import KDA_CHUNK, KDA_SUB, fused_applies, fused_kda_rows
 
 __all__ = [
-    "Config", "MODEL_TYPE", "cache_template", "finish_rows", "init_params", "prefill_chunk",
-    "prefill_chunk_len", "prefill_seqs", "score_window", "series_scale", "tokenize",
-    "window_counters", "WINDOW_COUNTERS",
+    "Config", "MODEL_TYPE", "cache_template", "cached_span", "finish_rows", "init_params",
+    "prefill_chunk", "prefill_chunk_len", "prefill_seqs", "score_window", "series_scale",
+    "tokenize", "window_counters", "WINDOW_COUNTERS",
 ]
 
 MODEL_TYPE = "kimi_linear"

@@ -301,12 +301,14 @@ class WorkerMetrics:
         self.fast_docs = Counter(
             "foremast_worker_fast_docs_total",
             "documents scored on the columnar fast path, by model kind "
-            "(univariate / bivariate / lstm / backbone / backbone_kda)",
+            "(univariate / bivariate / lstm / backbone / backbone_kda / "
+            "backbone_diffusion)",
             ["kind"],
             registry=reg,
         )
-        # the model-backed kinds (`backbone`, `backbone_kda`;
-        # engine/backbone.py), by kind: tokens prefilled and scored, the
+        # the model-backed kinds (`backbone`, `backbone_kda`,
+        # `backbone_diffusion`; engine/backbone.py), by kind: tokens
+        # prefilled and scored, the
         # cache, what the window dispatches read of it, the load of the
         # experts this process holds
         self.backbone_prefill_tokens = Counter(
@@ -346,6 +348,20 @@ class WorkerMetrics:
             "foremast_backbone_state_bytes_read_total",
             "bytes of recurrent state and convolution tails the window "
             "dispatches read from the cache rows",
+            ["kind"],
+            registry=reg,
+        )
+        self.backbone_denoise_tokens = Counter(
+            "foremast_backbone_denoise_tokens_total",
+            "token-forwards of the noisy block copies a block-diffusion "
+            "backbone's window program ran (B copies of B tokens a block)",
+            ["kind"],
+            registry=reg,
+        )
+        self.backbone_clean_tokens = Counter(
+            "foremast_backbone_clean_tokens_total",
+            "clean window tokens a block-diffusion backbone's window "
+            "program ran for later blocks to read",
             ["kind"],
             registry=reg,
         )
@@ -623,7 +639,8 @@ class WorkerMetrics:
         (`BackboneDetector.counters()`); deltas are exported, as in
         `observe_arena`. A counter the kind's model does not keep
         (`fused_attn_tokens`, `fused_kda_tokens`, `latent_positions`,
-        `state_bytes_read`) exports nothing."""
+        `state_bytes_read`, `denoise_tokens`, `clean_tokens`) exports
+        nothing."""
         last = self._backbone_last.setdefault(kind, {})
         flat = {
             "prefill_tokens": self.backbone_prefill_tokens,
@@ -632,6 +649,8 @@ class WorkerMetrics:
             "fused_kda_tokens": self.backbone_fused_kda_tokens,
             "latent_positions": self.backbone_latent_positions,
             "state_bytes_read": self.backbone_state_bytes_read,
+            "denoise_tokens": self.backbone_denoise_tokens,
+            "clean_tokens": self.backbone_clean_tokens,
             "cache_hits": self.backbone_cache_hits,
             "cache_misses": self.backbone_cache_misses,
             "dropped_tokens": self.backbone_dropped_tokens,

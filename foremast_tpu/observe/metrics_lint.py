@@ -42,6 +42,8 @@ ALLOWED_LABELS: dict[str, frozenset[str]] = {
     "foremast_backbone_fused_kda_tokens": frozenset({"kind"}),
     "foremast_backbone_latent_positions": frozenset({"kind"}),
     "foremast_backbone_state_bytes_read": frozenset({"kind"}),
+    "foremast_backbone_denoise_tokens": frozenset({"kind"}),
+    "foremast_backbone_clean_tokens": frozenset({"kind"}),
     "foremast_backbone_cache_rows_live": frozenset({"kind"}),
     "foremast_backbone_cache_hits": frozenset({"kind"}),
     "foremast_backbone_cache_misses": frozenset({"kind"}),
@@ -143,21 +145,23 @@ FAMILY_DOCS: dict[str, str] = {
     ),
     "foremast_worker_fast_docs": (
         "documents scored on the columnar fast path, by model kind "
-        "(univariate/bivariate/lstm/backbone/backbone_kda, plus `baseline` — the "
+        "(univariate/bivariate/lstm/backbone/backbone_kda/backbone_diffusion, "
+        "plus `baseline` — the "
         "canary bucket: baseline-carrying univariate docs judged through "
         "the pairwise-active columnar program)"
     ),
     "foremast_backbone_prefill_tokens": (
         "history tokens prefilled into the backbone's cache, by model-backed "
-        "kind (`ML_ALGORITHM=backbone` / `backbone_kda`; docs/backbone.md)"
+        "kind (`ML_ALGORITHM=backbone` / `backbone_kda` / "
+        "`backbone_diffusion`; docs/backbone.md)"
     ),
     "foremast_backbone_window_tokens": (
         "current-window tokens the backbone's window program scored"
     ),
     "foremast_backbone_fused_attn_tokens": (
-        "kind `backbone` only: of those, tokens of dispatches whose "
-        "attention took the fused TPU kernel; 0 off a TPU or at widths the "
-        "kernel does not tile"
+        "kinds `backbone` and `backbone_diffusion`: of those, tokens of "
+        "dispatches whose attention took the fused TPU kernel; 0 off a TPU "
+        "or at widths the kernel does not tile"
     ),
     "foremast_backbone_fused_kda_tokens": (
         "kind `backbone_kda` only: of those, tokens of dispatches whose KDA "
@@ -173,6 +177,15 @@ FAMILY_DOCS: dict[str, str] = {
     "foremast_backbone_state_bytes_read": (
         "bytes of recurrent state and convolution tails the window "
         "dispatches read from the cache rows (kind `backbone_kda`)"
+    ),
+    "foremast_backbone_denoise_tokens": (
+        "token-forwards of the noisy block copies the window program ran, "
+        "counted from the liveness its attention masks are taken under "
+        "(kind `backbone_diffusion`; B per scored token)"
+    ),
+    "foremast_backbone_clean_tokens": (
+        "clean window tokens the window program ran because later blocks "
+        "read their keys and values (kind `backbone_diffusion`)"
     ),
     "foremast_backbone_cache_rows_live": (
         "sequences whose prefix the backbone's cache holds "
@@ -441,6 +454,10 @@ def default_registry_families():
     metrics.observe_backbone(
         "backbone_kda",
         {**shared, "latent_positions": 1, "state_bytes_read": 1, "fused_kda_tokens": 1},
+    )
+    metrics.observe_backbone(
+        "backbone_diffusion",
+        {**shared, "denoise_tokens": 4, "clean_tokens": 1, "fused_attn_tokens": 1},
     )
     for path in ("micro", "sweep"):
         metrics.verdict_latency.labels(path=path, tenant="default").observe(

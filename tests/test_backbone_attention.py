@@ -216,3 +216,32 @@ def test_the_kda_chunk_kernel_compiles_for_a_v5e_at_the_published_widths(one_chi
     assert "tpu_custom_call" in compiled.as_text()
     # no batch of the states is gathered: nothing is held beside the leaf
     assert compiled.memory_analysis().temp_size_in_bytes < s * t * heads * 4 + 1
+
+
+def test_the_block_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip):
+    """Kind `backbone_diffusion`'s use of the kernel (`own_visible=`
+    `sdar_moe.block_visible`: the dispatch's own keys seen by (block, copy)
+    code; device op `sdar_moe.ATTN_OP`) at its cell's shapes: 80
+    sequences of 160 tokens (a 32-point bucket's clean blocks and 4 noisy
+    copies of each), 32 query heads on 4 key-value heads of 128, rows of
+    10,112 positions in a [80, 4, 4, 10112, 128] leaf, keys 512 a step."""
+    from foremast_tpu.models import sdar_moe
+
+    cfg = sdar_moe.Config.from_file()
+    s, t, hkv, d = 80, sdar_moe.window_tokens(cfg, 32), cfg.num_key_value_heads, cfg.head_dim
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    leaf = sd((s, cfg.share.layers_held, hkv, 10112, d), cfg.dtype)
+    new = sd((s, t, hkv, d), cfg.dtype)
+    compiled = fa.fused_attend_rows.lower(
+        leaf, leaf, sd((s,), jnp.int32), sd((s,), jnp.int32),
+        sd((s, t, cfg.num_attention_heads, d), cfg.dtype), new, new,
+        sd((s, t), jnp.int32), sd((s, t), jnp.bool_),
+        layer=cfg.share.layers_held - 1, group=cfg.group, window=None,
+        own_visible=sdar_moe.block_visible, name=sdar_moe.ATTN_OP, key_block=sdar_moe.KEY_BLOCK,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text() and sdar_moe.ATTN_OP in compiled.as_text()
+    # nothing of the rows' size is gathered or held beside the leaves
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * s * t * cfg.num_attention_heads * d * 2 + 1

@@ -380,6 +380,7 @@ def test_fast_kinds_are_what_the_lint_exercises_and_the_docs_list():
 
     assert fast_kinds() == (
         "univariate", "baseline", "bivariate", "lstm", "backbone", "backbone_kda",
+        "backbone_diffusion",
     )
     worker = BrainWorker(InMemoryStore(), _Source(), config=BrainConfig())
     assert tuple(worker._fast_kinds) == fast_kinds()
@@ -420,5 +421,5 @@ def test_config_lists_every_selector_the_table_answers_to():
     assert [k.name for k in JOINT_KINDS.values() if k.needs_gaps] == ["lstm"]
     assert [k.name for k in JOINT_KINDS.values() if k.pins_bucket] == ["lstm"]
     assert [k.name for k in JOINT_KINDS.values() if not k.persisted] == [
-        "backbone", "backbone_kda"
+        "backbone", "backbone_kda", "backbone_diffusion"
     ]

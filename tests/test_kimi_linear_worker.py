@@ -133,18 +133,19 @@ def test_both_kinds_are_one_class_over_one_detector_and_default_to_their_own_fil
     from foremast_tpu.engine.backbone import MODELS, load_model
     from foremast_tpu.engine.kinds.backbone import BackboneKind
 
-    a, b = JOINT_KINDS["backbone"], JOINT_KINDS[KIND]
-    assert type(a) is type(b) is BackboneKind
-    assert (a.model_types, b.model_types) == (("cohere2_moe",), ("kimi_linear",))
+    a, b, c = JOINT_KINDS["backbone"], JOINT_KINDS[KIND], JOINT_KINDS["backbone_diffusion"]
+    assert type(a) is type(b) is type(c) is BackboneKind
+    assert (a.model_types, b.model_types, c.model_types) == (
+        ("cohere2_moe",), ("kimi_linear",), ("sdar_moe",))
     assert a.selectors == {"backbone": (1, None)} and b.selectors == {KIND: (1, None)}
-    assert set(MODELS) == {"cohere2_moe", "kimi_linear"}
-    for kind in (a, b):
+    assert set(MODELS) == {"cohere2_moe", "kimi_linear", "sdar_moe"}
+    for kind in (a, b, c):
         model, cfg = load_model(None, kind.model_types)
-        assert model.MODEL_TYPE == kind.model_types[0] and cfg.hidden_size in (4096, 2304)
+        assert model.MODEL_TYPE == kind.model_types[0] and cfg.hidden_size in (4096, 2304, 2048)
     # the interface the detector reaches a model through
     for name in MODELS.values():
         module = __import__(name, fromlist=["x"])
-        for attr in ("MODEL_TYPE", "DEFAULT_MODEL_FILE", "Config", "prefill_seqs",
+        for attr in ("MODEL_TYPE", "DEFAULT_MODEL_FILE", "Config", "cached_span", "prefill_seqs",
                      "prefill_chunk_len", "cache_template", "init_params", "series_scale",
                      "tokenize", "prefill_chunk", "finish_rows", "score_window",
                      "window_counters", "WINDOW_COUNTERS"):
