@@ -102,9 +102,10 @@ class BackboneDetector:
         self.prefill_tokens = 0
         self.window_tokens = 0
         self.dropped_tokens = 0
-        # the model's own counters of its window dispatches (Cohere2:
-        # `fused_attn_tokens`; Kimi-Linear: `latent_positions`,
-        # `state_bytes_read`; SDAR: `denoise_tokens`, `clean_tokens`)
+        # the model's own counters of its window dispatches (every model:
+        # `sorted_moe_tokens`; Cohere2: `fused_attn_tokens`; Kimi-Linear:
+        # `latent_positions`, `state_bytes_read`, `fused_kda_tokens`; SDAR:
+        # `denoise_tokens`, `clean_tokens`, `fused_attn_tokens`)
         self.model_counters = dict.fromkeys(model.WINDOW_COUNTERS, 0)
 
     @property

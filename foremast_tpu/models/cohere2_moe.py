@@ -315,15 +315,18 @@ def fused_window_attention(cfg: Cohere2MoeConfig, ctx_cap: int, tokens: int) -> 
     )
 
 
-WINDOW_COUNTERS = ("fused_attn_tokens",)
+WINDOW_COUNTERS = ("fused_attn_tokens", "sorted_moe_tokens")
 
 
 def window_counters(cfg: Cohere2MoeConfig, ctx_cap: int, valid) -> dict:
     """What one window dispatch adds to the detector's counters beside the
     tokens it scored (`valid` [S, W]): the tokens whose attention took the
-    fused kernel."""
+    fused kernel, and those whose expert rows came back to them by the
+    inverse gather of `routed_experts` (`sorted_combine`)."""
     fused = fused_window_attention(cfg, ctx_cap, valid.shape[1])
-    return {"fused_attn_tokens": int(valid.sum()) if fused else 0}
+    n = int(valid.sum())
+    return {"fused_attn_tokens": n if fused else 0,
+            "sorted_moe_tokens": n if sorted_combine(cfg) else 0}
 
 
 def cache_template(cfg: Cohere2MoeConfig, ctx_cap: int) -> dict:
@@ -422,6 +425,21 @@ def _block_rows(cfg, tokens: int) -> int:
     return rows
 
 
+def sorted_combine(cfg) -> bool:
+    """Whether `routed_experts` brings the expert rows back to their tokens
+    by one inverse gather of rows sorted once (a share that holds at least
+    half the experts) or adds each block's rows to its tokens inside the
+    loop over blocks. The sorted buffers hold all T x k assignments, of
+    which a share holding a fraction of the experts multiplies that
+    fraction: at Command-A's share (16 of 128 experts) writing and
+    gathering them cost more than the block-wise adds they replace (a
+    window dispatch 7% slower on a TPU v5e, with the buffer zero-filled),
+    at Kimi-Linear's (128 of 256) less. Decided by the config alone, once
+    for every program; the detector counts `sorted_moe_tokens` by the same
+    call."""
+    return 2 * cfg.share.experts_held >= cfg.num_experts
+
+
 def routed_experts(cfg, lp: dict, xn, valid, route=route):
     """The held experts' part of the FFN for tokens xn [T, h] (`valid` [T]:
     padding is routed nowhere) -> (y [T, h] float32, assignments a held
@@ -431,21 +449,83 @@ def routed_experts(cfg, lp: dict, xn, valid, route=route):
     `num_experts`, `share.experts_held`, `expert_offset`); `route(cfg, lp,
     xn)` is the model's router: expert ids and weights [T, k].
 
-    The T x k assignments are sorted by expert (those of experts held
-    elsewhere, and padding, last), and the sorted run is cut into blocks of
-    rows that each belong to ONE expert: a loop over as many blocks as the
-    routing needs multiplies each by its expert's three matrices and adds
-    the weighted rows back to their tokens. Every assignment to a held
-    expert is in exactly one block, whatever the imbalance; the third
-    result, the rows the loop really multiplied, says so."""
+    The T x k assignments are sorted ONCE by expert (those of experts held
+    elsewhere, and padding, last) and the sorted run is cut into blocks of
+    rows that each belong to ONE expert; a loop over as many blocks as the
+    routing needs multiplies each by its expert's three matrices. Every
+    assignment to a held expert is in exactly one block, whatever the
+    imbalance; the third result, the live rows of the blocks the loop runs,
+    says so. Where `sorted_combine` holds, the rows are gathered once in
+    sorted order, a block is a contiguous slice of them, and its float32
+    output goes to the same place of a sorted buffer. A block's tail past
+    its expert's rows is written over by the next block, which starts
+    inside that tail, and the loop runs the blocks in order, so every row
+    holds its own expert's output at the end. One gather by the inverse of
+    the sort then brings each token its k rows, weighted and summed in
+    float32; an assignment to an expert held elsewhere, or of padding,
+    reads the buffer's last row, which no block reaches and which is set to
+    0. Elsewhere each block gathers its rows and adds them, weighted, back
+    to their tokens."""
     t, h = xn.shape
     k, held = cfg.num_experts_per_tok, cfg.share.experts_held
     rows = _block_rows(cfg, t)
+    n = t * k
     with jax.named_scope("moe_route"):
         top_i, top_w = route(cfg, lp, xn)
         local = top_i - cfg.expert_offset
         mine = (local >= 0) & (local < held) & valid[:, None]
-        key = jnp.where(mine, local, held).reshape(t * k)
+        key = jnp.where(mine, local, held).reshape(n)
+    if not sorted_combine(cfg):
+        return _added_by_block(cfg, lp, xn, top_w, key, rows)
+    with jax.named_scope("moe_route"):
+        slot = lax.iota(jnp.int32, n)
+        sorted_key, order = lax.sort((key, slot), num_keys=1, is_stable=True)
+        edges = jnp.searchsorted(sorted_key, jnp.arange(held + 1, dtype=jnp.int32),
+                                 method="compare_all").astype(jnp.int32)
+        starts, sizes = edges[:-1], edges[1:] - edges[:-1]
+        blocks = (sizes + rows - 1) // rows
+        ends = jnp.cumsum(blocks)
+        # the block table: block b is expert[b]'s rows from at[b]; a bound on
+        # the blocks any routing needs sizes it
+        b = jnp.arange(n // rows + held, dtype=jnp.int32)
+        expert = jnp.minimum(jnp.searchsorted(ends, b, side="right", method="compare_all"),
+                             held - 1).astype(jnp.int32)
+        j = b - (ends[expert] - blocks[expert])
+        at = starts[expert] + j * rows
+        live = jnp.where(b < ends[-1], jnp.clip(sizes[expert] - j * rows, 0, rows), 0)
+        # padded by one block so that the last block's slice stays inside
+        xs = xn[jnp.pad(order // k, (0, rows))]
+    with jax.named_scope("moe_experts"):
+
+        def body(i, ys):
+            e = expert[i]
+            xb = lax.dynamic_slice_in_dim(xs, at[i], rows)
+            up = jnp.dot(xb, lp["eu"][e], preferred_element_type=jnp.float32)
+            gate = jnp.dot(xb, lp["eg"][e], preferred_element_type=jnp.float32)
+            mid = (jax.nn.silu(gate) * up).astype(xn.dtype)
+            yb = jnp.dot(mid, lp["ed"][e], preferred_element_type=jnp.float32)
+            return lax.dynamic_update_slice_in_dim(ys, yb, at[i], axis=0)
+
+        # no fill: every row a token reads is written below or by a block
+        ys = lax.dynamic_update_slice_in_dim(
+            lax.empty((n + rows, h), jnp.float32), jnp.zeros((1, h), jnp.float32), n + rows - 1, 0)
+        ys = lax.fori_loop(0, ends[-1], body, ys)
+    with jax.named_scope("moe_combine"):
+        _, inv = lax.sort((order, slot), num_keys=1)
+        inv = jnp.where(key < held, inv, n + rows - 1).reshape(t, k).T
+        w = top_w.T[..., None]
+        # a token's j-th rows, one gather a j: no [T, k, h] array is formed
+        y = lax.fori_loop(0, k, lambda j, acc: acc + ys[inv[j]] * w[j],
+                          jnp.zeros((t, h), jnp.float32))
+    return y, sizes, live.sum()
+
+
+def _added_by_block(cfg, lp: dict, xn, top_w, key, rows: int):
+    """`routed_experts` where `sorted_combine` does not hold: each block
+    gathers its rows by token and adds its weighted output back to them."""
+    t, h = xn.shape
+    k, held = cfg.num_experts_per_tok, cfg.share.experts_held
+    with jax.named_scope("moe_route"):
         order = jnp.argsort(key, stable=True)
         # padded by one block so that the last block's slice stays inside
         tok = jnp.pad(order // k, (0, rows)).astype(jnp.int32)

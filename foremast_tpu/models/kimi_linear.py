@@ -95,6 +95,7 @@ from foremast_tpu.models.cohere2_moe import (  # the code both backbones share
     routed_experts,
     router_scores,
     series_scale,
+    sorted_combine,
     tensor,
     tokenize,
 )
@@ -398,13 +399,16 @@ def fused_window_kda(cfg: KimiLinearConfig, tokens: int, write_at=None) -> bool:
     return fused_applies(cfg.kda_head_dim, tokens, write_at)
 
 
-WINDOW_COUNTERS = ("latent_positions", "state_bytes_read", "fused_kda_tokens")
+WINDOW_COUNTERS = ("latent_positions", "state_bytes_read", "fused_kda_tokens",
+                   "sorted_moe_tokens")
 
 
 def window_counters(cfg: KimiLinearConfig, ctx_cap: int, valid, attended) -> dict:
     """What one window dispatch adds to the detector's counters beside the
     tokens it scored (`valid` [S, W] real points): `fused_kda_tokens`, the
     tokens of a dispatch whose KDA layers took the fused kernel;
+    `sorted_moe_tokens`, those whose expert rows came back to them by the
+    inverse gather of `routed_experts` (`sorted_combine`);
     `latent_positions`, the
     positions a MLA layer's queries attended to (every cached one, and the
     window's own up to the token), summed over tokens: `attended` [S], what
@@ -418,6 +422,7 @@ def window_counters(cfg: KimiLinearConfig, ctx_cap: int, valid, attended) -> dic
         "latent_positions": int(np.asarray(attended, np.int64).sum()) // max(cfg.n_mla, 1),
         "state_bytes_read": int(valid.any(axis=1).sum()) * state_bytes(cfg),
         "fused_kda_tokens": int(valid.sum()) if fused_window_kda(cfg, valid.shape[1]) else 0,
+        "sorted_moe_tokens": int(valid.sum()) if sorted_combine(cfg) else 0,
     }
 
 

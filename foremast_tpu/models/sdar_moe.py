@@ -85,6 +85,7 @@ from foremast_tpu.models.cohere2_moe import (  # the code the backbones share
     finish_rows,
     routed_experts,
     series_scale,
+    sorted_combine,
     tensor,
 )
 from foremast_tpu.models.kimi_linear import head_scores, rms_norm
@@ -313,7 +314,7 @@ def fused_window_attention(cfg: SdarMoeConfig, ctx_cap: int, points: int) -> boo
     return fused_applies(cfg.head_dim, cfg.dtype.itemsize, window_tokens(cfg, points), (ctx_cap,))
 
 
-WINDOW_COUNTERS = ("denoise_tokens", "clean_tokens", "fused_attn_tokens")
+WINDOW_COUNTERS = ("denoise_tokens", "clean_tokens", "fused_attn_tokens", "sorted_moe_tokens")
 
 
 def window_counters(cfg: SdarMoeConfig, ctx_cap: int, valid, denoise, clean) -> dict:
@@ -323,12 +324,15 @@ def window_counters(cfg: SdarMoeConfig, ctx_cap: int, valid, denoise, clean) -> 
     blocks read, both as `score_window` itself counted them a sequence from
     the liveness its attention masks are taken under (`denoise`, `clean`
     [S]); `fused_attn_tokens`, the scored tokens of dispatches whose
-    attention took the fused kernel."""
+    attention took the fused kernel; `sorted_moe_tokens`, those whose
+    expert rows came back to them by the inverse gather of `routed_experts`
+    (`sorted_combine`)."""
     fused = fused_window_attention(cfg, ctx_cap, valid.shape[1])
     return {
         "denoise_tokens": int(np.asarray(denoise, np.int64).sum()),
         "clean_tokens": int(np.asarray(clean, np.int64).sum()),
         "fused_attn_tokens": int(valid.sum()) if fused else 0,
+        "sorted_moe_tokens": int(valid.sum()) if sorted_combine(cfg) else 0,
     }
 
 

@@ -337,6 +337,14 @@ class WorkerMetrics:
             ["kind"],
             registry=reg,
         )
+        self.backbone_sorted_moe_tokens = Counter(
+            "foremast_backbone_sorted_moe_tokens_total",
+            "of the window tokens, those of dispatches whose expert rows "
+            "came back to them by one inverse gather of the rows sorted by "
+            "expert",
+            ["kind"],
+            registry=reg,
+        )
         self.backbone_latent_positions = Counter(
             "foremast_backbone_latent_positions_total",
             "positions the window tokens' latent attention attended to "
@@ -638,15 +646,16 @@ class WorkerMetrics:
         """Feed a model-backed kind's cumulative counters
         (`BackboneDetector.counters()`); deltas are exported, as in
         `observe_arena`. A counter the kind's model does not keep
-        (`fused_attn_tokens`, `fused_kda_tokens`, `latent_positions`,
-        `state_bytes_read`, `denoise_tokens`, `clean_tokens`) exports
-        nothing."""
+        (`fused_attn_tokens`, `fused_kda_tokens`, `sorted_moe_tokens`,
+        `latent_positions`, `state_bytes_read`, `denoise_tokens`,
+        `clean_tokens`) exports nothing."""
         last = self._backbone_last.setdefault(kind, {})
         flat = {
             "prefill_tokens": self.backbone_prefill_tokens,
             "window_tokens": self.backbone_window_tokens,
             "fused_attn_tokens": self.backbone_fused_attn_tokens,
             "fused_kda_tokens": self.backbone_fused_kda_tokens,
+            "sorted_moe_tokens": self.backbone_sorted_moe_tokens,
             "latent_positions": self.backbone_latent_positions,
             "state_bytes_read": self.backbone_state_bytes_read,
             "denoise_tokens": self.backbone_denoise_tokens,

@@ -40,6 +40,7 @@ ALLOWED_LABELS: dict[str, frozenset[str]] = {
     "foremast_backbone_window_tokens": frozenset({"kind"}),
     "foremast_backbone_fused_attn_tokens": frozenset({"kind"}),
     "foremast_backbone_fused_kda_tokens": frozenset({"kind"}),
+    "foremast_backbone_sorted_moe_tokens": frozenset({"kind"}),
     "foremast_backbone_latent_positions": frozenset({"kind"}),
     "foremast_backbone_state_bytes_read": frozenset({"kind"}),
     "foremast_backbone_denoise_tokens": frozenset({"kind"}),
@@ -167,6 +168,12 @@ FAMILY_DOCS: dict[str, str] = {
         "kind `backbone_kda` only: of those, tokens of dispatches whose KDA "
         "chunk algebra took the fused TPU kernel; 0 off a TPU or with heads "
         "that are no lane tile"
+    ),
+    "foremast_backbone_sorted_moe_tokens": (
+        "every model-backed kind: of those, tokens of dispatches whose "
+        "expert rows came back to them by one inverse gather of the rows "
+        "sorted by expert (`cohere2_moe.sorted_combine`: a share holding at "
+        "least half the experts); 0 where blocks add their rows back"
     ),
     "foremast_backbone_latent_positions": (
         "positions the window tokens' latent attention attended to, cached "
@@ -448,7 +455,7 @@ def default_registry_families():
     shared = {
         "prefill_tokens": 1, "window_tokens": 1, "cache_rows_live": 1,
         "cache_hits": 1, "cache_misses": 1, "dropped_tokens": 0,
-        "expert_tokens": [1, 1],
+        "expert_tokens": [1, 1], "sorted_moe_tokens": 1,
     }
     metrics.observe_backbone("backbone", {**shared, "fused_attn_tokens": 1})
     metrics.observe_backbone(

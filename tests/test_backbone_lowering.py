@@ -1,8 +1,8 @@
 """Kinds `backbone` and `backbone_kda` keep their programs while code they
-share grows a third user (`models/sdar_moe.py` reaches
+share with a third user changes (`models/sdar_moe.py` reaches
 `cohere2_attention.fused_attend_rows`, `cohere2_moe.attend` and
 `cohere2_moe.routed_experts`): the window program and the prefill of
-Cohere2-MoE and of Kimi-Linear lower to the very text they lowered to before
+Cohere2-MoE and of Kimi-Linear lower to the very text pinned here
 (sha256 of `lower().as_text()` and of the jaxpr), at the small widths of
 their model tests and
 at the published widths of their cells, on the path every CPU run takes and
@@ -44,16 +44,16 @@ DIGESTS = {
         "4661c4ff1e35d9ff4eb433575bac6bf36eadfbbaca78775a9828258cdb466796",
     ),
     ("kimi_linear", "tiny", "cpu"): (
-        "8eccb911a4d562481bdff4043865e660a9cdaf64d653b52eaedc78647f2ca537",
-        "2d8bf9ca64a4150cc062ce57ab09642fb540d9ea6a133fadab1833286e618fd3",
+        "64d189bf356f8793ae3b468a5d8c0db126fd3652fa7567b10fddf736460986d6",
+        "4bbb43bf8e6156e666a259437a5170f63d42000b07efe0c7e1b6267b9676314f",
     ),
     ("kimi_linear", "published", "cpu"): (
-        "4c38916246ebade6d37bb0e28f6030c03ec5f21b50292ee7522b0e3973b8efff",
-        "e67acf051d00746853e40c67aeaa45dae5ecaa8f19ef5231323c6dff0a9a5d90",
+        "3c6430f720ee19140486029fef88276c2e4665f5c35521f74a77ecd08f0bad98",
+        "e3635a9d2835e41d6675574cd8ee42fdbe9db15012f69b1fe45bcec64374e8c3",
     ),
     ("kimi_linear", "published", "tpu"): (
-        "faf29953eaef4aa127589b404983480db64591786b18d018fc7019b2998978d9",
-        "e67acf051d00746853e40c67aeaa45dae5ecaa8f19ef5231323c6dff0a9a5d90",
+        "f02471233cf7fbcf9573d5d0bd69ffa59a8daa14bc1ed972906ca161b38180f1",
+        "0e825cc33d0b09dc52666e1a86db95b8fbd102c1dee2a697c693c0b705efe3b4",
     ),
 }
 

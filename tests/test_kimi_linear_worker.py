@@ -97,6 +97,7 @@ def test_cold_tick_two_warm_ticks_and_a_followed_job_leave_the_rows_as_they_were
     assert c["latent_positions"] == TICKS * 7 * (WINDOW * (CONTEXT - 1) + WINDOW * (WINDOW + 1) // 2)
     assert "fused_attn_tokens" not in c and len(c["expert_tokens"]) == 8  # Cohere2's kernel's counter
     assert c["fused_kda_tokens"] == 0  # off a TPU every dispatch runs `kda_chunks`
+    assert c["sorted_moe_tokens"] == c["window_tokens"]
     assert worker._mvj.backbone_counters() == c
     assert worker._fast_kinds[KIND] == (TICKS - 1) * len(SERVICES) and worker._fast_kinds["backbone"] == 0
     # the gauge families carry the kind, and only the counters its model keeps
@@ -107,6 +108,7 @@ def test_cold_tick_two_warm_ticks_and_a_followed_job_leave_the_rows_as_they_were
     }
     assert samples[("foremast_backbone_window_tokens_total", KIND)] == c["window_tokens"]
     assert samples[("foremast_backbone_latent_positions_total", KIND)] == c["latent_positions"]
+    assert samples[("foremast_backbone_sorted_moe_tokens_total", KIND)] == c["window_tokens"]
     assert samples[("foremast_backbone_cache_rows_live", KIND)] == 7
     assert {kind for _, kind in samples} == {KIND}
     worker.close()

@@ -220,7 +220,8 @@ def test_a_30_point_window_runs_120_noisy_and_28_clean_tokens():
     assert denoise.tolist() == [120, 0] and clean.tolist() == [28, 0]
     assert int(counts.sum()) == (120 + 28) * 4 * 4
     got = m.window_counters(cfg, CAP, valid, denoise, clean)
-    assert got == {"denoise_tokens": 120, "clean_tokens": 28, "fused_attn_tokens": 0}
+    assert got == {"denoise_tokens": 120, "clean_tokens": 28, "fused_attn_tokens": 0,
+                   "sorted_moe_tokens": 30}
     assert set(got) == set(m.WINDOW_COUNTERS)
     assert m.window_tokens(cfg, 32) == 160
 

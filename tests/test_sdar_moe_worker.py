@@ -113,6 +113,7 @@ def test_cold_tick_two_warm_ticks_and_a_followed_job_match_the_reference(model_f
     assert c["denoise_tokens"] == TICKS * SEQS * 4 * WINDOW
     assert c["clean_tokens"] == TICKS * SEQS * 4
     assert c["fused_attn_tokens"] == 0  # off a TPU every dispatch attends through `attend`
+    assert c["sorted_moe_tokens"] == c["window_tokens"]
     assert len(c["expert_tokens"]) == 8
     assert worker._mvj.backbone_counters() == c
     assert worker._fast_kinds[KIND] == (TICKS - 1) * len(SERVICES)
@@ -124,6 +125,7 @@ def test_cold_tick_two_warm_ticks_and_a_followed_job_match_the_reference(model_f
     assert samples[("foremast_backbone_denoise_tokens_total", KIND)] == c["denoise_tokens"]
     assert samples[("foremast_backbone_clean_tokens_total", KIND)] == c["clean_tokens"]
     assert samples[("foremast_backbone_window_tokens_total", KIND)] == c["window_tokens"]
+    assert samples[("foremast_backbone_sorted_moe_tokens_total", KIND)] == c["window_tokens"]
     assert samples[("foremast_backbone_cache_rows_live", KIND)] == SEQS
     assert {kind for _, kind in samples} == {KIND}
     worker.close()
