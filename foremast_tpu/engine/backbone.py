@@ -35,6 +35,7 @@ import importlib
 import json
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -51,6 +52,19 @@ MODELS = {
 
 def _round_up(n: int, to: int) -> int:
     return -(-n // to) * to
+
+
+def _hbm(array) -> dict:
+    """The allocator's state of the device that holds `array`, as span
+    attrs: `hbm_in_use` and `hbm_largest_free`, where the backend reports
+    them (a CPU reports neither)."""
+    stats = next(iter(array.devices())).memory_stats() or {}
+    return {
+        attr: int(stats[key])
+        for attr, key in (("hbm_in_use", "bytes_in_use"),
+                          ("hbm_largest_free", "largest_free_block_bytes"))
+        if key in stats
+    }
 
 
 def load_model(model_file: str | None, model_types: tuple):
@@ -104,7 +118,7 @@ class BackboneDetector:
         self.dropped_tokens = 0
         # the model's own counters of its window dispatches (every model:
         # `sorted_moe_tokens`; Cohere2: `fused_attn_tokens`; Kimi-Linear:
-        # `latent_positions`, `state_bytes_read`, `fused_kda_tokens`; SDAR:
+        # `latent_positions`, `fused_kda_tokens`; SDAR:
         # `denoise_tokens`, `clean_tokens`, `fused_attn_tokens`)
         self.model_counters = dict.fromkeys(model.WINDOW_COUNTERS, 0)
 
@@ -231,12 +245,24 @@ class BackboneDetector:
             scores, counts, dropped, *counted = model.score_window(
                 cfg, self.params, arena.state, *handed
             )
+        out = (scores, counts, dropped, counted)
+        # the wait for the program alone: device idle under it is time the
+        # runtime had not yet run the program (a launch, a transfer, an
+        # allocation) or not yet told the host it ended, where idle under
+        # `judge.decode` is the copy
+        with span("judge.result_wait", stage="decode", rows=sb, device=True) as sp:
+            jax.block_until_ready(out)
+            if sp is not None:
+                note(sp, **_hbm(scores))
         with span("judge.decode", stage="decode", rows=sb, device=True):
-            scores = np.asarray(scores)[:s]
-        self.window_tokens += int(valid.sum())
-        counted = [np.asarray(c)[:s] for c in counted]
-        for name, n in model.window_counters(cfg, self.ctx_cap, valid[:s], *counted).items():
-            self.model_counters[name] += n
-        self.expert_tokens += np.asarray(counts)
-        self.dropped_tokens += int(dropped)
+            scores, counts, dropped, counted = jax.device_get(out)
+            scores = scores[:s]
+            self.window_tokens += int(valid.sum())
+            counted = [c[:s] for c in counted]
+            for name, n in model.window_counters(
+                cfg, self.ctx_cap, valid[:s], *counted
+            ).items():
+                self.model_counters[name] += n
+            self.expert_tokens += counts
+            self.dropped_tokens += int(dropped)
         return scores

@@ -399,8 +399,7 @@ def fused_window_kda(cfg: KimiLinearConfig, tokens: int, write_at=None) -> bool:
     return fused_applies(cfg.kda_head_dim, tokens, write_at)
 
 
-WINDOW_COUNTERS = ("latent_positions", "state_bytes_read", "fused_kda_tokens",
-                   "sorted_moe_tokens")
+WINDOW_COUNTERS = ("latent_positions", "fused_kda_tokens", "sorted_moe_tokens")
 
 
 def window_counters(cfg: KimiLinearConfig, ctx_cap: int, valid, attended) -> dict:
@@ -413,14 +412,10 @@ def window_counters(cfg: KimiLinearConfig, ctx_cap: int, valid, attended) -> dic
     positions a MLA layer's queries attended to (every cached one, and the
     window's own up to the token), summed over tokens: `attended` [S], what
     `score_window` itself counted under its softmax's masks over all its
-    MLA layers, so it falls if the program ever attends to less;
-    `state_bytes_read`, the float32 state and convolution tails the
-    dispatch's real sequences own: the request's size, not read off the
-    program."""
+    MLA layers, so it falls if the program ever attends to less."""
     valid = np.asarray(valid, bool)
     return {
         "latent_positions": int(np.asarray(attended, np.int64).sum()) // max(cfg.n_mla, 1),
-        "state_bytes_read": int(valid.any(axis=1).sum()) * state_bytes(cfg),
         "fused_kda_tokens": int(valid.sum()) if fused_window_kda(cfg, valid.shape[1]) else 0,
         "sorted_moe_tokens": int(valid.sum()) if sorted_combine(cfg) else 0,
     }

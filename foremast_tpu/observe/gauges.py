@@ -352,13 +352,6 @@ class WorkerMetrics:
             ["kind"],
             registry=reg,
         )
-        self.backbone_state_bytes_read = Counter(
-            "foremast_backbone_state_bytes_read_total",
-            "bytes of recurrent state and convolution tails the window "
-            "dispatches read from the cache rows",
-            ["kind"],
-            registry=reg,
-        )
         self.backbone_denoise_tokens = Counter(
             "foremast_backbone_denoise_tokens_total",
             "token-forwards of the noisy block copies a block-diffusion "
@@ -647,8 +640,8 @@ class WorkerMetrics:
         (`BackboneDetector.counters()`); deltas are exported, as in
         `observe_arena`. A counter the kind's model does not keep
         (`fused_attn_tokens`, `fused_kda_tokens`, `sorted_moe_tokens`,
-        `latent_positions`, `state_bytes_read`, `denoise_tokens`,
-        `clean_tokens`) exports nothing."""
+        `latent_positions`, `denoise_tokens`, `clean_tokens`) exports
+        nothing."""
         last = self._backbone_last.setdefault(kind, {})
         flat = {
             "prefill_tokens": self.backbone_prefill_tokens,
@@ -657,7 +650,6 @@ class WorkerMetrics:
             "fused_kda_tokens": self.backbone_fused_kda_tokens,
             "sorted_moe_tokens": self.backbone_sorted_moe_tokens,
             "latent_positions": self.backbone_latent_positions,
-            "state_bytes_read": self.backbone_state_bytes_read,
             "denoise_tokens": self.backbone_denoise_tokens,
             "clean_tokens": self.backbone_clean_tokens,
             "cache_hits": self.backbone_cache_hits,

@@ -42,7 +42,6 @@ ALLOWED_LABELS: dict[str, frozenset[str]] = {
     "foremast_backbone_fused_kda_tokens": frozenset({"kind"}),
     "foremast_backbone_sorted_moe_tokens": frozenset({"kind"}),
     "foremast_backbone_latent_positions": frozenset({"kind"}),
-    "foremast_backbone_state_bytes_read": frozenset({"kind"}),
     "foremast_backbone_denoise_tokens": frozenset({"kind"}),
     "foremast_backbone_clean_tokens": frozenset({"kind"}),
     "foremast_backbone_cache_rows_live": frozenset({"kind"}),
@@ -180,10 +179,6 @@ FAMILY_DOCS: dict[str, str] = {
         "and the window's own, as the window program counts them (kind "
         "`backbone_kda`); over the window tokens it is the context a token "
         "really saw"
-    ),
-    "foremast_backbone_state_bytes_read": (
-        "bytes of recurrent state and convolution tails the window "
-        "dispatches read from the cache rows (kind `backbone_kda`)"
     ),
     "foremast_backbone_denoise_tokens": (
         "token-forwards of the noisy block copies the window program ran, "
@@ -460,7 +455,7 @@ def default_registry_families():
     metrics.observe_backbone("backbone", {**shared, "fused_attn_tokens": 1})
     metrics.observe_backbone(
         "backbone_kda",
-        {**shared, "latent_positions": 1, "state_bytes_read": 1, "fused_kda_tokens": 1},
+        {**shared, "latent_positions": 1, "fused_kda_tokens": 1},
     )
     metrics.observe_backbone(
         "backbone_diffusion",

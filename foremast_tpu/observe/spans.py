@@ -35,7 +35,10 @@ The worker tick's stage spans (``TICK_STAGES``) close the tick's span
 tree: on each of a sweep's threads (prefetch, tick, writer) the stage
 spans are SIBLINGS that never nest, so ``foremast_tick_stage_seconds``
 sums are self time per thread. They open per slice, per dispatch group
-or per wait — never per document.
+or per wait — never per document. The one exception is stage ``gc``: a
+generation-2 garbage collection, recorded as a span ``python.gc`` on
+whatever thread ran it (it stops every Python thread), nested inside
+whatever span it interrupted.
 """
 
 from __future__ import annotations
@@ -43,6 +46,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import contextvars
+import gc
 import json
 import logging
 import os
@@ -71,8 +75,10 @@ STAGE_BUCKETS = (
 # The canonical tick stages, in tick order (docs/observability.md has the
 # table: stage, span names, thread, attrs). `wait` is the tick thread
 # blocked on the sweep pipeline's other threads; `housekeeping` is the
-# sweep's head, slice boundaries and tail. Kept here so the metrics lint
-# and the docs can't drift from the emitters.
+# sweep's head, slice boundaries and tail (and the tracer's own
+# `trace.flush`). `gc` is the one stage that nests: a generation-2
+# collection's seconds are also inside the span it interrupted. Kept here
+# so the metrics lint and the docs can't drift from the emitters.
 TICK_STAGES = (
     "claim",
     "admit",
@@ -87,6 +93,7 @@ TICK_STAGES = (
     "write_back",
     "wait",
     "housekeeping",
+    "gc",
 )
 
 
@@ -297,6 +304,15 @@ class Tracer:
         # serializes dump_jsonl between explicit flush() callers and the
         # background autoflush thread (both write the same target path)
         self._io_lock = threading.Lock()
+        # events of the generation-2 collections the gc hook saw, put in
+        # the ring at the next span's close (`_finish`): a collection can
+        # stop a thread inside the ring's lock
+        self._gc_done: collections.deque = collections.deque(maxlen=1024)
+        # the `gc` series, made now: a run with no generation-2 collection
+        # reads 0, not absent, and the hook observes it without `labels()`
+        # and the family's lock
+        self._gc_hist = self._hist.labels(stage="gc") if self._hist is not None else None
+        _watch_gc(self)
 
     # -- span creation ---------------------------------------------------
 
@@ -337,6 +353,13 @@ class Tracer:
             self._finish(s, root=parent is None)
 
     def _finish(self, s: Span, root: bool) -> None:
+        if self._gc_done:
+            self._record_gc()
+        self._record(s)
+        if root and self.ring is not None:
+            self._autoflush()
+
+    def _record(self, s: Span) -> None:
         if s.stage is not None:
             # accumulate: a tick may open several spans per stage (chunked
             # fetch/decide/write-back, per-bucket score) and the breakdown
@@ -348,8 +371,14 @@ class Tracer:
                 self._hist.labels(stage=s.stage).observe(s.duration)
         if self.ring is not None:
             self.ring.add(s.to_event())
-            if root:
-                self._autoflush()
+
+    def _record_gc(self) -> None:
+        while True:
+            try:
+                event = self._gc_done.popleft()
+            except IndexError:
+                return
+            self.ring.add(event)
 
     def _autoflush(self) -> None:
         """Flush on a daemon thread: root-span exit runs on whatever
@@ -373,8 +402,11 @@ class Tracer:
             self._last_flush = time.monotonic()
 
         def _run():
+            # the dump serializes the whole ring under the GIL: a span of
+            # its own, outside any tick's tree, names that time
+            s = Span("trace.flush", _new_id(), "", stage="housekeeping")
             try:
-                self.flush()
+                s.attrs["events"] = self._dump(self.trace_path())
             except Exception as e:  # noqa: BLE001 - tracing must never break serving
                 # warn ONCE: an unwritable FOREMAST_TRACE_DIR otherwise
                 # fails every 10 s with zero signal until shutdown
@@ -387,6 +419,8 @@ class Tracer:
                         e,
                     )
             finally:
+                s.duration = time.perf_counter() - s._t0
+                self._record(s)
                 with self._flush_lock:
                     self._flush_active = False
 
@@ -413,20 +447,26 @@ class Tracer:
         if self.ring is None:
             return None
         target = path or self.trace_path()
+        self._dump(target)
+        return target
+
+    def _dump(self, target: str) -> int:
+        """Write the ring to `target`; -> the events written."""
+        self._record_gc()
         with self._io_lock:
             os.makedirs(os.path.dirname(target) or ".", exist_ok=True)
             # _io_lock exists to serialize exactly this dump between
             # explicit flush() callers and the autoflush daemon — two
             # unguarded writers would truncate each other's temp file
             # foremast: ignore[blocking-under-lock]
-            self.ring.dump_jsonl(target)
+            n = self.ring.dump_jsonl(target)
         # `_last_flush` is _flush_lock state (the autoflush scheduler's
         # elapsed check reads it there); stamping it under _io_lock
         # raced the two critical sections against each other
         # (thread-escape mixed-guard finding)
         with self._flush_lock:
             self._last_flush = time.monotonic()
-        return target
+        return n
 
     def debug_state(self) -> dict:
         return {
@@ -436,6 +476,62 @@ class Tracer:
             "spans_total": self.ring.total if self.ring is not None else 0,
             "last_stage_seconds": dict(self.last_stage_seconds),
         }
+
+
+# ---------------------------------------------------------------------------
+# generation-2 garbage collections as spans
+# ---------------------------------------------------------------------------
+
+# the newest Tracer, weakly: the one the gc hook records into (one worker,
+# service or controller a process; a test's later Tracer takes over)
+_gc_tracer = None
+# (tracer, span) of the generation-2 collection in progress: collections
+# never overlap, whatever thread runs them
+_gc_open = None
+
+
+def _watch_gc(tracer: Tracer) -> None:
+    global _gc_tracer
+    if _gc_tracer is None:
+        gc.callbacks.append(_on_gc)
+    _gc_tracer = weakref.ref(tracer)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks` hook: a generation-2 collection becomes a span
+    `python.gc` (stage `gc`) of the hook's tracer, on the thread that ran
+    it and under whatever span is open there, if one is. Its seconds go
+    to the stage at once, so a window's stage seconds hold the window's
+    collections and no earlier one; its event waits for the next span's
+    close, since the collection may have stopped this thread inside the
+    ring's lock."""
+    if info["generation"] != 2:
+        return
+    global _gc_open
+    if phase == "start":
+        tracer = _gc_tracer()
+        if tracer is None:
+            return
+        parent = current_span()
+        s = Span(
+            "python.gc",
+            parent.trace_id if parent is not None else _new_id(),
+            parent.span_id if parent is not None else "",
+            stage="gc",
+            attrs={"generation": 2},
+        )
+        _gc_open = (tracer, s)
+    elif _gc_open is not None:
+        tracer, s = _gc_open
+        _gc_open = None
+        s.duration = time.perf_counter() - s._t0
+        s.attrs.update(collected=info["collected"], uncollectable=info["uncollectable"])
+        seconds = tracer.last_stage_seconds
+        seconds["gc"] = seconds.get("gc", 0.0) + s.duration
+        if tracer._gc_hist is not None:
+            tracer._gc_hist.observe(s.duration)
+        if tracer.ring is not None:
+            tracer._gc_done.append(s.to_event())
 
 
 # ---------------------------------------------------------------------------

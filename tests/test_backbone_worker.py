@@ -215,6 +215,63 @@ def test_program_scores_match_the_reference_through_the_judge(model_file):
     assert det.counters()["cache_hits"] == TICKS * len(keys)
 
 
+def test_score_reads_back_its_dispatch_under_stage_spans_alone(model_file, monkeypatch, tmp_path):
+    """One warm `score` under a Tracer: six stage spans, in order, siblings
+    on one thread; the wait for the dispatch runs under `judge.result_wait`,
+    and its one read-back and the counting under `judge.decode`."""
+    import jax
+    from prometheus_client import CollectorRegistry
+
+    from foremast_tpu.engine import backbone
+    from foremast_tpu.observe.spans import Tracer, current_span
+
+    kind, d, _ = model_file
+    fleet = Fleet()
+    det = backbone.BackboneDetector(model_types=(d["model_type"],))
+    keys = [(kind, s, a, "h") for s, al in SERVICES.items() for a in al]
+    scales = np.array(
+        [e[0] for e in det.ensure(keys, [fleet.hist[k[1:3]] for k in keys])], np.float32
+    )
+    cur = np.stack([fleet.windows[0][key[1:3]] for key in keys])
+    valid = np.ones(cur.shape, bool)
+    seen = []
+
+    def under_span(name, fn):
+        def wrapped(*a, **kw):
+            seen.append((name, current_span().name))
+            return fn(*a, **kw)
+
+        return wrapped
+
+    monkeypatch.setattr(
+        det.model, "window_counters", under_span("window_counters", det.model.window_counters)
+    )
+    monkeypatch.setattr(jax, "device_get", under_span("device_get", jax.device_get))
+    monkeypatch.setattr(
+        jax, "block_until_ready", under_span("block_until_ready", jax.block_until_ready)
+    )
+    tracer = Tracer(service="test", registry=CollectorRegistry(), trace_dir=str(tmp_path))
+    with tracer.span("worker.tick") as root:
+        det.score(keys, scales, cur, valid)
+    staged = [
+        e for e in tracer.ring.snapshot() if "stage" in e["args"] and e["name"] != "python.gc"
+    ]
+    assert [e["name"] for e in staged] == [
+        "judge.tokenize", "judge.arena_assemble", "judge.h2d", "judge.score",
+        "judge.result_wait", "judge.decode",
+    ]
+    assert {e["args"]["parent_id"] for e in staged} == {root.span_id}
+    assert len({e["tid"] for e in staged}) == 1
+    for a, b in zip(staged, staged[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1.0, (a["name"], b["name"])
+    assert seen == [
+        ("block_until_ready", "judge.result_wait"),
+        ("device_get", "judge.decode"),
+        ("window_counters", "judge.decode"),
+    ]
+    assert det.counters()["window_tokens"] == valid.sum()
+
+
 def test_a_recycled_row_sends_its_document_back_to_the_prefill(model_file, monkeypatch):
     """A cache smaller than the fleet recycles rows; the document whose row
     went finds no warm entry and is prefilled again: slower, never wrong."""

@@ -6,7 +6,12 @@ from __future__ import annotations
 
 import pytest
 
-from chipbench.readers import idle_gap_pct, span_attr_per_kwin, thread_unspanned_pct
+from chipbench.readers import (
+    idle_gap_pct,
+    span_attr_per_kwin,
+    span_ms_max,
+    thread_unspanned_pct,
+)
 
 TICK, PREFETCH = 11, 22
 
@@ -122,6 +127,47 @@ def test_idle_gap_is_zero_when_every_gap_has_a_name():
 )
 def test_idle_gap_is_none_without_a_trace(rec):
     assert idle_gap_pct.read(rec, GAP) is None
+
+
+# -- span_ms_max ------------------------------------------------------------
+
+WAIT = {"span": "judge.result_wait"}
+
+
+def test_span_ms_max_is_the_windows_longest_span_in_ms():
+    rec = _record(
+        [
+            # set-up's longest (a compile) is not the window's
+            _root(0, 9_000_000, trace="setup"),
+            _span("judge.result_wait", 10, 8_000_000, trace="setup", stage="decode"),
+            _root(10_000_000, 300_000, trace="t1"),
+            _span("judge.result_wait", 10_000_010, 60_000, trace="t1", stage="decode"),
+            _root(11_000_000, 4_300_000, trace="t2"),
+            _span("judge.result_wait", 11_000_010, 4_190_000, trace="t2", stage="decode"),
+            _span("judge.decode", 15_190_010, 5_000_000, trace="t2", stage="decode"),
+        ],
+        sweeps=2,
+    )
+    assert span_ms_max.read(rec, WAIT) == pytest.approx(4190.0)
+    assert span_ms_max.read(rec, {"span": "judge.decode"}) == pytest.approx(5000.0)
+
+
+@pytest.mark.parametrize(
+    "spans",
+    [
+        [],
+        # the parent commit: the root and `judge.decode`, no result wait
+        [_root(1000, 100), _span("judge.decode", 1010, 50, stage="decode")],
+        # the span in set-up's tick only
+        [
+            _root(0, 100, trace="setup"),
+            _span("judge.result_wait", 10, 5, trace="setup", stage="decode"),
+            _root(1000, 100),
+        ],
+    ],
+)
+def test_span_ms_max_is_none_without_the_span(spans):
+    assert span_ms_max.read(_record(spans, sweeps=1), WAIT) is None
 
 
 # -- span_attr_per_kwin -----------------------------------------------------

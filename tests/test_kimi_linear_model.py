@@ -350,4 +350,3 @@ def test_window_counters_count_what_the_program_attended_to():
     got = m.window_counters(cfg, 24, valid, attended)
     assert set(got) == set(m.WINDOW_COUNTERS)
     assert got["latent_positions"] == 6 * 19 + 21 + 6 * 10 + 21
-    assert got["state_bytes_read"] == 2 * m.state_bytes(cfg)

@@ -93,7 +93,6 @@ def test_cold_tick_two_warm_ticks_and_a_followed_job_leave_the_rows_as_they_were
     c = det.counters()
     assert c["prefill_tokens"] == 7 * (CONTEXT - 1) and c["cache_misses"] == 7
     assert c["window_tokens"] == TICKS * 7 * WINDOW and c["dropped_tokens"] == 0
-    assert c["state_bytes_read"] == TICKS * 7 * det.model.state_bytes(det.cfg)
     assert c["latent_positions"] == TICKS * 7 * (WINDOW * (CONTEXT - 1) + WINDOW * (WINDOW + 1) // 2)
     assert "fused_attn_tokens" not in c and len(c["expert_tokens"]) == 8  # Cohere2's kernel's counter
     assert c["fused_kda_tokens"] == 0  # off a TPU every dispatch runs `kda_chunks`

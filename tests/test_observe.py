@@ -489,9 +489,12 @@ def test_e2e_judgment_trace_pipeline(demo_traces, tmp_path):
     assert len(stages) >= 5, stages
     assert {"claim", "metric_fetch", "score", "decide"} <= stages
 
-    # (2) Perfetto-loadable JSONL: valid events sharing ONE trace ID
+    # (2) Perfetto-loadable JSONL: valid events sharing ONE trace ID (the
+    # tracer's own spans, a generation-2 collection's and the background
+    # dump's, belong to no judgment)
     path = tracer.flush()
-    events = [json.loads(line) for line in open(path)]
+    dumped = [json.loads(line) for line in open(path)]
+    events = [e for e in dumped if e["name"] not in ("python.gc", "trace.flush")]
     assert len(events) >= 5
     trace_ids = {e["args"]["trace_id"] for e in events}
     assert len(trace_ids) == 1
@@ -520,8 +523,9 @@ def test_e2e_judgment_trace_pipeline(demo_traces, tmp_path):
     state = worker.debug_state()
     assert state["last_tick"]["docs"] == 1
     assert state["model_cache"]["fit_entries"] >= 1
-    assert state["trace"]["spans_total"] == len(events)
-    assert set(state["trace"]["last_stage_seconds"]) == stages
+    assert state["trace"]["spans_total"] >= len(dumped) >= len(events)
+    # `gc` exists at 0 from the tracer's start; a collection may add it
+    assert set(state["trace"]["last_stage_seconds"]) - {"gc"} == stages - {"gc"}
 
     # controller leg over a real HTTP kube fake: the unhealthy verdict
     # drives poll -> transition -> pause, counted and spanned
