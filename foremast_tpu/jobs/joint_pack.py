@@ -46,10 +46,11 @@ def _stack(series, sel, f: int, dtype):
 
 
 class JointGroup:
-    """One (mode, F) dispatch group of a slice. `sub` is what decide
-    reads — (doc, end_epoch, jinfo, ct, cv, n) a row, in `ok_joint`
-    order, `ct`/`cv` of a bulk row views into its class's stacks — and
-    `fill` builds the buffers `joint_columnar` takes."""
+    """One (mode, F) dispatch group of a slice. `sub` holds (doc,
+    end_epoch, jinfo, ct, cv, n) a row, in `ok_joint` order, `ct`/`cv` of
+    a bulk row views into its class's stacks; `fill` builds the buffers
+    `joint_columnar` takes, and decide (`jobs/joint_decide.py`) reads the
+    flagged points' stamps back through `stamps_at`."""
 
     __slots__ = ("mode", "f", "sub", "classes")
 
@@ -57,8 +58,9 @@ class JointGroup:
         self.mode = mode
         self.f = f
         self.sub = sub
-        # (n, rows, V [len(rows), F, n]) per class, rows ascending; a doc
-        # that came through align_series is a class of one row
+        # (n, rows, V [len(rows), F, n], T [len(rows), n]) per class, rows
+        # ascending; a doc that came through align_series is a class of
+        # one row
         self.classes = classes
 
     def fill(self):
@@ -71,7 +73,7 @@ class JointGroup:
         tcb = bucket_length(max(c[0] for c in self.classes))
         cur = np.zeros((s, self.f, tcb), np.float32)
         mask = np.zeros((s, tcb), bool)
-        for n, rows, v in self.classes:
+        for n, rows, v, _ in self.classes:
             if len(rows) == s:  # the group's one class: every row, in order
                 rows = slice(None)
             cur[rows, :, :n] = v
@@ -91,6 +93,17 @@ class JointGroup:
                 np.int32,
             )
         return cur, mask, gaps, keys, entries, metas
+
+    def stamps_at(self, rows, cols):
+        """The int64 window stamps of the points (rows[i], cols[i]), each
+        inside its row's n: one gather from the group's one class, or
+        from the classes spread over a [S, widest n] array."""
+        if len(self.classes) == 1:  # the group's one class: every row
+            return self.classes[0][3][rows, cols]
+        out = np.empty((len(self.sub), max(c[0] for c in self.classes)), np.int64)
+        for n, r, _, t in self.classes:
+            out[r, :n] = t
+        return out[rows, cols]
 
 
 def pack_slice(ok_joint):
@@ -160,9 +173,10 @@ def pack_slice(ok_joint):
             if not sel:
                 continue
             v = _stack(vs, sel, f, np.float32)
-            for j, ct, cv in zip(sel, t[:, 0], v):
+            t0 = t[:, 0]
+            for j, ct, cv in zip(sel, t0, v):
                 slots[j] = docs[j][0] + (ct, cv, n)
-            classes.append((n, sel, v))
+            classes.append((n, sel, v, t0))
             bulk += len(sel)
         for j in sorted(single):
             item, series = docs[j]
@@ -177,7 +191,7 @@ def pack_slice(ok_joint):
                 demoted.append(idx[j])
             else:
                 slots[j] = item + (ct, cv, n)
-                classes.append((n, [j], cv[None]))
+                classes.append((n, [j], cv[None], ct[None]))
                 aligned += 1
         packed = [j for j, it in enumerate(slots) if it is not None]
         if not packed:
@@ -185,7 +199,9 @@ def pack_slice(ok_joint):
         if len(packed) < s:
             # a doc's row in the group: its rank among the packed docs
             row = {j: r for r, j in enumerate(packed)}
-            classes = [(n, [row[j] for j in sel], v) for n, sel, v in classes]
+            classes = [
+                (n, [row[j] for j in sel], v, t) for n, sel, v, t in classes
+            ]
         groups.append(
             (
                 idx[packed[0]],

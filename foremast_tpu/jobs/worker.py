@@ -1024,9 +1024,11 @@ class BrainWorker:
     ) -> None:
         """Shared status transition for the object and columnar paths —
         one source of truth for the reference's state machine
-        (`converter.go:13-26`, fail-fast per `design.md:43`). Mutates the
-        doc; the caller persists (per-doc update or batched
-        update_many)."""
+        (`converter.go:13-26`, fail-fast per `design.md:43`); the joint
+        columnar decide runs the same transitions over a whole group
+        (`joint_decide.set_statuses`, held equal to this by
+        tests/test_joint_fast_tick.py). Mutates the doc; the caller
+        persists (per-doc update or batched update_many)."""
         # a missing/unparseable endTime must not make the job immortal:
         # finalize on the first judgment instead of re-checking forever
         past_end = end <= 0 or now >= end
@@ -1733,9 +1735,9 @@ class BrainWorker:
         other dispatches and the pipeline's release between this and
         the decide half (`_dispatch_slice`); `_fast_tick` runs one
         after the other."""
+        from foremast_tpu.jobs.joint_decide import past_end, set_statuses
         from foremast_tpu.jobs.joint_pack import pack_slice
 
-        observe = self.metrics.observe_doc if self.metrics else None
         hook = self.on_verdict
         judge = self._mvj
         updated: list = []
@@ -1753,17 +1755,26 @@ class BrainWorker:
             )
 
         if empty:
-            with span("worker.decide", stage="decide", docs=len(empty)):
-                for doc, end_epoch, jinfo, vals in empty:
-                    # UNKNOWN, object-path parity (`_unknown` —
-                    # baseline-less pairwise is (1.0, False))
-                    self._decide_status(doc, UNKNOWN, {}, now, end_epoch)
-                    self._log_judged(doc)
-                    updated.append(doc)
-                    counts[jinfo[0]] += 1
-                    if observe:
-                        observe(doc.status, len(jinfo[1]))
-                    if hook:
+            e = len(empty)
+            with span("worker.decide", stage="decide", docs=e, bulk=e):
+                # UNKNOWN, object-path parity (`_unknown` — baseline-less
+                # pairwise is (1.0, False))
+                docs = [it[0] for it in empty]
+                statuses = set_statuses(
+                    docs,
+                    past_end([it[1] for it in empty], now),
+                    [False] * e,
+                    (),
+                    clean=UNKNOWN,
+                )
+                updated += docs
+                for it in empty:
+                    counts[it[2][0]] += 1
+                self._account_judged(
+                    docs, statuses, sum(len(it[2][1]) for it in empty)
+                )
+                if hook:
+                    for doc, _end, jinfo, vals in empty:
                         vs = [
                             MetricVerdict(
                                 job_id=doc.id,
@@ -1813,58 +1824,68 @@ class BrainWorker:
     def _decide_joint_fast(self, issued: _JointIssued, now: float):
         """The other half of `_judge_joint_fast`: per dispatch group, in
         issue order, block for the flags (`judge.decode`) and decide
-        every doc. Statuses and anomaly pairs replicate the object
-        path's `_emit` exactly. Returns (updated_docs, demoted_docs,
-        per-kind counts)."""
-        observe = self.metrics.observe_doc if self.metrics else None
+        every doc (`joint_decide.decide_group`: a fixed number of array
+        operations a group and one plain pass over its docs). Statuses
+        and anomaly pairs replicate the object path's `_emit` exactly.
+        Only a wired `on_verdict` hook gets per-doc verdict objects.
+        Returns (updated_docs, demoted_docs, per-kind counts)."""
+        from foremast_tpu.jobs.joint_decide import decide_group
+
         hook = self.on_verdict
         judge = self._mvj
-        thr = float(
-            np.float32(judge.config.anomaly.rule_for(None).threshold)
-        )
         updated, counts = issued.updated, issued.counts
         for group, filled, pending in issued.groups:
             mode, f, sub = group.mode, group.f, group.sub
+            s = len(sub)
             # the gather goes through `joint_columnar`, the one return
             # by which joint flags reach the host (what wraps it to
             # alter an answer, as the benchmark's faults do, sees both
             # the one-call and the issue-then-gather form)
             flags = judge.joint_columnar(mode, *filled, issued=pending)
-            with span("worker.decide", stage="decide", docs=len(sub)) as sd:
-                unhealthy = points = 0
-                for i, (doc, end_epoch, jinfo, ct, cv, n) in enumerate(sub):
-                    fl = flags[i, :n]
-                    jv = UNHEALTHY if fl.any() else HEALTHY
-                    values_map = {}
-                    if jv == UNHEALTHY:
-                        ft = ct[fl]
-                        unhealthy += 1
-                        points += f * len(ft)
-                        for f_i, alias in enumerate(jinfo[1]):
-                            pairs = np.empty(2 * len(ft), np.float64)
-                            pairs[0::2] = ft
-                            pairs[1::2] = cv[f_i][fl]
-                            values_map[alias] = pairs.tolist()
-                    self._decide_status(doc, jv, values_map, now, end_epoch)
-                    self._log_judged(doc)
-                    updated.append(doc)
-                    counts[mode] += 1
-                    if observe:
-                        observe(doc.status, f)
-                    if hook:
-                        try:
-                            hook(
-                                doc,
-                                self._joint_verdicts(
-                                    doc, jinfo, ct, cv, n, fl, jv, thr
-                                ),
-                            )
-                        except Exception:
-                            log.exception(
-                                "on_verdict hook failed for %s", doc.id
-                            )
-                note(sd, unhealthy=unhealthy, payload_points=points)
+            with span("worker.decide", stage="decide", docs=s) as sd:
+                d = decide_group(group, flags, filled[3], filled[4], now)
+                updated += d.docs
+                counts[mode] += s
+                self._account_judged(d.docs, d.statuses, f * s)
+                if hook:
+                    self._hook_joint(group, d)
+                note(
+                    sd,
+                    unhealthy=d.statuses.get(STATUS_COMPLETED_UNHEALTH, 0),
+                    payload_points=f * d.points,
+                    bulk=s,
+                )
         return updated, issued.demoted, counts
+
+    def _account_judged(self, docs, statuses: dict, n_windows: int) -> None:
+        """A decided group's accounting: the worker's job and window
+        counters, one increment a status, and the judgment log line of
+        each doc that carries a trace ID (`_log_judged` is silent for
+        the others)."""
+        if self.metrics:
+            self.metrics.observe_docs(statuses, n_windows)
+        for doc in docs:
+            if doc.trace_id:
+                self._log_judged(doc)
+
+    def _hook_joint(self, group, d) -> None:
+        """`on_verdict` once a doc of a decided group, in row order."""
+        thr = float(
+            np.float32(self._mvj.config.anomaly.rule_for(None).threshold)
+        )
+        for (doc, _end, jinfo, ct, cv, n), hit, bad in zip(
+            group.sub, d.hit, d.flagged
+        ):
+            jv = UNHEALTHY if bad else HEALTHY
+            try:
+                self.on_verdict(
+                    doc,
+                    self._joint_verdicts(
+                        doc, jinfo, ct, cv, n, hit[:n], jv, thr
+                    ),
+                )
+            except Exception:
+                log.exception("on_verdict hook failed for %s", doc.id)
 
     def _joint_verdicts(self, doc, jinfo, ct, cv, n, fl, jv, thr):
         """Hook verdicts replicating the object path's `_emit`: per-alias

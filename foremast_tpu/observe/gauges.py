@@ -580,6 +580,13 @@ class WorkerMetrics:
         self.jobs.labels(status=status).inc()
         self.windows.inc(n_windows)
 
+    def observe_docs(self, status_counts: dict, n_windows: int) -> None:
+        """`observe_doc` for a group of docs: {status: docs} and their
+        windows, one increment a status (the same totals)."""
+        for status, n in status_counts.items():
+            self.jobs.labels(status=status).inc(n)
+        self.windows.inc(n_windows)
+
     def observe_device_mesh(self, state: dict) -> None:
         """Feed the worker's cumulative device_mesh varz section
         (BrainWorker._device_mesh_state); deltas keep the Prometheus

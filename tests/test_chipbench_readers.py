@@ -253,6 +253,51 @@ def test_pack_bulk_layer_reads_bulk_from_the_slices_first_pack_span():
     assert span_attr_per_kwin.read(rec, spec["params"]) is None
 
 
+def test_decide_bulk_layer_sums_bulk_over_the_joint_decide_spans():
+    """The decide layer file through the reader it names: every joint
+    `worker.decide` span (a dispatch group's, and the window-less docs')
+    carries `bulk`; the writer thread's univariate decide does not, and
+    set-up's spans do not count."""
+    import json
+    import os
+
+    from chipbench import readers
+
+    path = os.path.join(
+        os.path.dirname(readers.__file__), os.pardir, "layers",
+        "decide_bulk_docs_per_kwin.sweep.json",
+    )
+    with open(path) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "span_attr_per_kwin"
+    assert spec["params"] == {"span": "worker.decide", "attr": "bulk"}
+    rec = _record(
+        [
+            _root(0, 500, trace="setup"),
+            _span("worker.decide", 10, 5, trace="setup", stage="decide",
+                  docs=8192, bulk=8192, unhealthy=0, payload_points=0),
+            _root(1000, 100, trace="t1"),
+            _span("worker.decide", 1010, 1, trace="t1", stage="decide",
+                  docs=4, bulk=4),
+            _span("worker.decide", 1020, 5, trace="t1", stage="decide",
+                  docs=596, bulk=596, unhealthy=90, payload_points=600),
+            _span("worker.decide", 1040, 5, trace="t1", stage="decide",
+                  docs=400, bulk=400, unhealthy=60, payload_points=400),
+            # univariate decide on the writer: no `bulk`
+            _span("worker.decide", 1050, 5, tid=PREFETCH, trace="t1",
+                  stage="decide", docs=2000),
+        ],
+        windows=6000,  # 1,000 4-alias docs + 2,000 1-alias
+    )
+    assert span_attr_per_kwin.read(rec, spec["params"]) == pytest.approx(
+        1000 / 6.0
+    )
+    # the parent commit's spans carry no `bulk`: the metric is left out
+    for sp in rec["spans"]:
+        sp["args"].pop("bulk", None)
+    assert span_attr_per_kwin.read(rec, spec["params"]) is None
+
+
 def test_prefetch_at_issue_layer_counts_the_waits_released_from_a_judge():
     """ISSUE 30's layer file through the reader it names: a sweep of four
     slices waits for five prefetches, four of them submitted from inside

@@ -10,6 +10,7 @@ identical claims.
 """
 
 import dataclasses
+import logging
 
 import numpy as np
 import pytest
@@ -18,7 +19,9 @@ from benchmarks.worker_bench import build_mixed_fleet
 from foremast_tpu.config import BrainConfig
 from foremast_tpu.jobs import (
     BrainWorker,
+    STATUS_COMPLETED_HEALTH,
     STATUS_COMPLETED_UNHEALTH,
+    STATUS_COMPLETED_UNKNOWN,
     STATUS_PREPROCESS_COMPLETED,
 )
 
@@ -312,15 +315,18 @@ def test_lstm_mixed_window_buckets_merge_into_one_dispatch():
 
 
 # ---------------------------------------------------------------------------
-# ISSUE 25: the slice's joint windows are packed in bulk (jobs/joint_pack.py).
-# The per-doc loop it replaced stays HERE as the oracle: for the same
-# `ok_joint`, everything handed to `joint_columnar`, every status / payload
-# / hook verdict and the order of `updated` must be identical.
+# The slice's joint windows are packed in bulk (jobs/joint_pack.py) and
+# decided in bulk (jobs/joint_decide.py). The per-doc loop they replaced
+# stays HERE as the oracle: for the same `ok_joint`, everything handed to
+# `joint_columnar`, every status / payload / hook verdict, the order of
+# `updated`, the worker's job and window counters and the judgment log lines
+# must be identical.
 # ---------------------------------------------------------------------------
 
 
 def _oracle_judge_joint_fast(self, ok_joint, now):
-    """`BrainWorker._judge_joint_fast` as PR 24 had it (spans left out)."""
+    """`BrainWorker._judge_joint_fast` and its decide as one per-doc loop
+    (spans left out)."""
     from foremast_tpu.engine import (
         HEALTHY, UNHEALTHY, UNKNOWN, MetricVerdict,
     )
@@ -363,8 +369,11 @@ def _oracle_judge_joint_fast(self, ok_joint, now):
         )
     for doc, end_epoch, jinfo, vals in empty:
         self._decide_status(doc, UNKNOWN, {}, now, end_epoch)
+        self._log_judged(doc)
         updated.append(doc)
         counts[jinfo[0]] += 1
+        if self.metrics:
+            self.metrics.observe_doc(doc.status, len(jinfo[1]))
         if hook:
             hook(doc, [
                 MetricVerdict(
@@ -411,8 +420,11 @@ def _oracle_judge_joint_fast(self, ok_joint, now):
                     pairs[1::2] = cv[f_i][fl]
                     values_map[alias] = pairs.tolist()
             self._decide_status(doc, jv, values_map, now, end_epoch)
+            self._log_judged(doc)
             updated.append(doc)
             counts[mode] += 1
+            if self.metrics:
+                self.metrics.observe_doc(doc.status, f)
             if hook:
                 hook(doc, self._joint_verdicts(
                     doc, jinfo, ct, cv, n, fl, jv, thr
@@ -423,7 +435,8 @@ def _oracle_judge_joint_fast(self, ok_joint, now):
 class _StubJudge:
     """Stands where `MultivariateJudge` does: records what
     `joint_columnar` is handed and flags every real point where some
-    alias reads over 2.0 (about one clean doc in two carries a flag)."""
+    alias reads over 2.0 (about one clean doc in two carries a flag), and
+    every padded column past a row's n, which no decide may count."""
 
     def __init__(self):
         from types import SimpleNamespace as NS
@@ -446,7 +459,7 @@ class _StubJudge:
         from foremast_tpu.engine.kinds import JointPending
 
         self.calls.append((mode, keys, entries, metas, cur, mask, gaps))
-        return JointPending(mask & (cur.max(axis=1) > 2.0))
+        return JointPending(~mask | (cur.max(axis=1) > 2.0))
 
 
 T0 = int(NOW) - 30 * 60
@@ -473,12 +486,40 @@ def _spec(mode="lstm", f=4, n=CUR_LEN, fitted=None, times=None, seed=0):
 
 
 def _edit(spec, alias, times=None, values=None):
-    mode, fitted, series = spec
+    mode, fitted, series, *doc = spec
     series = list(series)
     t, v = series[alias]
     series[alias] = (t if times is None else times,
                      v if values is None else values)
-    return mode, fitted, series
+    return (mode, fitted, series, *doc)
+
+
+def _with(spec, **doc):
+    """The spec's document given an `end` epoch (default an hour ahead)
+    or a `trace_id`."""
+    mode, fitted, series, *old = spec
+    return mode, fitted, series, {**(old[0] if old else {}), **doc}
+
+
+def _calm(spec):
+    """The spec with its values at a tenth: no point reads over 2.0."""
+    mode, fitted, series, *doc = spec
+    return (mode, fitted, [(t, v * 0.1) for t, v in series], *doc)
+
+
+def _spiked(spec, at=5):
+    """The spec with its first alias at 3.0 in column `at`."""
+    v = spec[2][0][1].copy()
+    v[at] = 3.0
+    return _edit(spec, 0, values=v)
+
+
+def _nothing():
+    """A 4-alias doc with no observation at all."""
+    return (
+        "lstm", 32,
+        [(np.zeros(0, np.int64), np.zeros(0, np.float32))] * 4,
+    )
 
 
 def _case_shared_stamps():
@@ -534,11 +575,7 @@ def _case_float_stamps_and_float64_values():
 def _case_one_empty_alias():
     gone = _edit(_spec(seed=1), 1, times=np.zeros(0, np.int64),
                  values=np.zeros(0, np.float32))
-    nothing = (
-        "lstm", 32,
-        [(np.zeros(0, np.int64), np.zeros(0, np.float32))] * 4,
-    )
-    return [_spec(seed=0), gone, nothing, _spec(seed=2)]
+    return [_spec(seed=0), gone, _nothing(), _spec(seed=2)]
 
 
 def _case_mixed_lengths_one_dispatch():
@@ -581,6 +618,39 @@ def _case_kinds_interleaved():
     ]
 
 
+def _case_statuses_in_one_group():
+    # closed windows (no endTime, past it, at it) beside open ones, clean
+    # and flagged, and a window-less doc of each: every transition of the
+    # state machine in one slice
+    return [
+        _with(_calm(_spec(seed=0)), end=0.0),
+        _with(_calm(_spec(seed=1)), end=NOW - 60.0),
+        _with(_calm(_spec(seed=2)), end=NOW),
+        _calm(_spec(seed=3)),
+        _with(_spiked(_calm(_spec(seed=4))), end=0.0),
+        _spiked(_calm(_spec(seed=5))),
+        _with(_nothing(), end=-1.0),
+        _nothing(),
+    ]
+
+
+def _case_flag_past_n_is_padding():
+    # nothing real reads over 2.0: the stub's only flags are the padded
+    # columns of both classes (30 -> 64 and 45 -> 64), which count for no doc
+    return [_calm(_spec(n=(30, 45)[i % 2], seed=i)) for i in range(4)]
+
+
+def _case_traced_docs():
+    # a doc that carries a trace ID logs its judgment, flagged, clean or
+    # window-less; the others log nothing
+    return [
+        _with(_calm(_spec(seed=0)), trace_id="trace-a"),
+        _calm(_spec(seed=1)),
+        _with(_spiked(_calm(_spec(seed=2))), trace_id="trace-b", end=0.0),
+        _with(_nothing(), trace_id="trace-c"),
+    ]
+
+
 _PACK_CASES = {
     # name: (builder, bulk, aligned, empty, demoted, dispatches)
     "shared_stamps": (_case_shared_stamps, 6, 0, 0, 0, 1),
@@ -601,6 +671,22 @@ _PACK_CASES = {
     "fallback_in_the_middle": (
         _case_fallback_in_the_middle_keeps_its_row, 8, 1, 0, 0, 1),
     "kinds_interleaved": (_case_kinds_interleaved, 5, 0, 0, 1, 3),
+    "statuses_in_one_group": (_case_statuses_in_one_group, 6, 0, 2, 0, 1),
+    "flag_past_n_is_padding": (_case_flag_past_n_is_padding, 4, 0, 0, 0, 1),
+    "traced_docs": (_case_traced_docs, 3, 0, 1, 0, 1),
+}
+
+# the statuses a case's docs end in, where they are not "some flagged doc
+# among others"
+_CASE_STATUSES = {
+    "one_empty_alias": None,
+    "statuses_in_one_group": {
+        STATUS_COMPLETED_HEALTH,
+        STATUS_COMPLETED_UNHEALTH,
+        STATUS_COMPLETED_UNKNOWN,
+        STATUS_PREPROCESS_COMPLETED,
+    },
+    "flag_past_n_is_padding": {STATUS_PREPROCESS_COMPLETED},
 }
 
 
@@ -609,7 +695,8 @@ def _slice_of(specs):
     from foremast_tpu.jobs.models import Document
 
     ok_joint = []
-    for i, (mode, fitted, series) in enumerate(specs):
+    for i, (mode, fitted, series, *opt) in enumerate(specs):
+        opt = opt[0] if opt else {}
         f = len(series)
         aliases = tuple(f"m{a}" for a in range(f))
         # history's last stamp 1, 1.5, 2.5 or 3.5 steps before the
@@ -622,8 +709,11 @@ def _slice_of(specs):
             (mode, f"app{i}", aliases), ("entry", i),
             ("jmeta", mode, f"app{i}"), meta,
         )
-        doc = Document(id=f"job-{i}", app_name=f"app{i}")
-        ok_joint.append(((doc, NOW + 3600.0, jinfo), series))
+        doc = Document(
+            id=f"job-{i}", app_name=f"app{i}",
+            trace_id=opt.get("trace_id", ""),
+        )
+        ok_joint.append(((doc, opt.get("end", NOW + 3600.0), jinfo), series))
     return ok_joint
 
 
@@ -635,19 +725,48 @@ def _judge_joint_fast(worker, ok_joint, now):
     )
 
 
-def _run_pack(judge_fn, specs):
+class _JudgmentLines(logging.Handler):
+    """The worker's `judgment` log lines: (level, job, status, trace)."""
+
+    def __init__(self):
+        super().__init__(logging.DEBUG)
+        self.lines = []
+
+    def emit(self, record):
+        if record.getMessage() == "judgment":
+            c = record.ctx
+            self.lines.append(
+                (record.levelno, c["job_id"], c["status"], c["job_trace_id"])
+            )
+
+
+def _run_pack(judge_fn, specs, hook=True):
+    from prometheus_client import CollectorRegistry
+
     from benchmarks.worker_bench import ArraySource
     from foremast_tpu.jobs.store import InMemoryStore
+    from foremast_tpu.observe.gauges import WorkerMetrics
 
     seen = []
+    registry = CollectorRegistry()
     worker = BrainWorker(
         InMemoryStore(), ArraySource(), config=BrainConfig(),
         worker_id="pack-w",
-        on_verdict=lambda doc, vs: seen.append((doc.id, vs)),
+        on_verdict=(lambda doc, vs: seen.append((doc.id, vs))) if hook
+        else None,
+        metrics=WorkerMetrics(registry=registry),
     )
     worker._mvj = _StubJudge()
     ok_joint = _slice_of(specs)
-    updated, demoted, counts = judge_fn(worker, ok_joint, NOW)
+    logger = logging.getLogger("foremast_tpu.worker")
+    lines, level = _JudgmentLines(), logger.level
+    logger.addHandler(lines)
+    logger.setLevel(logging.DEBUG)
+    try:
+        updated, demoted, counts = judge_fn(worker, ok_joint, NOW)
+    finally:
+        logger.removeHandler(lines)
+        logger.setLevel(level)
     return {
         "calls": worker._mvj.calls,
         "updated": [
@@ -657,19 +776,40 @@ def _run_pack(judge_fn, specs):
         "demoted": [d.id for d in demoted],
         "counts": counts,
         "hook": seen,
+        "metrics": {
+            (smp.name, tuple(sorted(smp.labels.items()))): smp.value
+            for fam in registry.collect()
+            for smp in fam.samples
+            if smp.name in (
+                "foremast_worker_jobs_total", "foremast_worker_windows_total"
+            )
+        },
+        "log": lines.lines,
     }
 
 
-@pytest.mark.parametrize("case", sorted(_PACK_CASES))
-def test_bulk_pack_matches_the_per_doc_loop(case):
+@pytest.mark.parametrize(
+    "case, hook",
+    [
+        p
+        for c in sorted(_PACK_CASES)
+        for p in (
+            pytest.param(c, True, id=c),
+            pytest.param(c, False, id=f"{c}-no_hook"),
+        )
+    ],
+)
+def test_bulk_pack_matches_the_per_doc_loop(case, hook):
     """For the same `ok_joint` the bulk pack hands `joint_columnar` the
-    same bytes in the same rows as the per-doc loop did, and decides,
-    demotes and reports the same docs in the same order."""
+    same bytes in the same rows as the per-doc loop did, and the bulk
+    decide decides, demotes, reports, counts and logs the same docs in
+    the same order, with an `on_verdict` hook wired (the same verdicts)
+    and without (the benchmark's and a deployment's path)."""
     from foremast_tpu.jobs.joint_pack import pack_slice
 
     build, bulk, aligned, empty, demoted, dispatches = _PACK_CASES[case]
-    want = _run_pack(_oracle_judge_joint_fast, build())
-    got = _run_pack(_judge_joint_fast, build())
+    want = _run_pack(_oracle_judge_joint_fast, build(), hook)
+    got = _run_pack(_judge_joint_fast, build(), hook)
 
     assert len(got["calls"]) == len(want["calls"]) == dispatches
     for g, w in zip(got["calls"], want["calls"]):
@@ -690,6 +830,19 @@ def test_bulk_pack_matches_the_per_doc_loop(case):
     assert {k: n for k, n in got["counts"].items() if n} == {
         k: n for k, n in want["counts"].items() if n
     }
+    # foremast_worker_jobs_total{status} and foremast_worker_windows_total
+    assert got["metrics"] == want["metrics"]
+    assert sum(
+        v for (name, _), v in got["metrics"].items()
+        if name == "foremast_worker_jobs_total"
+    ) == len(got["updated"])
+    assert got["log"] == want["log"]
+    traced = [
+        f"job-{i}" for i, sp in enumerate(build())
+        if len(sp) > 3 and sp[3].get("trace_id")
+    ]
+    assert sorted(line[1] for line in got["log"]) == traced
+    assert bool(got["hook"]) == hook
     assert [d for d, _ in got["hook"]] == [d for d, _ in want["hook"]]
     for (_, va), (_, vb) in zip(got["hook"], want["hook"]):
         assert len(va) == len(vb)
@@ -704,8 +857,11 @@ def test_bulk_pack_matches_the_per_doc_loop(case):
     assert (n_bulk, n_aligned, len(empties), len(drifted)) == (
         bulk, aligned, empty, demoted
     )
-    if case != "one_empty_alias":
-        assert any(s[1] == STATUS_COMPLETED_UNHEALTH for s in got["updated"])
+    statuses = {s[1] for s in got["updated"]}
+    if case in _CASE_STATUSES:
+        assert _CASE_STATUSES[case] in (None, statuses)
+    else:
+        assert STATUS_COMPLETED_UNHEALTH in statuses
 
 
 def test_bulk_pack_decide_reads_views_not_copies():

@@ -36,7 +36,7 @@ from prometheus_client import CollectorRegistry
 from benchmarks.worker_bench import build_mixed_fleet
 from foremast_tpu.config import BrainConfig
 from foremast_tpu.engine.kinds import lstm as lstm_kind
-from foremast_tpu.jobs import BrainWorker, joint_pack
+from foremast_tpu.jobs import BrainWorker, joint_decide, joint_pack
 from foremast_tpu.jobs.pipeline import PipelineStats
 from foremast_tpu.observe import spans
 from foremast_tpu.observe.spans import TICK_STAGES, Tracer, current_span
@@ -144,6 +144,10 @@ def swept(tmp_path_factory):
         joint_pack, "align_series",
         rec.wrap("align_series", joint_pack.align_series),
     )
+    mp.setattr(
+        joint_decide, "decide_group",
+        rec.wrap("decide_group", joint_decide.decide_group),
+    )
     mp.setattr(w.store, "claim", rec.wrap("store.claim", w.store.claim))
     mp.setattr(
         w.store, "update_many", rec.wrap("store.update_many", w.store.update_many)
@@ -190,13 +194,16 @@ def _stage_counts(registry) -> dict:
         ("align_series", {("pack", "tick")}),
         ("ae_cutoff", {("pack", "tick")}),
         ("_place_joint", {("h2d", "tick")}),
-        # joint docs decide on the tick thread, univariate on the writer
-        ("_decide_status", {("decide", "tick"), ("decide", "writer")}),
-        ("_log_judged", {("decide", "tick"), ("decide", "writer")}),
+        # univariate docs decide one at a time on the writer; joint docs
+        # a dispatch group at a time on the tick thread (`decide_group`,
+        # which logs only the docs that carry a trace ID: none here)
+        ("_decide_status", {("decide", "writer")}),
+        ("_log_judged", {("decide", "writer")}),
         ("store.update_many", {("write_back", "writer")}),
         ("_observe_verdicts", {("housekeeping", "writer")}),
         ("_preempt_between_slices", {("housekeeping", "tick")}),
         ("_tick_done", {("housekeeping", "tick")}),
+        ("decide_group", {("decide", "tick")}),
     ],
 )
 def test_unspanned_function_now_runs_under_its_stage(swept, fn, expected):
